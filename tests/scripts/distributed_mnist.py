@@ -12,9 +12,6 @@ import sys
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=2")
 import jax
 
-if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 jax.distributed.initialize(
     coordinator_address=os.environ["JAX_COORDINATOR_ADDRESS"],
     num_processes=int(os.environ["JAX_NUM_PROCESSES"]),

@@ -13,11 +13,6 @@ import json
 import os
 import sys
 
-import jax
-
-if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 import jax.numpy as jnp
 
 from tony_tpu.checkpoint import CheckpointManager

@@ -11,10 +11,7 @@ import time
 import tony_tpu  # noqa: F401  (starts the reporter + arms TONY_FAULTS)
 from tony_tpu import telemetry
 
-import jax
 import numpy as np
-
-jax.config.update("jax_platforms", "cpu")
 
 from tony_tpu.data import ShardedBatchIterator  # noqa: E402
 from tony_tpu.parallel import MeshSpec, build_mesh  # noqa: E402

@@ -8,9 +8,6 @@ import time
 
 import jax
 
-if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 import jax.numpy as jnp
 
 from tony_tpu.checkpoint import CheckpointManager

@@ -32,17 +32,25 @@ def test_fused_groupnorm_matches_flax(use_pallas, relu):
                                rtol=2e-5, atol=2e-5)
 
 
-def test_fused_groupnorm_under_jit_and_grad():
-    """Remat'd fused path: grads match the unfused flax composition."""
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_fused_groupnorm_under_jit_and_grad(use_pallas):
+    """Remat'd fused path: grads w.r.t. the activation AND the affine
+    params match the unfused flax composition — on the Pallas apply too
+    (pallas_call has no reverse-mode rule of its own; the path a TPU
+    takes must still train)."""
     x = jax.random.normal(jax.random.key(0), (2, 5, 5, 8), jnp.float32)
-    scale = jnp.ones((8,))
-    bias = jnp.zeros((8,))
+    scale = 1.0 + 0.1 * jax.random.normal(jax.random.key(1), (8,))
+    bias = 0.1 * jax.random.normal(jax.random.key(2), (8,))
+    w = jax.random.normal(jax.random.key(3), x.shape)
 
-    g1 = jax.jit(jax.grad(lambda x: convfuse.fused_groupnorm_relu(
-        x, scale, bias, groups=4).sum()))(x)
-    g2 = jax.grad(lambda x: _ref(x, scale, bias, 4).sum())(x)
-    np.testing.assert_allclose(np.asarray(g1), np.asarray(g2),
-                               rtol=2e-4, atol=2e-5)
+    g1 = jax.jit(jax.grad(lambda x, s, b: (w * convfuse.fused_groupnorm_relu(
+        x, s, b, groups=4, use_pallas=use_pallas)).sum(),
+        argnums=(0, 1, 2)))(x, scale, bias)
+    g2 = jax.grad(lambda x, s, b: (w * _ref(x, s, b, 4)).sum(),
+                  argnums=(0, 1, 2))(x, scale, bias)
+    for got, want in zip(g1, g2):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-4, atol=2e-5)
 
 
 def test_fused_groupnorm_channel_edge():

@@ -320,7 +320,10 @@ class FakeRunner:
         self.killed = []
         self.resize_ok = resize_ok
         self.migrate_ok = migrate_ok
-        self._next_pid = 1000
+        # Above any kernel's pid_max (<= 2**22): a fake client pid must
+        # read as DEAD to recovery's liveness probe, whatever real
+        # processes the host happens to be running.
+        self._next_pid = 2 ** 22 + 1000
 
     def spawn(self, workdir, overrides):
         os.makedirs(workdir, exist_ok=True)

@@ -15,7 +15,7 @@ import optax
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from tony_tpu import compat, telemetry
+from tony_tpu import telemetry
 from tony_tpu.parallel import (GradSyncSpec, MeshSpec, batch_sharding,
                                build_mesh, bucketed_sync,
                                init_sharded_state, jit_train_step,
@@ -96,7 +96,7 @@ def test_bucketed_accum_allclose_monolithic_psum(rig, bucket_mb, accum):
     mesh, model, batch, state, sh = rig
     loss_fn = _loss_fn(model)
     part_sh = NamedSharding(mesh, P(("dcn_dp", "dp"), None))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         mono = jax.jit(lambda p, b, r: monolithic_grads(
             loss_fn, p, b, r))(state.params, batch, jax.random.key(2))
         accum_fn = _build_accum_fn(loss_fn, mesh, accum, 8,

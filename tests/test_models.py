@@ -27,6 +27,18 @@ def test_transformer_forward_shapes():
     assert logits.dtype == jnp.float32
 
 
+def test_llama3_8b_preset_takes_overrides():
+    """A depth or vocabulary cut is ONE call (it used to raise TypeError:
+    the preset passed n_layers/vocab_size twice); no width moves."""
+    cfg = TransformerConfig.llama3_8b(n_layers=2, vocab_size=32064,
+                                      max_seq_len=8192)
+    assert (cfg.n_layers, cfg.vocab_size) == (2, 32064)
+    assert (cfg.dim, cfg.n_heads, cfg.n_kv_heads, cfg.mlp_dim,
+            cfg.rope_theta) == (4096, 32, 8, 14336, 500000.0)
+    full = TransformerConfig.llama3_8b()
+    assert (full.n_layers, full.vocab_size) == (32, 128256)
+
+
 def test_chunked_loss_matches_full_loss():
     """chunked_causal_lm_loss == causal_lm_loss(full logits) — value AND
     gradients — including a chunk size that doesn't divide the shifted
@@ -134,7 +146,6 @@ def test_transformer_ring_attention_seq_parallel():
     import flax.linen as nn
     from jax.sharding import NamedSharding, PartitionSpec as P
     from tony_tpu.parallel.sharding import DEFAULT_RULES
-    from tony_tpu.compat import shard_map
 
     with nn.logical_axis_rules(list(DEFAULT_RULES)):
         variables = Transformer(cfg_flash).init(jax.random.key(1), tokens)
@@ -147,7 +158,7 @@ def test_transformer_ring_attention_seq_parallel():
     def fwd(params, tokens):
         return Transformer(cfg_ring).apply({"params": params}, tokens)
 
-    ring_fn = shard_map(
+    ring_fn = jax.shard_map(
         fwd, mesh=mesh_sp,
         in_specs=(P(), P(("dp", "fsdp"), "sp")),
         out_specs=P(("dp", "fsdp"), "sp", None), check_vma=False)

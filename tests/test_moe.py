@@ -8,7 +8,6 @@ import numpy as np
 import optax
 import pytest
 
-from tony_tpu import compat
 from tony_tpu.models.moe import (MoEConfig, MoEMLP, MoETransformer,
                                  moe_lm_loss)
 from tony_tpu.parallel import MeshSpec, build_mesh, init_sharded_state
@@ -76,7 +75,7 @@ def test_moe_transformer_trains_on_ep_mesh():
         loss, grads = jax.value_and_grad(loss_fn)(state.params)
         return state.apply_gradients(grads), loss
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         losses = []
         for _ in range(5):
             state, loss = step(state)
@@ -109,7 +108,7 @@ def test_moe_dispatch_is_all_to_all_on_ep_mesh():
             return moe_lm_loss(model.apply({"params": p}, tokens), tokens,
                                cfg.aux_loss_weight)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         txt = jax.jit(jax.grad(loss_fn)).lower(state.params).compile()\
             .as_text()
     assert "all-to-all" in txt, "expert dispatch did not lower to all_to_all"

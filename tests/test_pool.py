@@ -437,3 +437,35 @@ def test_replenish_recycles_overage_worker(tmp_path):
     finally:
         daemon.request_stop()
         t.join(timeout=30)
+
+
+def test_warm_worker_turned_executor_never_initializes_a_backend(tmp_path):
+    """One process for each chip: a warm worker preloads with the default
+    ``tony.pool.preload`` (jax), then BECOMES the executor whose monitor
+    samples the task — and through all of it no JAX backend comes up in
+    that process. A backend initialized here would hold the chip against
+    the user process this executor spawns (invisible on CPU, fatal on a
+    TPU). Fresh interpreter: this test process has long since initialized
+    its own backend."""
+    from tony_tpu.conf import keys as K
+    from tony_tpu.conf.config import TonyTpuConfig
+
+    preload = str(TonyTpuConfig().get(K.POOL_PRELOAD))
+    code = (
+        "import sys\n"
+        "from tony_tpu import pool\n"
+        "from tony_tpu.executor import monitor\n"
+        f"done = pool._preload({preload!r})\n"
+        "assert 'jax' in done and 'jax' in sys.modules, done\n"
+        "assert not hasattr(monitor, 'tpu_hbm_in_use_bytes')\n"
+        "m = monitor.TaskMonitor('worker:0', lambda *_: None,\n"
+        f"                        metrics_file={str(tmp_path / 'absent.json')!r})\n"
+        "m.sample_once()\n"
+        "from jax._src import xla_bridge\n"
+        "assert not xla_bridge.backends_are_initialized(), \\\n"
+        "    'preload/monitor initialized a jax backend'\n"
+        "print('NO_BACKEND')\n")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run([sys.executable, "-c", code], cwd=repo,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0 and "NO_BACKEND" in r.stdout, r.stderr[-2000:]

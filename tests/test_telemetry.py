@@ -90,3 +90,34 @@ def test_step_stats_derivation():
     assert s["model_flops_per_sec"] > 0
     assert s["tokens_per_sec"] > 0
     assert s["mean_step_s"] >= 0.02
+
+
+def test_mfu_needs_an_exact_peak_table_key(monkeypatch):
+    """One peak table, exact ``device_kind`` keys: a known kind yields
+    MFU; an unknown kind that merely STARTS like a known one ("TPU v5x"
+    used to inherit v5p's 459 TF/s by prefix) yields no MFU field at all;
+    and a measurement that needs the peak raises instead of defaulting."""
+    import types
+
+    import jax
+
+    telemetry._steps.update(count=0, busy_s=0.0, flops=0.0, tokens=0.0,
+                            first_start=0.0, last_end=0.0)
+    with telemetry.step(flops=1e12, tokens=10):
+        pass
+
+    def fake(kind):
+        dev = types.SimpleNamespace(device_kind=kind,
+                                    memory_stats=lambda: None)
+        monkeypatch.setattr(jax, "local_devices", lambda: [dev])
+        monkeypatch.setattr(jax, "device_count", lambda: 1)
+        return telemetry.collect_device_stats()
+
+    known = fake("TPU v5 lite")
+    assert known["mfu_vs_peak_bf16"] == pytest.approx(
+        known["model_flops_per_sec"] / 197e12)
+    for kind in ("TPU v5x", "TPU v5 lite pod", "cpu"):
+        assert "mfu_vs_peak_bf16" not in fake(kind), kind
+        with pytest.raises(KeyError, match="no bf16 peak on record"):
+            telemetry.peak_bf16_flops(kind)
+    assert telemetry.peak_bf16_flops("TPU v5") == 459e12

@@ -65,15 +65,3 @@ _telemetry.install_stack_dump_handler()
 from tony_tpu import faults as _faults  # noqa: E402
 
 _faults.install_from_env()
-
-# Inside a multi-process CPU task (virtual-mesh gangs), select a working
-# cross-process collectives backend before the first computation; no-op
-# everywhere else. Deliberately NOT `from tony_tpu import compat` at module
-# scope for the coordinator/CLI processes' sake — compat imports jax, and
-# control-plane processes must not pay (or require) a jax import.
-import os as _os  # noqa: E402
-
-if int(_os.environ.get("JAX_NUM_PROCESSES", "1") or 1) > 1:
-    from tony_tpu import compat as _compat  # noqa: E402
-
-    _compat.configure_cpu_collectives()

@@ -4,8 +4,10 @@ Dual role, mirroring the reference:
 - the **test substrate** — in-process fake cluster like
   ``tony-mini/.../MiniCluster.java:43-63`` (no YARN/HDFS needed);
 - the **single-host production path** — on a TPU VM the coordinator and all
-  task processes are host-local, and JAX device visibility is partitioned per
-  task via env when multiple tasks share the host's chips.
+  task processes are host-local. Nothing partitions device visibility
+  between tasks: ONE JAX worker per host drives all of the host's chips
+  (a chip belongs to one process, so a second JAX task on the same host
+  cannot get any).
 
 Each task runs ``python -m tony_tpu.executor`` (the TaskExecutor entrypoint)
 in its own working directory with the task-identity environment; stdout/stderr

@@ -1,150 +1,87 @@
-"""Version-compat shims over the installed jax.
+"""Helpers over the mesh that ``jax.set_mesh`` binds (jax 0.9.0, the one
+pinned installation — see pyproject.toml).
 
-Model/ops code is written against the current jax surface (``jax.shard_map``,
-``jax.set_mesh``, abstract-mesh introspection); CI images and TPU-VM runtime
-images lag by several releases. Every drift point is absorbed HERE, once —
-call sites import from this module and stay clean of try/except ladders.
+Model and ops code never receives a mesh argument: it reads the ambient
+abstract mesh (``jax.sharding.get_abstract_mesh()``) that the library's
+jitted steps bind with ``jax.set_mesh``, and falls back to its unsharded
+path when none is bound (``model.init`` under ``eval_shape``, eager unit
+tests). The three questions such code asks live here, once:
 
-Covered drifts (installed floor: jax 0.4.x):
-- ``shard_map``: top-level ``jax.shard_map`` vs
-  ``jax.experimental.shard_map.shard_map``.
-- ``set_mesh``: ``jax.set_mesh(mesh)`` (sharding-in-types context) vs the
-  classic ``with mesh:`` physical-mesh context — on old jax the Mesh object
-  itself is the context manager and jit consumes NamedShardings directly,
-  so entering the physical mesh is the equivalent context.
-- ``mesh_axis_size``: size of a named axis of the *currently bound* mesh
-  (``jax.sharding.get_abstract_mesh()`` on new jax; the thread-resources
-  physical mesh on old jax). Returns 1 when no mesh is bound or the axis
-  is absent — callers branch to their unsharded path.
-- ``partial_shard_map``: manual collectives over ONE axis with every other
-  mesh axis left automatic (new: ``jax.shard_map(..., axis_names={ax})``;
-  old: explicit mesh + ``auto=<other axes>``).
+- ``mesh_axis_size``: how large is a named axis of the bound mesh;
+- ``partial_shard_map``: manual collectives over ONE axis, every other
+  mesh axis left to the partitioner (the MoE expert exchange);
+- ``per_shard``: run a Pallas kernel on each device's own shard — the
+  TPU compiler refuses to partition a Mosaic kernel automatically.
 """
 
 from __future__ import annotations
 
-import os
-from typing import Any, Optional
+import math
+from typing import Any, Callable, Optional, Sequence
 
 import jax
+from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.6: the supported top-level name
-    from jax import shard_map as _raw_shard_map  # type: ignore[attr-defined]
-except ImportError:  # older jax: the long-lived experimental home
-    from jax.experimental.shard_map import shard_map as _raw_shard_map
-
-import inspect
-
-_SHARD_MAP_PARAMS = frozenset(
-    inspect.signature(_raw_shard_map).parameters)
-
-
-def shard_map(f, **kw):
-    """``shard_map`` accepting either spelling of the replication-check
-    kwarg (``check_vma`` today, ``check_rep`` before the rename) and
-    translating to whatever the installed jax takes."""
-    if "check_vma" in kw and "check_vma" not in _SHARD_MAP_PARAMS:
-        kw["check_rep"] = kw.pop("check_vma")
-    elif "check_rep" in kw and "check_rep" not in _SHARD_MAP_PARAMS:
-        kw["check_vma"] = kw.pop("check_rep")
-    return _raw_shard_map(f, **kw)
-
-__all__ = ["shard_map", "set_mesh", "current_mesh", "mesh_axis_size",
-           "partial_shard_map", "configure_cpu_collectives"]
-
-
-def configure_cpu_collectives() -> None:
-    """Multi-process CPU gangs (the virtual-mesh test substrate) need a
-    cross-process collectives backend; on jax versions whose CPU default
-    is "none" every sharded computation fails with "Multiprocess
-    computations aren't implemented on the CPU backend". Select gloo when
-    this process is part of a multi-process tony task on CPU. Safe to call
-    any time before the first computation; silently a no-op where the
-    option is gone (newer jax defaults to gloo)."""
-    if int(os.environ.get("JAX_NUM_PROCESSES", "1") or 1) <= 1:
-        return
-    platforms = (os.environ.get("JAX_PLATFORMS", "")
-                 or str(jax.config.jax_platforms or "")).strip().lower()
-    if platforms != "cpu":
-        return
-    try:
-        from jax._src import distributed as _dist
-
-        if getattr(_dist.global_state, "client", None) is None:
-            # gloo needs the distributed-runtime client; selecting it in
-            # a process that never calls jax.distributed.initialize (a
-            # gang member doing only local work) would CRASH CPU backend
-            # creation instead of helping. Scripts initialize before
-            # importing tony_tpu, so by the time we run the client is
-            # there exactly when it should be.
-            return
-    except Exception:  # noqa: BLE001 — private API moved: don't guess
-        return
-    impl = os.environ.get("JAX_CPU_COLLECTIVES_IMPLEMENTATION", "gloo")
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", impl)
-    except Exception:  # noqa: BLE001 — option removed: default is fine
-        pass
-
-
-def set_mesh(mesh):
-    """Context manager binding ``mesh`` for the enclosed trace/execution.
-
-    New jax: ``jax.set_mesh`` (also feeds ``get_abstract_mesh``). Old jax:
-    the Mesh object is its own context manager and binds the
-    thread-resources physical mesh, which is what ``mesh_axis_size`` and
-    legacy collectives read.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+__all__ = ["current_mesh", "mesh_axis_size", "partial_shard_map",
+           "per_shard"]
 
 
 def current_mesh() -> Optional[Any]:
-    """The mesh bound by ``set_mesh`` (or None outside any mesh context)."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        m = get_abstract()
-        return m if getattr(m, "axis_types", None) else None
-    try:
-        from jax._src import mesh as mesh_lib
-
-        m = mesh_lib.thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:  # noqa: BLE001 — private API gone: no mesh context
-        return None
+    """The abstract mesh bound by ``jax.set_mesh`` (inside a ``shard_map``:
+    the same mesh with the mapped axes typed Manual), or None outside any
+    mesh context."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def mesh_axis_size(axis_name: str) -> int:
     """Size of ``axis_name`` on the currently bound mesh; 1 when no mesh
-    is bound or the mesh has no such axis (the unsharded fallback)."""
+    is bound or the mesh has no such axis (the unsharded path)."""
     m = current_mesh()
-    if m is None:
-        return 1
-    shape = dict(getattr(m, "shape", {}) or {})
-    return int(shape.get(axis_name, 1))
+    return 1 if m is None else int(m.shape.get(axis_name, 1))
 
 
 def partial_shard_map(fn, axis_name: str, in_specs, out_specs):
     """``shard_map`` manual over exactly ``axis_name``; every other axis of
     the bound mesh stays automatic (partial-manual collectives — the MoE
-    expert-exchange shape). Must run under ``set_mesh``."""
-    if hasattr(jax, "shard_map") and hasattr(jax, "set_mesh"):
-        # New jax: the abstract mesh is ambient; axis_names selects the
-        # manual subset.
-        return jax.shard_map(fn, axis_names={axis_name},
-                             in_specs=in_specs, out_specs=out_specs)
-    m = current_mesh()
-    if m is None:
-        raise RuntimeError(
-            f"partial_shard_map over {axis_name!r} needs a bound mesh "
-            f"(wrap the call in compat.set_mesh(mesh))")
-    # Old jax: partial-auto (`auto=`) + all_to_all hard-aborts the SPMD
-    # partitioner ("Check failed: target.IsManualSubgroup()"), so fall back
-    # to FULL manual over every mesh axis with the given specs — inputs are
-    # replicated over the non-manual axes (correct, at the cost of
-    # redundant compute/memory on those axes; the new-jax path keeps them
-    # automatic). check_rep=False: the replication check predates this
-    # nesting and false-positives on it.
-    return shard_map(fn, mesh=m, in_specs=in_specs, out_specs=out_specs,
-                     check_vma=False)
+    expert-exchange shape). Must run under ``jax.set_mesh``."""
+    return jax.shard_map(fn, axis_names={axis_name},
+                         in_specs=in_specs, out_specs=out_specs)
+
+
+def per_shard(fn: Callable, dim_axes: Sequence[Sequence[str]]) -> Callable:
+    """Wrap a Pallas kernel call so each device runs it on its own shard.
+
+    XLA:TPU cannot partition a Mosaic custom call ("Mosaic kernels cannot
+    be automatically partitioned"), so a kernel traced into a jitted step
+    over a multi-device mesh must sit inside a ``shard_map``. ``fn`` takes
+    and returns arrays whose leading dims are all laid out alike;
+    ``dim_axes[d]`` names the mesh axes dim ``d`` may be split over (e.g.
+    ``(BATCH_AXES, ("tp",))`` for ``[batch, heads, ...]`` operands). A dim
+    is split only when every operand's extent divides the axes' size —
+    otherwise it stays whole and the shards compute it redundantly, which
+    is what the partitioner would do with an unannotated operand.
+
+    The map is manual over EVERY axis of the bound mesh that is not manual
+    already (size-1 axes included: the compiler rejects the kernel under
+    any remaining automatic axis). With no mesh bound, or inside a
+    ``shard_map`` that already covers the whole mesh (ring, Ulysses,
+    pipeline stages), ``fn`` runs as is.
+    """
+    def wrapped(*args):
+        mesh = current_mesh()
+        auto = () if mesh is None else tuple(
+            a for a in mesh.axis_names if a not in mesh.manual_axes)
+        if not auto:
+            return fn(*args)
+        spec = []
+        for d, axes in enumerate(dim_axes):
+            axes = tuple(a for a in axes if a in auto)
+            n = math.prod(mesh.shape[a] for a in axes)
+            split = n > 1 and all(x.shape[d] % n == 0 for x in args)
+            spec.append(axes if split else None)
+        # check_vma off: pallas_call outputs carry no varying-axes type.
+        return jax.shard_map(fn, axis_names=set(auto), in_specs=P(*spec),
+                             out_specs=P(*spec), check_vma=False)(*args)
+
+    return wrapped

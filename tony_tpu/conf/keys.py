@@ -935,9 +935,9 @@ POOL_MAX_LEASE_AGE_S = _key(
 POOL_PRELOAD = _key(
     "tony.pool.preload", "jax", str,
     "Comma-separated modules each warm worker imports while idle (on top "
-    "of the always-preloaded executor stack). 'jax' also initializes the "
-    "backend — the multi-second cold-start slice the pool exists to "
-    "hide. Empty = interpreter + tony_tpu only.")
+    "of the always-preloaded executor stack). Import only: no backend is "
+    "initialized — the chip must stay free for the user process the "
+    "adopted executor spawns. Empty = interpreter + tony_tpu only.")
 
 # --- fleet: persistent multi-job gang scheduler (tony_tpu/fleet/) ---------
 FLEET_DIR = _key(

@@ -7,9 +7,10 @@ YARN's ResourceCalculatorProcessTree (:71,:109-114) and GPU utilization via
 ``tony.task.metrics-interval-ms`` (:92-99).
 
 TPU deltas: RSS comes from /proc (no YARN); accelerator telemetry comes from
-the TPU runtime when present — libtpu exposes device metrics through JAX
-(``jax.local_devices()[i].memory_stats()``) instead of an ``nvidia-smi``
-subprocess. Sampling is best-effort and never fails the task.
+the USER process — the one that holds the chips — which reads
+``jax.local_devices()[i].memory_stats()`` and writes it to the metrics file
+this sampler tails (``tony_tpu/telemetry.py``). The executor itself never
+touches jax. Sampling is best-effort and never fails the task.
 """
 
 from __future__ import annotations
@@ -81,33 +82,6 @@ def _proc_tree_rss_bytes(root_pid: int) -> int:
     return total
 
 
-def tpu_hbm_in_use_bytes() -> int:
-    """Best-effort HBM usage of locally visible TPU devices; 0 when no TPU
-    runtime is attached to *this* process (the usual case — the user process
-    owns the chips)."""
-    try:
-        import sys
-
-        if "jax" not in sys.modules:
-            # The probe is only meaningful where this process already runs
-            # jax (in-process/notebook modes). IMPORTING jax here costs
-            # ~2.3 s and then reads 0 — in the executor that tax landed in
-            # monitor.stop()'s final sample, i.e. on EVERY task teardown
-            # (found via the r5 suite-latency hunt: a trivial task's
-            # "user process exited" trailed its actual exit by 2.3 s).
-            return 0
-        import jax
-
-        total = 0
-        for d in jax.local_devices():
-            stats = getattr(d, "memory_stats", lambda: None)()
-            if stats:
-                total += int(stats.get("bytes_in_use", 0))
-        return total
-    except Exception:  # noqa: BLE001 — telemetry must never break the task
-        return 0
-
-
 class TaskMonitor:
     """Background sampler pushing metrics to the coordinator."""
 
@@ -135,10 +109,11 @@ class TaskMonitor:
     def sample_once(self) -> Dict[str, float]:
         pid = self._pid_fn()
         rss = _proc_tree_rss_bytes(pid) if pid else 0
-        # HBM: prefer the user process's own reporter (tony_tpu.telemetry
-        # writes TONY_METRICS_FILE from inside the process that owns the
-        # chips); the local probe only ever sees this monitor process and
-        # reads 0 on real jobs (round-1 VERDICT weak #7).
+        # HBM comes from the user process's own reporter
+        # (tony_tpu.telemetry writes TONY_METRICS_FILE from inside the
+        # process that owns the chips). The executor never asks jax
+        # itself: a chip belongs to one process, and a backend brought up
+        # here would hold it against the task this executor supervises.
         hbm = 0.0
         if self._metrics_file:
             from tony_tpu.telemetry import read_stats
@@ -151,8 +126,6 @@ class TaskMonitor:
             for key, src in _UTIL_PASSTHROUGH.items():
                 if src in stats:
                     self._metrics[key] = float(stats[src])
-        if not hbm:
-            hbm = tpu_hbm_in_use_bytes()
         self.last_rss = float(rss)
         self._samples += 1
         n = self._samples

@@ -261,7 +261,7 @@ def dryrun_ep_step(devices, ep: int) -> float:
     # set_mesh binds the abstract mesh MoEMLP reads to pick the ep path;
     # without it n_ep resolves to 1 and the dry run would only validate the
     # replicated fallback (advisor finding, round 2).
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(step).lower(state).compile()
         hlo = compiled.as_text()
         assert "all-to-all" in hlo, \

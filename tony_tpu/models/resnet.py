@@ -27,8 +27,8 @@ class ResNetConfig:
     dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32
     norm_groups: int = 32
-    # HBM-aware conv trunk (BENCH_r05: every conv fusion HBM-bound at
-    # 0.13 MFU): each conv→norm→relu chain runs the fused two-pass
+    # HBM-aware conv trunk (the conv fusions are HBM-bound, not
+    # MXU-bound): each conv→norm→relu chain runs the fused two-pass
     # GroupNorm epilogue (ops/convfuse.py — folded affine, Pallas apply
     # on TPU, remat'd backward) instead of nn.GroupNorm + separate relu.
     # False keeps the original module chain (the parity twin the fused

@@ -40,6 +40,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from tony_tpu.compat import per_shard
+from tony_tpu.parallel.mesh import BATCH_AXES
 
 NEG_INF = -1e30
 # Stats (lse/delta) sublane broadcast factor: min f32 tile is (8, 128), so
@@ -357,7 +361,21 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 # pallas_call plumbing
 # ---------------------------------------------------------------------------
 def _interpret() -> bool:
+    """Pallas interpret mode, for every platform but TPU (the CPU suite
+    runs the same kernels through the interpreter). Keyed on the devices
+    in use — the bound mesh's when there is one, so an ahead-of-time
+    lowering for a TPU topology from a CPU host gets the Mosaic kernels —
+    and on the default backend otherwise."""
+    dev = jax.sharding.get_abstract_mesh().abstract_device
+    if dev is not None:
+        return not dev.device_kind.startswith("TPU")
     return jax.default_backend() != "tpu"
+
+
+#: Every kernel operand is laid out [batch, heads (q or kv), ...]: under a
+#: bound mesh each device runs the kernels on its own
+#: [B/batch-axes, H/tp, S, D] shard (compat.per_shard).
+_KERNEL_DIM_AXES = (BATCH_AXES, ("tp",))
 
 
 def _round_up(x: int, m: int) -> int:
@@ -367,6 +385,14 @@ def _round_up(x: int, m: int) -> int:
 
 
 def _fwd_impl(q, k, v, scale, causal, block_q, block_k, out_dtype=None):
+    return per_shard(
+        functools.partial(_fwd_call, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k,
+                          out_dtype=out_dtype),
+        _KERNEL_DIM_AXES)(q, k, v)
+
+
+def _fwd_call(q, k, v, *, scale, causal, block_q, block_k, out_dtype):
     b, h, sq, d = q.shape
     hk = k.shape[1]
     g = h // hk
@@ -377,7 +403,6 @@ def _fwd_impl(q, k, v, scale, causal, block_q, block_k, out_dtype=None):
     block_k = min(block_k, _round_up(sk, 16))
     nq = pl.cdiv(sq, block_q)
     nk = pl.cdiv(sk, block_k)
-    from jax.experimental.pallas import tpu as pltpu
 
     def kv_j(i, j):
         # Clamp fully-masked causal tiles to the previous fetch so the
@@ -426,15 +451,7 @@ def _fwd_impl(q, k, v, scale, causal, block_q, block_k, out_dtype=None):
 
 def _bwd_impl(q, k, v, o, lse, do, scale, causal, block_q, block_k,
               dlse=None):
-    b, h, sq, d = q.shape
-    hk = k.shape[1]
-    g = h // hk
-    sk = k.shape[2]
-    block_q = min(block_q, _round_up(sq, 128))
-    block_k = min(block_k, _round_up(sk, 16))
-    nq = pl.cdiv(sq, block_q)
-    nk = pl.cdiv(sk, block_k)
-    from jax.experimental.pallas import tpu as pltpu
+    b, h, sq, _ = q.shape
     delta_rows = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32),
                          axis=-1)                    # [B, H, S]
     if dlse is not None:
@@ -445,6 +462,21 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, block_q, block_k,
     delta = jnp.broadcast_to(
         delta_rows[:, :, None, :],
         (b, h, STAT_SUB, sq))                        # sublane-bcast like lse
+    return per_shard(
+        functools.partial(_bwd_call, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k),
+        _KERNEL_DIM_AXES)(q, k, v, do, lse, delta)
+
+
+def _bwd_call(q, k, v, do, lse, delta, *, scale, causal, block_q, block_k):
+    b, h, sq, d = q.shape
+    hk = k.shape[1]
+    g = h // hk
+    sk = k.shape[2]
+    block_q = min(block_q, _round_up(sq, 128))
+    block_k = min(block_k, _round_up(sk, 16))
+    nq = pl.cdiv(sq, block_q)
+    nk = pl.cdiv(sk, block_k)
 
     def kv_j(i, j):
         return jnp.minimum(j, _last_valid_kj(i, block_q, block_k)) \

@@ -1,9 +1,7 @@
 """HBM-aware fused GroupNorm→ReLU for the resnet conv trunk.
 
-BENCH_r05 pins the resnet workload at 0.13 MFU with every conv fusion
-HBM-bound (~700 GiB/s measured, xprof r5): the chip's 240 FLOPs/byte
-ratio, not the MXU, is the ceiling, so the lever is *fewer HBM passes
-per conv→norm→relu chain*, not faster matmuls. ``nn.GroupNorm`` + a
+ResNet's conv→norm→relu chains are HBM-bound, not MXU-bound: the lever is
+*fewer HBM passes per chain*, not faster matmuls. ``nn.GroupNorm`` + a
 separate ``nn.relu`` walks the [B, H, W, C] activation several times
 (stats, normalize, affine, relu) and saves the normalized tensor for
 backward. This module collapses the chain:
@@ -21,18 +19,17 @@ backward. This module collapses the chain:
   keeping the [B, H, W, C] normalized tensor resident — HBM footprint
   and write traffic both drop.
 
-Degrade discipline matches ops/quant.py: the Pallas path is probed once
-per backend with a tiny eager call; any refusal falls back to the lax
-composition with a one-time warning — the fused trunk may lose its
-kernel, never the job. models/resnet.py threads this through every
-bottleneck via ``ResNetConfig.fused`` (on by default; the unfused
-GroupNorm path stays as the parity twin).
+On TPU the Pallas apply is called plainly: a shape the compiler refuses
+is an error at trace time, where it can be read — never a silent switch
+to the lax path. models/resnet.py threads this through every bottleneck
+via ``ResNetConfig.fused`` (on by default; the unfused GroupNorm path
+stays as the parity twin). Whether the kernel beats the lax composition
+on the chip is not measured (ROADMAP S5).
 """
 
 from __future__ import annotations
 
 import functools
-import logging
 from typing import Optional
 
 import jax
@@ -40,13 +37,13 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-log = logging.getLogger(__name__)
+from tony_tpu.compat import per_shard
+from tony_tpu.ops.attention import _interpret
+from tony_tpu.parallel.mesh import BATCH_AXES
 
 #: row-block for the Pallas apply kernel ([rows, C] tiles of the
 #: flattened [B, H·W, C] view).
 APPLY_BLOCK_ROWS = 256
-
-_pallas_fallback_reason: Optional[str] = None
 
 
 def group_stats(x: jax.Array, groups: int):
@@ -94,49 +91,49 @@ def _apply_kernel(x_ref, a_ref, b_ref, o_ref, *, relu):
     o_ref[0] = y.astype(o_ref.dtype)
 
 
-def _apply_pallas(x: jax.Array, a: jax.Array, b: jax.Array, relu: bool,
-                  interpret: bool) -> jax.Array:
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _apply_pallas(x: jax.Array, a: jax.Array, b: jax.Array,
+                  relu: bool) -> jax.Array:
     """One-HBM-pass apply: grid over (batch, row blocks) of the
-    flattened [B, H·W, C] view; a/b ride along as [1, C] blocks."""
+    flattened [B, H·W, C] view. a/b ride along as [B, 1, C] arrays in
+    (1, 1, C) blocks — the TPU lowering wants a block's last two dims
+    tile-aligned or equal to the array's, which a (1, C) block of a
+    [B, C] array is not for any B > 1. Under a bound mesh each device
+    applies its own batch shard (compat.per_shard)."""
     batch, c = x.shape[0], x.shape[-1]
     x2 = x.reshape(batch, -1, c)
-    rows = x2.shape[1]
-    block = min(APPLY_BLOCK_ROWS, rows)
-    grid = (batch, pl.cdiv(rows, block))
-    out = pl.pallas_call(
-        functools.partial(_apply_kernel, relu=relu),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block, c), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, c), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, c), lambda i, j: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block, c), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
-        interpret=interpret,
-    )(x2, a, b)
+
+    def call(x2, a3, b3):
+        rows = x2.shape[1]
+        block = min(APPLY_BLOCK_ROWS, rows)
+        row_spec = pl.BlockSpec((1, block, c), lambda i, j: (i, j, 0))
+        vec_spec = pl.BlockSpec((1, 1, c), lambda i, j: (i, 0, 0))
+        return pl.pallas_call(
+            functools.partial(_apply_kernel, relu=relu),
+            grid=(x2.shape[0], pl.cdiv(rows, block)),
+            in_specs=[row_spec, vec_spec, vec_spec],
+            out_specs=row_spec,
+            out_shape=jax.ShapeDtypeStruct(x2.shape, x2.dtype),
+            interpret=_interpret(),
+        )(x2, a3, b3)
+
+    out = per_shard(call, (BATCH_AXES,))(x2, a[:, None, :], b[:, None, :])
     return out.reshape(x.shape)
 
 
-@functools.lru_cache(maxsize=None)
-def _pallas_ok(backend: str) -> bool:
-    """Probe the Pallas apply once per backend (tiny eager call, CPU
-    interpret mode included); any refusal degrades to the lax path with
-    a one-time warning."""
-    global _pallas_fallback_reason
-    try:
-        x = jnp.ones((1, 8, 8), jnp.float32)
-        ab = jnp.ones((1, 8), jnp.float32)
-        out = _apply_pallas(x, ab, ab, True, backend != "tpu")
-        jax.block_until_ready(out)
-    except Exception as e:  # noqa: BLE001 — any refusal shape degrades
-        _pallas_fallback_reason = f"{type(e).__name__}: {e}"[:200]
-        log.warning(
-            "fused groupnorm Pallas apply unavailable on backend %r "
-            "(%s); DEGRADING to the fused lax composition (one-time "
-            "warning)", backend, _pallas_fallback_reason)
-        return False
-    return True
+def _apply_pallas_fwd(x, a, b, relu):
+    return _apply_pallas(x, a, b, relu), (x, a, b)
+
+
+def _apply_pallas_bwd(relu, res, dy):
+    # pallas_call has no reverse-mode rule. The backward is the lax
+    # composition's own VJP from the kernel's INPUTS — one XLA fusion, and
+    # the normalized output is never a residual.
+    _, vjp = jax.vjp(functools.partial(_apply_lax, relu=relu), *res)
+    return vjp(dy)
+
+
+_apply_pallas.defvjp(_apply_pallas_fwd, _apply_pallas_bwd)
 
 
 def fused_groupnorm_relu(x: jax.Array, scale: jax.Array, bias: jax.Array,
@@ -148,29 +145,24 @@ def fused_groupnorm_relu(x: jax.Array, scale: jax.Array, bias: jax.Array,
     sweep, one fused folded-affine apply. Numerically matches
     ``nn.relu(nn.GroupNorm(num_groups=groups)(x))`` to f32 tolerance.
 
-    ``use_pallas=None`` auto-selects: the Pallas kernel on TPU (probed
-    once, degrades to lax), interpret-mode Pallas only when forced
-    (unit tests), the lax composition otherwise. ``remat=True`` wraps
-    the apply in ``jax.checkpoint`` so backward recomputes it instead of
-    keeping the normalized activation resident."""
+    ``use_pallas=None`` selects from the devices in use: the Pallas
+    kernel on TPU, the lax composition elsewhere. ``True`` forces the
+    kernel (interpret mode off-TPU — the unit tests), ``False`` the lax
+    path. ``remat=True`` wraps the apply in ``jax.checkpoint`` so
+    backward recomputes it instead of keeping the normalized activation
+    resident."""
     c = x.shape[-1]
     if c % groups:
         raise ValueError(f"channels {c} not divisible by groups {groups}")
     mean, var = group_stats(x, groups)
     a, b = folded_affine(mean, var, scale, bias, c, eps)
 
-    backend = jax.default_backend()
     if use_pallas is None:
-        use_pallas = backend == "tpu" and _pallas_ok(backend)
-    elif use_pallas:
-        use_pallas = _pallas_ok(backend)
+        use_pallas = not _interpret()
+    fn = _apply_pallas if use_pallas else _apply_lax
 
-    if use_pallas:
-        def apply(x, a, b):
-            return _apply_pallas(x, a, b, relu, backend != "tpu")
-    else:
-        def apply(x, a, b):
-            return _apply_lax(x, a, b, relu)
+    def apply(x, a, b):
+        return fn(x, a, b, relu)
 
     if remat:
         apply = jax.checkpoint(apply)

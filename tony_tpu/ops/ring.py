@@ -42,8 +42,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tony_tpu.compat import shard_map
-
 from tony_tpu.ops.attention import DEFAULT_BLOCK, flash_attention_with_lse
 
 NEG_INF = -1e30
@@ -179,5 +177,5 @@ def ring_attention_sharded(mesh: Mesh, q: jax.Array, k: jax.Array,
     fn = functools.partial(ring_attention, axis_name=axis_name,
                            causal=causal, scale=scale,
                            block_q=block_q, block_k=block_k)
-    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
                      out_specs=spec, check_vma=False)(q, k, v)

@@ -56,7 +56,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tony_tpu import compat, telemetry
+from tony_tpu import telemetry
 from tony_tpu.parallel.mesh import (BATCH_AXES, replicated_sharding,
                                     tree_batch_shardings)
 from tony_tpu.parallel.sharding import DEFAULT_RULES
@@ -343,7 +343,7 @@ def jit_train_step_accum(
         donate_argnums=(0, 1) if donate else ())
 
     def step(state, batch, rng):
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             stacked, loss, aux = accum_jit(state.params, batch, rng)
             if comms_phase:
                 with telemetry.phase("comms") as p:

@@ -59,7 +59,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tony_tpu.compat import shard_map
 from tony_tpu.models.transformer import (Block, TransformerConfig,
                                          causal_lm_loss)
 
@@ -262,7 +261,7 @@ def pipeline_forward(cfg: TransformerConfig, mesh: Mesh,
 
     fn = functools.partial(_pipeline_blocks, cfg, num_microbatches,
                            fsdp_axes, n_fsdp)
-    x = shard_map(
+    x = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(blocks_spec, P(BATCH_AXES), P(BATCH_AXES)),
         out_specs=P(BATCH_AXES), check_vma=False,

@@ -18,7 +18,6 @@ import optax
 from flax import struct
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tony_tpu import compat
 from tony_tpu.parallel.mesh import tree_batch_shardings
 from tony_tpu.parallel.sharding import DEFAULT_RULES, param_shardings
 
@@ -72,7 +71,7 @@ def init_sharded_state(
     def init_fn(rng):
         return nn.meta.unbox(boxed_init(rng))
 
-    with compat.set_mesh(mesh), nn.logical_axis_rules(list(rules)):
+    with jax.set_mesh(mesh), nn.logical_axis_rules(list(rules)):
         state = jax.jit(init_fn, out_shardings=state_sh)(rng)
     return state, state_sh
 
@@ -90,7 +89,10 @@ def jit_train_step(
 
     Returns ``step(state, batch, rng) -> (state, metrics)`` compiled with
     explicit in/out shardings: batch sharded over (dp, fsdp) on dim 0, state
-    per ``state_shardings`` — XLA derives every collective from there.
+    per ``state_shardings`` — XLA derives every collective from there. The
+    mesh is bound (``jax.set_mesh``) around every call, which is what lets
+    the Pallas kernels in the model run per shard; ``step.lower(state,
+    batch, rng)`` lowers under the same binding, for ahead-of-time compiles.
     """
     def step(state: TrainState, batch: Any, rng: jax.Array):
         with nn.logical_axis_rules(list(rules)):
@@ -112,7 +114,15 @@ def jit_train_step(
         donate_argnums=(0,) if donate else ())
 
     def wrapped(state, batch, rng):
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             return jitted(state, batch, rng)
 
+    def lower(state, batch, rng):
+        """``jax.jit(...).lower`` under the same bound mesh — accepts
+        ``ShapeDtypeStruct``s, so the step can be compiled ahead of time
+        for devices this host does not have (a TPU topology)."""
+        with jax.set_mesh(mesh):
+            return jitted.lower(state, batch, rng)
+
+    wrapped.lower = lower
     return wrapped

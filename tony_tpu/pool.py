@@ -115,11 +115,13 @@ def _pid_alive(pid: int) -> bool:
 # ---------------------------------------------------------------------------
 def _preload(preload: str) -> List[str]:
     """Import the configured modules while idle — the whole point of being
-    warm. ``jax`` additionally initializes the backend (device scan +
-    plugin load, the multi-second part) so an adopted executor's own
-    tooling — and, via the hot OS page cache, the user process's import
-    of the same libraries — starts fast. Failures are logged and skipped:
-    a pool on a CPU-only host must still warm the rest."""
+    warm: the adopted executor's own tooling and, via the hot OS page
+    cache, the user process's import of the same libraries start fast.
+    Preload IMPORTS; it never initializes a backend. A chip belongs to
+    one process, and this worker becomes the executor that spawns the
+    user process which needs it — a backend brought up here would hold
+    the chip against its own task. Failures are logged and skipped: a
+    pool on a host without a module must still warm the rest."""
     import importlib
 
     done: List[str] = []
@@ -130,9 +132,7 @@ def _preload(preload: str) -> List[str]:
     mods += [m.strip() for m in (preload or "").split(",") if m.strip()]
     for mod in mods:
         try:
-            m = importlib.import_module(mod)
-            if mod == "jax":
-                m.devices()          # backend init, not just import
+            importlib.import_module(mod)
             done.append(mod)
         except Exception as e:  # noqa: BLE001 — warm what we can
             log.warning("preload of %s failed: %s", mod, e)
@@ -355,9 +355,8 @@ class PoolDaemon:
         env["PYTHONPATH"] = (repo_root + os.pathsep +
                              env.get("PYTHONPATH", "")).rstrip(os.pathsep)
         if self.jax_cache_dir:
-            # Mount the persistent compile cache for the warm backend
-            # init AND for the user processes the adopted executor will
-            # spawn (they inherit the executor env).
+            # Mount the persistent compile cache for the user processes
+            # the adopted executor will spawn (they inherit its env).
             env.setdefault(constants.JAX_COMPILATION_CACHE_DIR,
                            os.path.expanduser(self.jax_cache_dir))
         wlog = open(os.path.join(wdir, "worker.log"), "ab")

@@ -1,9 +1,9 @@
 """User-process-side accelerator telemetry reporter.
 
 The executor's TaskMonitor samples process-tree RSS fine, but HBM belongs
-to the *user* process — the one that initialized the TPU runtime — so a
-monitor-side ``jax.local_devices()`` always reads 0 (round-1 VERDICT weak
-#7; the reference has the same split: ``TaskMonitor.java`` samples inside
+to the *user* process — the one that initialized the TPU runtime and so
+holds the chips; the executor must never bring up a backend of its own
+(the reference has the same split: ``TaskMonitor.java`` samples inside
 the container alongside the training process, :109-170).
 
 Mechanism: the executor exports ``TONY_METRICS_FILE`` into the user
@@ -48,16 +48,32 @@ _step_lock = threading.Lock()
 _steps = {"count": 0, "busy_s": 0.0, "flops": 0.0, "tokens": 0.0,
           "first_start": 0.0, "last_end": 0.0, "first_end_wall": 0.0}
 
-# Public peak bf16 matmul FLOP/s per chip (spec sheets), for the MFU derive.
+# Peak dense bf16 matmul FLOP/s of one device — THE table (bench.py and
+# chip_smoke.py read it from here). Keys are the exact ``device_kind``
+# strings jax reports, as libtpu 0.0.34 names them (read off
+# ``jax.experimental.topologies`` for v4 / v5e / v5p / v6e); values are
+# Google Cloud's per-chip figures ("System architecture" pages for TPU v4,
+# v5e, v5p and v6e). No prefix matching: a kind that is not a key has no
+# peak — a measurement raises (``peak_bf16_flops``), the reporter below
+# leaves the MFU field out.
 PEAK_BF16_FLOPS = {
     "TPU v4": 275e12,
-    "TPU v5 lite": 197e12,
-    "TPU v5e": 197e12,
-    "TPU v5p": 459e12,
-    "TPU v5": 459e12,
-    "TPU v6 lite": 918e12,
-    "TPU v6e": 918e12,
+    "TPU v5 lite": 197e12,      # v5e
+    "TPU v5": 459e12,           # v5p
+    "TPU v6 lite": 918e12,      # v6e (Trillium)
 }
+
+
+def peak_bf16_flops(device_kind: str) -> float:
+    """The table's peak for exactly this ``device_kind``; KeyError (naming
+    the known kinds) for any other — never a default."""
+    try:
+        return PEAK_BF16_FLOPS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no bf16 peak on record for device_kind {device_kind!r} "
+            f"(known: {sorted(PEAK_BF16_FLOPS)}); add it to "
+            f"tony_tpu.telemetry.PEAK_BF16_FLOPS with its source") from None
 
 
 # ---------------------------------------------------------------------------
@@ -433,8 +449,7 @@ def collect_device_stats() -> Dict[str, float]:
     if util:
         out.update(util)
         kind = per_device[0]["kind"] if per_device else ""
-        peak_fl = next((v for k, v in PEAK_BF16_FLOPS.items()
-                        if str(kind).startswith(k)), None)
+        peak_fl = PEAK_BF16_FLOPS.get(str(kind))
         if jax is not None and peak_fl \
                 and util.get("model_flops_per_sec"):
             # flops passed to step() are the model's GLOBAL per-step FLOPs
