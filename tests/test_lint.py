@@ -59,6 +59,8 @@ def test_conf_key_bad_and_clean(tmp_path):
         C = "tony.fault"                     # family prefix mention
         D = "job.tony.json"                  # a file name, not a key
         E = f"tony.trace.enabled={1}"        # key inside an f-string
+        F = "tony.step tony.phase.data_wait"  # profiler-trace names
+        G = "tony.loss_and_grad/tony.optimizer" + "tony.phase." + "x"
     ''', ["conf-key"])
     assert clean == []
 

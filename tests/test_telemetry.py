@@ -89,7 +89,7 @@ def test_step_stats_derivation():
     assert 0.3 < s["step_duty_cycle"] < 1.0
     assert s["model_flops_per_sec"] > 0
     assert s["tokens_per_sec"] > 0
-    assert s["mean_step_s"] >= 0.02
+    assert s["steps_per_sec"] <= 3 / 0.08      # 3 × 20 ms busy, 2 × 10 idle
 
 
 def test_mfu_needs_an_exact_peak_table_key(monkeypatch):
@@ -121,3 +121,190 @@ def test_mfu_needs_an_exact_peak_table_key(monkeypatch):
         with pytest.raises(KeyError, match="no bf16 peak on record"):
             telemetry.peak_bf16_flops(kind)
     assert telemetry.peak_bf16_flops("TPU v5") == 459e12
+
+
+# ---------------------------------------------------------------------------
+# Spans of the user process: profiler annotations, boot and compile spans
+# ---------------------------------------------------------------------------
+def _reset_steps():
+    telemetry._steps.update(count=0, busy_s=0.0, flops=0.0, tokens=0.0,
+                            first_start=0.0, last_end=0.0,
+                            first_end_wall=0.0)
+    telemetry._reset_span_state()
+
+
+def test_step_and_phase_reach_a_profiler_capture(tmp_path):
+    """A jax.profiler capture holds ``tony.step`` host events (one a
+    step) and ``tony.phase.data_wait`` nested in time inside them: the
+    loop's spans are on the profiler's clock, beside the device's."""
+    import glob
+    import time as _t
+
+    import jax
+    import jax.numpy as jnp
+
+    _reset_steps()
+    x = jnp.ones((64, 64))
+    jax.block_until_ready(x @ x)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(2):
+            with telemetry.step():
+                with telemetry.phase("data_wait"):
+                    _t.sleep(0.002)
+                jax.block_until_ready(x @ x)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    events = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for plane in data.planes for line in plane.lines
+              for e in line.events if e.name.startswith("tony.")]
+    steps = sorted(e for e in events if e[0] == "tony.step")
+    waits = sorted(e for e in events if e[0] == "tony.phase.data_wait")
+    assert len(steps) == 2 and len(waits) == 2, events
+    for (_, s0, s1), (_, w0, w1) in zip(steps, waits):
+        assert s0 <= w0 and w1 <= s1
+        assert w1 - w0 >= 2e6          # the sleep, in nanoseconds
+
+
+def test_step_and_phase_without_jax_never_import_it():
+    """In an interpreter that has not loaded jax, step and phase work as
+    before and jax stays unloaded: the annotations and listeners exist
+    only where the user's own code brought jax in."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from tony_tpu import telemetry\n"
+        "for _ in range(2):\n"
+        "    with telemetry.step(tokens=4):\n"
+        "        with telemetry.phase('data_wait'):\n"
+        "            pass\n"
+        "assert telemetry.step_stats()['steps_completed'] == 2\n"
+        "assert 'data_wait' in telemetry.phase_stats()['cum']\n"
+        "assert not telemetry.install_jax_hooks()\n"
+        "assert 'jax' not in sys.modules, 'jax was imported'\n")
+    env = dict(os.environ, PYTHONPATH=os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))))
+    done = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert done.returncode == 0, done.stderr
+
+
+def test_compiles_become_spans_and_counters():
+    """Each jit compile is ``user.compile`` spans (trace, lower, backend)
+    with the function's name; a cached call is none; a new shape after a
+    step is a recompile that says at which step it happened."""
+    import jax
+    import jax.numpy as jnp
+
+    a, b = jnp.ones((4, 4)), jnp.ones((8, 4))
+    jax.block_until_ready((a, b))
+    _reset_steps()
+    assert telemetry.install_jax_hooks()
+
+    @jax.jit
+    def tony_test_compile_target(x):
+        return jnp.tanh(x).sum()
+
+    def mine():
+        return [s for s in telemetry.span_stats().get("spans", [])
+                if s["name"] == "user.compile"
+                and "tony_test_compile_target" in s["args"]["fun_name"]]
+
+    tony_test_compile_target(a)
+    first = mine()
+    assert [s["args"]["stage"] for s in first] == ["trace", "lower",
+                                                   "backend"]
+    assert all(s["args"]["step"] == 0 and s["end"] >= s["start"]
+               for s in first)
+    tony_test_compile_target(a)
+    assert mine() == first                       # cached: no new span
+    with telemetry.step():
+        pass
+    tony_test_compile_target(b)
+    again = mine()[len(first):]
+    assert [s["args"]["stage"] for s in again] == ["trace", "lower",
+                                                   "backend"]
+    assert all(s["args"]["step"] >= 1 for s in again)
+    stats = telemetry.span_stats()
+    assert stats["compiles"] == 2
+    assert stats["compiles_after_first_step"] == 1
+    assert stats["compile_seconds"] > 0
+    assert telemetry.collect_device_stats()["compiles"] == 2
+
+
+def test_span_list_has_its_own_file_rewritten_only_when_it_grew(tmp_path):
+    """The metrics file, rewritten every tick, carries the counters and
+    ``spans_kept``; the list lies beside it and is written again only when
+    a span was added (its size in every tick cost slow steps on the chip)."""
+    _reset_steps()
+    path = str(tmp_path / "m.json")
+    telemetry.record_span("user.pre_import", 10.0, 12.5)
+    assert telemetry.write_stats_once(path)
+    stats = telemetry.read_stats(path)
+    assert "spans" not in stats
+    assert stats["spans_kept"] == 1 and stats["spans_dropped"] == 0
+    assert stats["compiles"] == 0
+    listed = telemetry.read_stats(telemetry.spans_file(path))
+    assert listed["pid"] == stats["pid"] == os.getpid()
+    assert [(s["seq"], s["name"], s["start"], s["end"])
+            for s in listed["spans"]] == [(1, "user.pre_import", 10.0, 12.5)]
+    os.unlink(telemetry.spans_file(path))
+    assert telemetry.write_stats_once(path)          # nothing new: no list
+    assert not os.path.exists(telemetry.spans_file(path))
+    telemetry.record_span("user.compile", 13.0, 13.5, stage="backend")
+    assert telemetry.write_stats_once(path)
+    assert len(telemetry.read_stats(
+        telemetry.spans_file(path))["spans"]) == 2
+    assert telemetry.read_stats(path)["spans_kept"] == 2
+    _reset_steps()
+
+
+def test_span_list_stops_at_its_cap_and_counts_the_rest():
+    _reset_steps()
+    for i in range(telemetry.SPAN_CAP + 5):
+        telemetry.record_span("user.test", float(i), float(i) + 0.5, i=i)
+    stats = telemetry.span_stats()
+    assert len(stats["spans"]) == telemetry.SPAN_CAP
+    assert stats["spans_dropped"] == 5
+    assert [s["seq"] for s in stats["spans"]] == list(
+        range(1, telemetry.SPAN_CAP + 1))
+    _reset_steps()
+    assert telemetry.span_stats() == {}
+
+
+@pytest.mark.filterwarnings(
+    "ignore::pytest.PytestUnhandledThreadExceptionWarning")
+def test_a_new_span_wakes_the_reporter(tmp_path, monkeypatch):
+    """The reporter sleeps a whole interval between writes unless a span
+    was added: then the list goes out at once, not a tick later."""
+    import threading
+    import time as _t
+
+    _reset_steps()
+    telemetry._span_added.clear()
+    writes = []
+
+    def fake_write(path):
+        writes.append(_t.monotonic())
+        if len(writes) == 2:
+            raise SystemExit          # ends the reporter thread, quietly
+
+    monkeypatch.setattr(telemetry, "write_stats_once", fake_write)
+    reporter = threading.Thread(target=telemetry._loop,
+                                args=(str(tmp_path / "m.json"), 60.0),
+                                daemon=True)
+    reporter.start()
+    deadline = _t.monotonic() + 10
+    while not writes and _t.monotonic() < deadline:
+        _t.sleep(0.01)
+    assert len(writes) == 1
+    telemetry.record_span("user.compile", 1.0, 2.0, stage="backend")
+    reporter.join(timeout=10)
+    assert not reporter.is_alive()
+    assert 0 < writes[1] - writes[0] < 10          # not the 60 s interval
+    _reset_steps()

@@ -367,3 +367,159 @@ def test_cold_start_breakdown_clamps_out_of_window_boundaries():
             r["ts_us"] = 11_000_000          # pathological: after the end
     bd = tracing.cold_start_breakdown(recs)
     assert round(sum(bd["phases"].values()), 6) == bd["total_s"] == 10.0
+
+
+# ---------------------------------------------------------------------------
+# The user process's own spans: user_boot split, executor forwarding
+# ---------------------------------------------------------------------------
+def _user_spans(task="worker:0"):
+    """What the executor forwards of a user process's boot, inside the
+    5 s user_boot phase (5.0 s → 10.0 s) of ``_cold_start_records``."""
+    return [
+        _x("user.pre_import", 5_100_000, 1_900_000, task=task),  # → 7.0
+        _x("user.init_state", 7_200_000, 1_000_000, task=task),  # → 8.2
+        # inside init_state: its compile is the child, not double-counted
+        _x("user.compile", 7_300_000, 600_000, task=task, stage="backend"),
+        _x("user.compile", 8_500_000, 1_200_000, task=task, stage="trace"),
+        # after the first step's end (10.0 s): outside the phase
+        _x("user.compile", 10_500_000, 300_000, task=task, stage="lower"),
+        # another task's span never counts
+        _x("user.compile", 5_000_000, 4_000_000, task="worker:9"),
+    ]
+
+
+def test_cold_start_breakdown_splits_user_boot_into_self_times():
+    bd = tracing.cold_start_breakdown(_cold_start_records()
+                                      + _user_spans())
+    assert bd["user_boot"] == {
+        "user.pre_import": 1.9,
+        "user.init_state": 0.4,          # 1.0 less the 0.6 inside it
+        "user.compile": 1.8,             # 0.6 + 1.2; the late one is out
+        "unattributed": 0.9}
+    assert round(sum(bd["user_boot"].values()), 6) \
+        == bd["phases"]["user_boot"] == 5.0
+
+
+def test_cold_start_breakdown_is_unchanged_without_user_spans():
+    plain = tracing.cold_start_breakdown(_cold_start_records())
+    assert "user_boot" not in plain
+    with_spans = tracing.cold_start_breakdown(_cold_start_records()
+                                              + _user_spans())
+    for key in ("total_s", "task", "phases", "span_durations"):
+        assert with_spans[key] == plain[key], key
+    assert list(plain) == ["total_s", "task", "phases", "span_durations"]
+    assert plain["phases"]["user_boot"] == 5.0
+    assert plain["span_durations"]["executor.first_step"] == 1.0
+
+
+def _executor(tmp_path, monkeypatch, trace_id):
+    from tony_tpu import constants
+    from tony_tpu.executor.executor import TaskExecutor
+
+    monkeypatch.chdir(tmp_path)
+    env = {constants.JOB_NAME: "worker", constants.TASK_INDEX: "0",
+           constants.TASK_NUM: "1", constants.COORDINATOR_HOST: "127.0.0.1",
+           constants.COORDINATOR_PORT: "1"}
+    if trace_id:
+        env[constants.TRACE_ID_ENV] = trace_id
+    ex = TaskExecutor(env=env)
+    ex._metrics_file = str(tmp_path / "user-metrics.json")
+    ex._run_span = ex.tracer.start_span("executor.run", task=ex.task_id)
+    return ex
+
+
+def _write_user_metrics(ex, pid, spans, spans_pid=None):
+    """The user process's two files, as telemetry.write_stats_once leaves
+    them: the span list, then the metrics file that counts it."""
+    import json
+
+    from tony_tpu import telemetry
+
+    with open(telemetry.spans_file(ex._metrics_file), "w",
+              encoding="utf-8") as f:
+        json.dump({"pid": spans_pid or pid, "spans": spans}, f)
+    with open(ex._metrics_file, "w", encoding="utf-8") as f:
+        json.dump({"pid": pid, "device_count": 1.0,
+                   "spans_kept": len(spans), "spans_dropped": 0}, f)
+
+
+def _user_span(seq, name, start, end, **args):
+    return {"seq": seq, "name": name, "start": start, "end": end,
+            "args": args}
+
+
+def test_executor_forwards_each_user_span_once_under_the_run_span(
+        tmp_path, monkeypatch):
+    import os
+
+    from tony_tpu import telemetry
+
+    ex = _executor(tmp_path, monkeypatch, trace_id="feedfacefeedface")
+    boot = [_user_span(1, "user.pre_import", 100.0, 102.5),
+            _user_span(2, "user.compile", 103.0, 103.25, stage="backend",
+                       fun_name="jit(step)", step=0)]
+    _write_user_metrics(ex, 4242, boot)
+    ex._progress_beacon()
+    # The same files: nothing new, and the list is not even opened.
+    os.unlink(telemetry.spans_file(ex._metrics_file))
+    ex._progress_beacon()
+    _write_user_metrics(ex, 4242, boot + [
+        _user_span(3, "user.compile", 900.0, 901.0, stage="backend",
+                   fun_name="jit(step)", step=4000),
+        {"seq": "garbage"}])
+    ex._progress_beacon()
+    got = [r for r in ex.tracer.drain() if r["name"].startswith("user.")]
+    assert [(r["name"], r["ts_us"], r["dur_us"]) for r in got] == [
+        ("user.pre_import", 100_000_000, 2_500_000),
+        ("user.compile", 103_000_000, 250_000),
+        ("user.compile", 900_000_000, 1_000_000)]
+    assert all(r["parent"] == ex._run_span.span_id
+               and r["task"] == "worker:0" and r["ev"] == "X"
+               and r["trace"] == "feedfacefeedface" for r in got)
+    assert got[2]["args"] == {"stage": "backend", "fun_name": "jit(step)",
+                              "step": 4000}
+    # A relaunched user process (another pid) numbers its spans anew; a
+    # list that the process before it left behind is not its list.
+    relaunched = [_user_span(1, "user.pre_import", 2000.0, 2001.0)]
+    _write_user_metrics(ex, 4343, relaunched, spans_pid=4242)
+    ex._progress_beacon()
+    assert ex.tracer.drain() == []
+    _write_user_metrics(ex, 4343, relaunched)
+    ex._progress_beacon()
+    again = [r for r in ex.tracer.drain() if r["name"].startswith("user.")]
+    assert [r["ts_us"] for r in again] == [2_000_000_000]
+
+
+def test_executor_forwards_no_user_span_with_tracing_off(tmp_path,
+                                                         monkeypatch):
+    # tony.trace.enabled=false: the coordinator exports no trace id.
+    ex = _executor(tmp_path, monkeypatch, trace_id="")
+    assert not ex.tracer.enabled
+    _write_user_metrics(ex, 4242, [
+        _user_span(1, "user.pre_import", 100.0, 102.5)])
+    ex._progress_beacon()
+    assert ex.tracer.drain() == []
+    assert ex._user_span_fence == (None, 0)
+
+
+def test_cli_cold_start_prints_user_boot_sub_lines(tmp_path, capsys):
+    import json
+
+    from tony_tpu import constants
+    from tony_tpu.cli.main import main
+
+    job_dir = tmp_path / constants.HISTORY_INTERMEDIATE / "app_x"
+    job_dir.mkdir(parents=True)
+    with open(job_dir / constants.TRACE_FILE, "w", encoding="utf-8") as f:
+        for rec in _cold_start_records() + _user_spans():
+            f.write(json.dumps(rec) + "\n")
+    assert main(["trace", "app_x", "--cold-start",
+                 "--history-root", str(tmp_path)]) == 0
+    lines = capsys.readouterr().out.splitlines()
+    at = next(i for i, line in enumerate(lines)
+              if line.startswith("  user_boot"))
+    assert lines[at].split()[1] == "5.00s"
+    sub = {line.split()[0]: line.split()[1] for line in lines[at + 1:at + 5]}
+    assert sub == {"user.pre_import": "1.90s", "user.init_state": "0.40s",
+                   "user.compile": "1.80s", "unattributed": "0.90s"}
+    assert all(line.startswith("    ") for line in lines[at + 1:at + 5])

@@ -65,3 +65,8 @@ _telemetry.install_stack_dump_handler()
 from tony_tpu import faults as _faults  # noqa: E402
 
 _faults.install_from_env()
+
+# Inside a task, process start → here is the first span of the user
+# process's boot (``user.pre_import``, telemetry.py); no-op — one env read
+# — everywhere else.
+_telemetry.mark_import_done()

@@ -635,6 +635,10 @@ def _cmd_trace(args: argparse.Namespace) -> int:
         for phase, secs in bd["phases"].items():
             bar = "#" * min(60, int(60 * secs / max(bd["total_s"], 1e-9)))
             print(f"  {phase:<10}{secs:>8.2f}s  {bar}")
+            if phase == "user_boot":
+                # The user process's own spans, as self times.
+                for name, sub in bd.get("user_boot", {}).items():
+                    print(f"    {name:<20}{sub:>8.2f}s")
         if bd["span_durations"]:
             print("  raw span durations (may overlap):")
             for name, secs in sorted(bd["span_durations"].items()):

@@ -19,6 +19,8 @@ Rules (ids are what ``# tony: lint-ignore[<rule>]`` suppresses):
 conf-key        every ``tony.*`` dotted token in a string literal outside
                 ``conf/keys.py`` must resolve to a registered ConfigKey, a
                 dynamic per-jobtype key, or a registered key family prefix
+                (the user process's profiler-trace names, ``tony.step`` and
+                the like, are the one other ``tony.*`` namespace)
 fault-site      ``faults.fire/check/fire_amount/check_partition`` call
                 sites use literal site names from ``faults.SITES``; every
                 listed site has at least one call site (both directions,
@@ -107,6 +109,11 @@ RULES.update(RULES_RACE)
 _SUPPRESS_RE = re.compile(r"tony:\s*lint-ignore\[([a-z\-]+)\]")
 _KEY_TOKEN_RE = re.compile(
     r"tony\.[a-z][a-z0-9_\-]*(?:\.[a-z0-9_\-]+)*")
+#: ``tony.*`` names that are no config keys: what the user process calls
+#: its host spans and jit scopes in a profiler trace (telemetry.step and
+#: telemetry.phase, parallel/train.py jit_train_step).
+_TRACE_NAME_RE = re.compile(
+    r"^tony\.(step|phase(\.[a-z0-9_\-]+)?|loss_and_grad|optimizer)$")
 #: dotted tokens whose last segment is one of these are file names
 #: ("job.tony.json", "tony.xml"), not config-key references
 _FILE_EXTS = ("xml", "json", "jsonl", "yaml", "yml", "md", "py", "log",
@@ -302,6 +309,8 @@ class Linter:
                     continue
                 if tok.rsplit(".", 1)[-1] in _FILE_EXTS:
                     continue    # "job.tony.json": a file name, not a key
+                if _TRACE_NAME_RE.match(tok):
+                    continue    # "tony.step": a name in a profiler trace
                 # prose mention of a key family ("tony.fault.<site>",
                 # "tony.application.security.tls-*")
                 if any(k.startswith(tok + ".") for k in registered):

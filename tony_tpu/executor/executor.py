@@ -318,6 +318,11 @@ class TaskExecutor:
         self._trace_ctx: Optional[tuple] = None
         self._user_start_us = 0
         self._first_step_emitted = False
+        # The user process's own spans (telemetry.record_span) already in
+        # the tracer: (pid of the process that numbered them, last seq).
+        # The heartbeat thread and the final read both forward.
+        self._user_span_lock = threading.Lock()
+        self._user_span_fence: tuple = (None, 0)
         self._monitor: Optional[TaskMonitor] = None
         self.client = self._make_client(self.coordinator_host,
                                         self.coordinator_port)
@@ -454,6 +459,7 @@ class TaskExecutor:
         from tony_tpu import telemetry
 
         stats = telemetry.read_stats(self._metrics_file)
+        self._forward_user_spans(stats)
         beacon: Dict[str, object] = {}
         steps = stats.get("steps_completed")
         if steps is not None:
@@ -512,6 +518,47 @@ class TaskExecutor:
                          end_us=max(end_us, self._user_start_us),
                          parent=self._run_span, task=self.task_id,
                          attrs={"steps_at_observation": steps})
+
+    def _forward_user_spans(self, stats: dict) -> None:
+        """The spans the user process closed itself (boot phases, every
+        compile: telemetry.record_span) → the job's span log, each once,
+        under this task's run span, with the wall timestamps the user
+        process took. The list is short by construction (nothing per
+        step) and lies in a file of its own (telemetry.spans_file), which
+        is read only when the metrics file says it has grown: after boot,
+        when something rare happened — a recompile."""
+        kept = stats.get("spans_kept")
+        if not self.tracer.enabled or not isinstance(kept, int):
+            return
+        from tony_tpu import telemetry
+
+        with self._user_span_lock:
+            pid, last = self._user_span_fence
+            if stats.get("pid") != pid:
+                # A relaunched user process (elastic park) numbers anew.
+                pid, last = stats.get("pid"), 0
+            spans: list = []
+            if kept > last:
+                # The list has its own file, read only when it has grown.
+                listed = telemetry.read_stats(
+                    telemetry.spans_file(self._metrics_file))
+                if listed.get("pid") == pid:
+                    spans = listed.get("spans") or []
+            for span in spans:
+                try:
+                    seq = int(span["seq"])
+                    if seq <= last:
+                        continue
+                    self.tracer.emit(
+                        str(span["name"]),
+                        start_us=int(float(span["start"]) * 1e6),
+                        end_us=int(float(span["end"]) * 1e6),
+                        parent=self._run_span, task=self.task_id,
+                        attrs=dict(span.get("args") or {}))
+                    last = seq
+                except (KeyError, TypeError, ValueError):
+                    continue
+            self._user_span_fence = (pid, last)
 
     def _dump_user_stacks(self) -> None:
         """Coordinator declared this task HUNG: deliver the dump signal so

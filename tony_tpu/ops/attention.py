@@ -445,6 +445,7 @@ def _fwd_call(q, k, v, *, scale, causal, block_q, block_k, out_dtype):
              pltpu.VMEM((block_q, 1), jnp.float32),
              pltpu.VMEM((block_q, d), jnp.float32)]),
         interpret=_interpret(),
+        name="flash_fwd",
     )(q, k, v)
     return o, lse
 
@@ -504,6 +505,7 @@ def _bwd_call(q, k, v, do, lse, delta, *, scale, causal, block_q, block_k):
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_interpret(),
+        name="flash_dq",
     )(q, k, v, do, lse, delta)
 
     # dk/dv: one grid cell per kv head; the g q-head group members are
@@ -555,6 +557,7 @@ def _bwd_call(q, k, v, do, lse, delta, *, scale, causal, block_q, block_k):
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

@@ -115,6 +115,7 @@ def _apply_pallas(x: jax.Array, a: jax.Array, b: jax.Array,
             out_specs=row_spec,
             out_shape=jax.ShapeDtypeStruct(x2.shape, x2.dtype),
             interpret=_interpret(),
+            name="groupnorm_relu",
         )(x2, a3, b3)
 
     out = per_shard(call, (BATCH_AXES,))(x2, a[:, None, :], b[:, None, :])

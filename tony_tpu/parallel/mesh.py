@@ -32,6 +32,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from tony_tpu import telemetry
+
 # Outermost (slow, DCN-tolerant) → innermost (fast, wants ICI neighbours).
 # ``dcn_dp`` is the multislice axis: pure data parallelism ACROSS slices,
 # whose only collective (the gradient psum) is the one thing DCN bandwidth
@@ -119,7 +121,12 @@ def build_mesh(spec: Optional[MeshSpec] = None,
     torus so innermost axes land on ICI neighbours; on a host-platform
     (CPU test) mesh the devices are virtual and a plain reshape suffices.
     """
-    devices = list(devices if devices is not None else jax.devices())
+    if devices is None:
+        # The call that brings the backend (libtpu) up in a job that lets
+        # the mesh find its devices: a boot span of the user process.
+        with telemetry.span("user.backend_init"):
+            devices = jax.devices()
+    devices = list(devices)
     spec = (spec or MeshSpec()).resolve(len(devices))
     sizes = spec.sizes()
     if devices[0].platform == "tpu":

@@ -18,6 +18,7 @@ import optax
 from flax import struct
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from tony_tpu import telemetry
 from tony_tpu.parallel.mesh import tree_batch_shardings
 from tony_tpu.parallel.sharding import DEFAULT_RULES, param_shardings
 
@@ -38,6 +39,7 @@ class TrainState:
                             opt_state=new_opt)
 
 
+@telemetry.span("user.init_state")     # host time: trace, compile, dispatch
 def init_sharded_state(
     model: nn.Module,
     sample_batch: Any,
@@ -95,10 +97,16 @@ def jit_train_step(
     batch, rng)`` lowers under the same binding, for ahead-of-time compiles.
     """
     def step(state: TrainState, batch: Any, rng: jax.Array):
-        with nn.logical_axis_rules(list(rules)):
+        # Two scopes, so that every fusion's op_name metadata says which
+        # part of the step it belongs to: jax stamps jvp(...) and
+        # transpose(jvp(...)) inside the first, which makes forward,
+        # backward and optimizer three disjoint prefixes in a device trace.
+        with nn.logical_axis_rules(list(rules)), \
+                jax.named_scope("tony.loss_and_grad"):
             (loss, aux), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(state.params, batch, rng)
-        new_state = state.apply_gradients(grads)
+        with jax.named_scope("tony.optimizer"):
+            new_state = state.apply_gradients(grads)
         metrics = {"loss": loss, "step": new_state.step, **aux}
         return new_state, metrics
 

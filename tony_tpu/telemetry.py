@@ -16,7 +16,9 @@ numbers without the user writing a line of code. Scripts that never import
 
 The reporter NEVER imports jax itself: it only reads stats once the user's
 own code has brought the runtime up (jax present in sys.modules), so a
-non-JAX task doesn't get a TPU runtime forced into it.
+non-JAX task doesn't get a TPU runtime forced into it. The same holds for
+the spans of the user process (further down): the profiler annotations
+and the compile listeners exist only where ``jax`` is already loaded.
 """
 
 from __future__ import annotations
@@ -28,7 +30,7 @@ import os
 import sys
 import threading
 import time
-from typing import Deque, Dict, Optional
+from typing import Any, Deque, Dict, List, Optional
 
 from tony_tpu import constants
 
@@ -112,8 +114,210 @@ _phase_acc: Dict[str, float] = {}   # seconds since the last step boundary
 _phase_cum: Dict[str, float] = {}   # job-cumulative, folded per step
 _phase_wall_cum = 0.0               # cumulative attribution wall
 _phase_steps = 0
-_phase_ring: Deque[dict] = collections.deque(
-    maxlen=max(8, int(os.environ.get("TONY_PHASE_RING_STEPS", "") or 256)))
+#: steps the recent-means ring holds.
+PHASE_RING_STEPS = 256
+_phase_ring: Deque[dict] = collections.deque(maxlen=PHASE_RING_STEPS)
+
+# ---------------------------------------------------------------------------
+# Spans of the user process itself. Two sinks, two clocks:
+#
+# - the PROFILER's trace, on its clock: ``step()`` and ``phase(name)`` open
+#   a ``jax.profiler`` annotation (``tony.step`` with its ``step_num``,
+#   ``tony.phase.<name>``) for their duration, so any capture — the
+#   benchmark's traced steps, ``profiler.trace_window``, ``tony-tpu
+#   profile`` — shows the loop's host spans beside the device's operations
+#   and an idle gap has an owner. With no capture running an annotation is
+#   one TraceMe object that records nothing.
+# - the job's SPAN LOG, on the wall clock: a short bounded list of closed
+#   spans (``record_span``), and the executor emits each once under the
+#   task's run span (executor._forward_user_spans). Only what is rare
+#   goes this way — boot (``user.pre_import``, ``user.backend_init``,
+#   ``user.init_state``) and every compile (``user.compile``: a recompile
+#   at step 4,000 shows with its step) — never a per-step span. The list
+#   has a file of its own beside the metrics file (``spans_file``),
+#   rewritten only when it grew; the metrics file, rewritten every tick,
+#   carries the counters and ``spans_kept``, which tells the executor
+#   when to look. The list inside the metrics file was measured: the
+#   13 KB it added to every tick put a step 60–110 ms long into most 40 s
+#   windows of the shortest-step cell, with the parent's code as well
+#   (PERF.md, PR 25).
+# ---------------------------------------------------------------------------
+#: closed spans kept for the executor; past the cap only the count grows.
+SPAN_CAP = 256
+#: the stages of one jit compile, as ``jax.monitoring`` names their spans.
+COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+
+#: the persistent compile cache's events, and the counter each feeds.
+CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "compile_cache_hits",
+    "/jax/compilation_cache/cache_misses": "compile_cache_misses",
+}
+
+#: how long after a new span the reporter writes the list out.
+SPAN_FLUSH_DELAY_S = 0.1
+
+_span_lock = threading.Lock()
+_span_added = threading.Event()     # wakes the reporter (``_loop``)
+_spans: List[Dict[str, Any]] = []
+_spans_closed = 0                   # kept + dropped; a span's ``seq``
+_compile_stats = {"compiles": 0, "compile_cache_hits": 0,
+                  "compile_cache_misses": 0, "compile_seconds": 0.0,
+                  "compiles_after_first_step": 0}
+_compile_nesting = threading.local()
+_jax_hooks_installed = False
+_spans_written: tuple = ("", 0)     # (metrics path, spans in its spans file)
+
+
+def record_span(name: str, start: float, end: float, **attrs: Any) -> None:
+    """Keep one closed span of this process (wall-clock seconds) for the
+    job's span log. Past ``SPAN_CAP`` the span is counted and dropped."""
+    global _spans_closed
+    with _span_lock:
+        _spans_closed += 1
+        if len(_spans) < SPAN_CAP:
+            _spans.append({"seq": _spans_closed, "name": name,
+                           "start": start, "end": max(end, start),
+                           "args": attrs})
+            _span_added.set()
+
+
+@contextlib.contextmanager
+def span(name: str, **attrs: Any):
+    """``record_span`` around a block: wall-anchored start, monotonic
+    duration (the span log's own rule, tracing.py)."""
+    install_jax_hooks()
+    start, t0 = time.time(), time.monotonic()
+    try:
+        yield
+    finally:
+        record_span(name, start, start + time.monotonic() - t0, **attrs)
+
+
+def span_stats() -> Dict[str, Any]:
+    """The kept spans, how many there are and how many were dropped, and
+    the compile counters; {} before the first span."""
+    with _span_lock:
+        if not _spans_closed:
+            return {}
+        return {"spans": list(_spans), "spans_kept": len(_spans),
+                "spans_dropped": _spans_closed - len(_spans),
+                **_compile_stats}
+
+
+def spans_file(metrics_path: str) -> str:
+    """Where the span list of the process that writes ``metrics_path``
+    lies: ``{"pid", "spans"}``, the spans in ``seq`` order."""
+    return metrics_path + ".spans"
+
+
+def _process_start_wall() -> Optional[float]:
+    """When this process started, on the wall clock: field 22 of
+    ``/proc/self/stat`` is the start in clock ticks since boot. None where
+    /proc does not say."""
+    try:
+        with open("/proc/self/stat", encoding="ascii") as f:
+            ticks = int(f.read().rsplit(") ", 1)[1].split()[19])
+        # The process's age on the boot clock, off a wall-clock anchor.
+        age_s = (time.clock_gettime(time.CLOCK_BOOTTIME)
+                 - ticks / os.sysconf("SC_CLK_TCK"))
+        now = time.time()
+        return now - age_s
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+
+
+def mark_import_done() -> None:
+    """Last line of ``import tony_tpu`` inside a task: process start → now
+    is ``user.pre_import`` (the interpreter, and whatever the script
+    imported before tony_tpu: jax and the backend, if it went first)."""
+    if not os.environ.get(constants.METRICS_FILE):
+        return
+    start = _process_start_wall()
+    if start is not None:
+        record_span("user.pre_import", start, time.time())
+    install_jax_hooks()
+
+
+def _on_jax_scalar(event: str, value: float, **kw: Any) -> None:
+    # jax records a scalar (the start time) as each compile stage BEGINS:
+    # the only sign of nesting there is (a jit traced inside a trace).
+    if event in COMPILE_STAGES:
+        _compile_nesting.depth = getattr(_compile_nesting, "depth", 0) + 1
+
+
+def _on_jax_time_span(event: str, start: float, end: float,
+                      **kw: Any) -> None:
+    stage = COMPILE_STAGES.get(event)
+    if stage is None:
+        return
+    depth = max(0, getattr(_compile_nesting, "depth", 1) - 1)
+    _compile_nesting.depth = depth
+    if depth:
+        return          # inside an outer stage, whose span covers this one
+    with _step_lock:
+        step_count = _steps["count"]
+    with _span_lock:
+        _compile_stats["compile_seconds"] += max(0.0, end - start)
+        if stage == "backend":
+            _compile_stats["compiles"] += 1
+            if step_count:
+                _compile_stats["compiles_after_first_step"] += 1
+    record_span("user.compile", start, end, stage=stage,
+                fun_name=str(kw.get("fun_name", "")), step=step_count)
+
+
+def _on_jax_event(event: str, **kw: Any) -> None:
+    key = CACHE_EVENTS.get(event)
+    if key:
+        with _span_lock:
+            _compile_stats[key] += 1
+
+
+def install_jax_hooks() -> bool:
+    """Register the three ``jax.monitoring`` listeners, once, as soon as
+    the process has jax loaded (never imports it). Called wherever the
+    loop touches this module, and from the reporter's tick."""
+    global _jax_hooks_installed
+    if _jax_hooks_installed:
+        return True
+    monitoring = sys.modules.get("jax.monitoring")
+    register = [getattr(monitoring, name, None) for name in (
+        "register_scalar_listener", "register_event_time_span_listener",
+        "register_event_listener")]
+    if not all(register):
+        return False
+    with _span_lock:
+        if _jax_hooks_installed:
+            return True
+        _jax_hooks_installed = True
+    for reg, listener in zip(register, (_on_jax_scalar, _on_jax_time_span,
+                                        _on_jax_event)):
+        reg(listener)
+    return True
+
+
+def _profiler_annotation(kind: str, name: str, **kw: Any):
+    """``jax.profiler.<kind>(name, **kw)`` where the process has jax
+    loaded, a no-op context elsewhere."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return contextlib.nullcontext()
+    return getattr(profiler, kind)(name, **kw)
+
+
+def _reset_span_state() -> None:
+    """Tests: forget every span and compile count (listeners stay)."""
+    global _spans_closed, _spans_written
+    with _span_lock:
+        _spans.clear()
+        _spans_closed = 0
+        _spans_written = ("", 0)
+        for k in _compile_stats:
+            _compile_stats[k] = type(_compile_stats[k])()
 
 
 class _PhaseSpan:
@@ -137,10 +341,12 @@ def phase(name: str):
     """Attribute the enclosed wall time to step-phase ``name``:
     ``with telemetry.phase("data_wait"): batch = next(it)``. Folded into
     the per-step ring at the next ``step_done`` and shipped on the
-    heartbeat metrics beacon as ``tony_step_phase_seconds``."""
+    heartbeat metrics beacon as ``tony_step_phase_seconds``; a profiler
+    capture shows it as ``tony.phase.<name>``."""
     t0 = time.monotonic()
     try:
-        yield _PhaseSpan()
+        with _profiler_annotation("TraceAnnotation", "tony.phase." + name):
+            yield _PhaseSpan()
     finally:
         dt = time.monotonic() - t0
         with _phase_lock:
@@ -253,10 +459,18 @@ def step_done(started_at: float, flops: float = 0.0,
 @contextlib.contextmanager
 def step(flops: float = 0.0, tokens: float = 0.0):
     """Time one training step: ``with telemetry.step(flops=6*params*B*S):``.
-    Feeds steps/s, duty-cycle, and MFU into the task's metrics stream."""
+    Feeds steps/s, duty-cycle, and MFU into the task's metrics stream; a
+    profiler capture shows it as ``tony.step`` with its ``step_num``."""
+    install_jax_hooks()
+    with _step_lock:
+        step_num = _steps["count"]
     t0 = time.monotonic()
     try:
-        yield
+        # Closed before step_done: an on-demand capture starts and stops
+        # there, and must hold whole tony.step spans only.
+        with _profiler_annotation("StepTraceAnnotation", "tony.step",
+                                  step_num=step_num):
+            yield
     finally:
         step_done(t0, flops=flops, tokens=tokens)
 
@@ -272,7 +486,6 @@ def step_stats() -> Dict[str, float]:
     out = {
         "steps_completed": float(s["count"]),
         "steps_per_sec": s["count"] / wall,
-        "mean_step_s": s["busy_s"] / s["count"],
         # Fraction of wall time spent inside steps: the duty-cycle proxy
         # (host-side; dispatch gaps and eval/checkpoint pauses count as
         # idle, which is exactly the signal an operator wants).
@@ -419,7 +632,7 @@ def collect_device_stats() -> Dict[str, float]:
     beacon the coordinator's hang detection watches (device stats alone
     stay jax-gated: this module never imports jax itself)."""
     out: Dict[str, float] = {}
-    per_device: list = []
+    kinds: List[str] = []
     jax = None
     if "jax" in sys.modules:
         try:
@@ -439,17 +652,13 @@ def collect_device_stats() -> Dict[str, float]:
                 p = float(stats.get("peak_bytes_in_use", b) or b)
                 in_use += b
                 peak += p
-                per_device.append({"kind": getattr(d, "device_kind", "?"),
-                                   "bytes_in_use": b,
-                                   "peak_bytes_in_use": p})
+                kinds.append(str(getattr(d, "device_kind", "?")))
             out["hbm_bytes_in_use"] = in_use
             out["hbm_peak_bytes"] = peak
-            out["devices"] = per_device  # type: ignore[assignment]
     util = step_stats()
     if util:
         out.update(util)
-        kind = per_device[0]["kind"] if per_device else ""
-        peak_fl = PEAK_BF16_FLOPS.get(str(kind))
+        peak_fl = PEAK_BF16_FLOPS.get(kinds[0] if kinds else "")
         if jax is not None and peak_fl \
                 and util.get("model_flops_per_sec"):
             # flops passed to step() are the model's GLOBAL per-step FLOPs
@@ -460,9 +669,11 @@ def collect_device_stats() -> Dict[str, float]:
             try:
                 n_global = jax.device_count()
             except Exception:  # noqa: BLE001
-                n_global = len(per_device) or 1
+                n_global = len(kinds) or 1
             out["mfu_vs_peak_bf16"] = (util["model_flops_per_sec"]
                                        / (peak_fl * n_global))
+    # Boot and compile spans for the span log, and the compile counters.
+    out.update(span_stats())
     phases = phase_stats()
     if phases:
         # Step-time attribution: rides the metrics file → heartbeat
@@ -487,14 +698,21 @@ def collect_device_stats() -> Dict[str, float]:
 
 
 def write_stats_once(path: str) -> bool:
+    global _spans_written
     stats = collect_device_stats()
     if not stats:
         return False
-    stats["ts"] = time.time()
     stats["pid"] = os.getpid()
+    spans = stats.pop("spans", [])
     try:
         from tony_tpu.utils.durable import atomic_write
 
+        if (path, len(spans)) != _spans_written:
+            # Before the metrics file, whose spans_kept sends the reader
+            # here: what it then finds is at least that long.
+            atomic_write(spans_file(path), json.dumps(
+                {"pid": stats["pid"], "spans": spans}).encode("utf-8"))
+            _spans_written = (path, len(spans))
         atomic_write(path, json.dumps(stats).encode("utf-8"))
         return True
     except OSError:
@@ -507,10 +725,17 @@ def _loop(path: str, interval_s: float) -> None:
         # written just before this tick arms at the very next boundary.
         try:
             _poll_profile_request()
+            install_jax_hooks()
         except Exception:  # noqa: BLE001 — telemetry must never die
             pass
         write_stats_once(path)
-        time.sleep(interval_s)
+        # A new span cuts the sleep short, so that the list is on disk
+        # while the compile burst that made it is hardly over (boot, or a
+        # recompile), not up to a tick later in the middle of the steps
+        # that follow; the short wait lets the burst finish first.
+        if _span_added.wait(interval_s):
+            time.sleep(SPAN_FLUSH_DELAY_S)
+            _span_added.clear()
 
 
 def maybe_start(interval_s: float = 3.0) -> bool:

@@ -455,7 +455,14 @@ def cold_start_breakdown(records: List[Dict[str, Any]]) -> Dict[str, Any]:
         {"total_s": float,            # == sum(phases.values()), exact
          "task": "worker:0",
          "phases": {phase: seconds, ...},     # ordered, consecutive
-         "span_durations": {name: seconds}}   # raw (possibly overlapping)
+         "span_durations": {name: seconds},   # raw (possibly overlapping)
+         "user_boot": {name: seconds}}        # only with ``user.*`` spans
+
+    ``user_boot`` splits the ``user_boot`` phase by the anchor task's
+    ``user.*`` spans (the user process's own: telemetry.record_span) into
+    SELF times per span name — a span's time less what the spans opened
+    inside it cover — plus ``unattributed``; the values sum to
+    ``phases["user_boot"]``.
     """
     payload = to_trace_events(records)
     events = [e for e in payload["traceEvents"] if e.get("ph") == "X"]
@@ -487,7 +494,7 @@ def cold_start_breakdown(records: List[Dict[str, Any]]) -> Dict[str, Any]:
 
     t0 = int(submit["ts"])
     phases: Dict[str, float] = {}
-    prev = t0
+    prev = boot_start = t0
     end = int(first["ts"] + first.get("dur", 0))
     for phase, span_name, edge in _COLD_START_BOUNDARIES:
         b = _boundary(span_name, edge)
@@ -497,6 +504,8 @@ def cold_start_breakdown(records: List[Dict[str, Any]]) -> Dict[str, Any]:
             continue
         b = max(min(b, end), prev)   # clamp: monotonic, inside the window
         phases[phase] = round((b - prev) / 1e6, 4)
+        if phase == "user_boot":
+            boot_start = prev
         prev = b
     # Anything after the last known boundary still belongs to the total.
     if end > prev:
@@ -511,5 +520,33 @@ def cold_start_breakdown(records: List[Dict[str, Any]]) -> Dict[str, Any]:
         if cands:
             e = min(cands, key=lambda c: c["ts"])
             durations[name] = round(e.get("dur", 0) / 1e6, 4)
-    return {"total_s": round((end - t0) / 1e6, 4), "task": task,
-            "phases": phases, "span_durations": durations}
+    out = {"total_s": round((end - t0) / 1e6, 4), "task": task,
+           "phases": phases, "span_durations": durations}
+    user = [(int(e["ts"]), int(e["ts"] + e.get("dur", 0)), e["name"])
+            for e in events
+            if e["name"].startswith("user.") and _task(e) == task]
+    boot = _self_times(user, boot_start, end)
+    if boot:
+        boot = {name: round(us / 1e6, 4) for name, us in boot.items()}
+        boot["unattributed"] = round(
+            phases.get("user_boot", 0.0) - sum(boot.values()), 4)
+        out["user_boot"] = boot
+    return out
+
+
+def _self_times(spans: List[Tuple[int, int, str]], lo: int,
+                hi: int) -> Dict[str, int]:
+    """Microseconds of ``[lo, hi]`` owned by each span name: every instant
+    belongs to the innermost span open at it (the one opened last), so a
+    parent keeps its duration less what its children cover, and the
+    instants no span covers belong to no name."""
+    spans = sorted((max(a, lo), min(b, hi), name) for a, b, name in spans
+                   if min(b, hi) > max(a, lo))
+    cuts = sorted({t for a, b, _ in spans for t in (a, b)})
+    out: Dict[str, int] = {}
+    for a, b in zip(cuts, cuts[1:]):
+        open_here = [s for s in spans if s[0] <= a and s[1] >= b]
+        if open_here:
+            name = max(open_here, key=lambda s: (s[0], -s[1]))[2]
+            out[name] = out.get(name, 0) + (b - a)
+    return out
