@@ -78,18 +78,25 @@ def test_chunked_loss_matches_full_loss():
             a, b, atol=1e-4, rtol=1e-4), gc, gf)
 
 
-def test_selective_remat_is_numerically_inert():
-    """remat_skip_every changes memory/recompute scheduling only — loss
-    and gradients must be bit-comparable to full remat and to no remat
-    (it's the r5 perf lever; a numerics change would be a bug)."""
+@pytest.mark.parametrize("attn_impl,variants", [
+    ("xla", (dict(remat=False), dict(remat=True),
+             dict(remat=True, remat_skip_every=2))),
+    # What the remat keeps of the flash forward (its tagged o and lse under
+    # None, nothing under "nothing_saveable") are the values a re-run gives.
+    ("flash", (dict(remat=False), dict(remat=True, remat_policy=None),
+               dict(remat=True, remat_policy="nothing_saveable"))),
+])
+def test_selective_remat_is_numerically_inert(attn_impl, variants):
+    """remat_skip_every and remat_policy change memory/recompute scheduling
+    only — loss and gradients must be bit-comparable to full remat and to
+    no remat (it's the r5 perf lever; a numerics change would be a bug)."""
     import flax.linen as nn
     from tony_tpu.parallel.sharding import DEFAULT_RULES
 
     tokens = jax.random.randint(jax.random.key(0), (2, 32), 0, 256)
     results = []
-    for remat, skip in ((False, 0), (True, 0), (True, 2)):
-        cfg = TransformerConfig.tiny(remat=remat, remat_skip_every=skip,
-                                     attn_impl="xla")
+    for variant in variants:
+        cfg = TransformerConfig.tiny(attn_impl=attn_impl, **variant)
         model = Transformer(cfg)
         with nn.logical_axis_rules(list(DEFAULT_RULES)):
             params = model.init(jax.random.key(1), tokens)["params"]
@@ -106,6 +113,42 @@ def test_selective_remat_is_numerically_inert():
             lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5,
                                                     atol=1e-6),
             g, results[0][1])
+
+
+@pytest.mark.parametrize("remat_policy,fwd_per_layer",
+                         [(None, 1), ("nothing_saveable", 2)])
+@pytest.mark.parametrize("family", ["dense", "moe"])
+def test_block_remat_keeps_the_flash_forward_outputs(family, remat_policy,
+                                                     fwd_per_layer):
+    """With no policy named the block's remat keeps the flash forward's o
+    and lse, so the gradient runs ``flash_fwd`` once a layer; a named policy
+    is honoured to the letter, and "nothing_saveable" runs it twice. dq and
+    dkv run once a layer either way."""
+    import flax.linen as nn
+    from jaxpr_kernels import pallas_calls
+    from tony_tpu.models.moe import MoEConfig, MoETransformer, moe_lm_loss
+    from tony_tpu.parallel.sharding import DEFAULT_RULES
+
+    kw = dict(attn_impl="flash", remat=True, remat_policy=remat_policy)
+    if family == "dense":
+        cfg = TransformerConfig.tiny(**kw)
+        model, loss_of = Transformer(cfg), causal_lm_loss
+    else:
+        cfg = MoEConfig.tiny_moe(**kw)
+        model = MoETransformer(cfg)
+
+        def loss_of(out, tokens):
+            return moe_lm_loss(out, tokens, cfg.aux_loss_weight)
+    tokens = jax.random.randint(jax.random.key(0), (2, 32), 0,
+                                cfg.vocab_size)
+    with nn.logical_axis_rules(list(DEFAULT_RULES)):
+        params = nn.meta.unbox(
+            jax.eval_shape(model.init, jax.random.key(1), tokens))["params"]
+        calls = pallas_calls(jax.make_jaxpr(jax.grad(
+            lambda p: loss_of(model.apply({"params": p}, tokens), tokens)))(
+                params))
+    assert calls == {"flash_fwd": fwd_per_layer * cfg.n_layers,
+                     "flash_dq": cfg.n_layers, "flash_dkv": cfg.n_layers}
 
 
 def test_transformer_trains_sharded_tp_fsdp():

@@ -116,6 +116,34 @@ def test_flash_lse_matches_logsumexp_oracle(causal):
         np.testing.assert_allclose(om, o, atol=2e-5, rtol=2e-5)
 
 
+def test_flash_with_lse_is_not_kept_by_the_residual_names():
+    """Only ``flash_attention`` tags its forward's o and lse. Ring attention
+    calls ``flash_attention_with_lse`` once per hop, and a layer would keep
+    sp f32 partial outputs: under a checkpoint that saves the tagged names
+    that forward still runs again in the backward, the plain one does not."""
+    from jaxpr_kernels import pallas_calls
+    from tony_tpu.ops.attention import (FLASH_RESIDUAL_NAMES,
+                                        flash_attention_with_lse)
+
+    q, k, v = _qkv(b=1, s=32, h=2, d=16)
+    policy = jax.checkpoint_policies.save_only_these_names(
+        *FLASH_RESIDUAL_NAMES)
+
+    def with_lse(q, k, v):
+        o, lse = flash_attention_with_lse(q, k, v, block_q=16, block_k=16)
+        return jnp.sum(o ** 2) + jnp.sum(jnp.sin(lse))
+
+    def plain(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, block_q=16, block_k=16) ** 2)
+
+    def calls(fn):
+        fn = jax.checkpoint(fn, policy=policy)
+        return pallas_calls(jax.make_jaxpr(jax.grad(fn, (0, 1, 2)))(q, k, v))
+
+    assert calls(with_lse) == {"flash_fwd": 2, "flash_dq": 1, "flash_dkv": 1}
+    assert calls(plain) == {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1}
+
+
 def test_flash_lse_gradient_flows_through_lse():
     """The lse output is differentiable: a loss that consumes BOTH o and
     lse (like the ring merge does) matches autodiff of the XLA oracle."""

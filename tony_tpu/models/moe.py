@@ -37,7 +37,7 @@ import jax.numpy as jnp
 
 from tony_tpu import compat
 from tony_tpu.models.transformer import (Attention, RMSNorm,
-                                         TransformerConfig)
+                                         TransformerConfig, remat_policy_of)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -204,13 +204,9 @@ class MoETransformer(nn.Module):
             # model (same defect found and measured in
             # models/transformer.py; False is only sound inside
             # scan/while bodies — see parallel/pipeline.py for the
-            # legitimate case). remat_policy is honoured like the dense
-            # transformer's.
-            import jax as _jax
-
-            policy = (getattr(_jax.checkpoint_policies, cfg.remat_policy)
-                      if cfg.remat_policy else None)
-            block = nn.remat(MoEBlock, prevent_cse=True, policy=policy)
+            # legitimate case). The policy is the dense transformer's.
+            block = nn.remat(MoEBlock, prevent_cse=True,
+                             policy=remat_policy_of(cfg))
         aux_total = jnp.zeros((), jnp.float32)
         for i in range(cfg.n_layers):
             x, aux = block(cfg, name=f"layer_{i}")(x, positions)

@@ -22,7 +22,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from tony_tpu.ops.attention import flash_attention, reference_attention
+from tony_tpu.ops.attention import (FLASH_RESIDUAL_NAMES, flash_attention,
+                                    reference_attention)
 from tony_tpu.ops.quant import QDense
 from tony_tpu.ops.ring import ring_attention
 from tony_tpu.ops.ulysses import ulysses_attention
@@ -43,10 +44,17 @@ class TransformerConfig:
     param_dtype: jnp.dtype = jnp.float32
     attn_impl: str = "flash"                 # flash | ring | ulysses | xla
     remat: bool = True
-    # Name of a jax.checkpoint_policies policy for remat, e.g.
-    # "dots_with_no_batch_dims_saveable" (save matmul outputs, recompute
-    # only cheap elementwise/norm ops — ~the full-remat memory win at a
-    # fraction of the recompute FLOPs). None → full remat of each block.
+    # Name of a jax.checkpoint_policies policy for remat, honoured to the
+    # letter, e.g. "dots_with_no_batch_dims_saveable" (save matmul outputs,
+    # recompute only cheap elementwise/norm ops — ~the full-remat memory
+    # win at a fraction of the recompute FLOPs). None → each block is
+    # recomputed except the flash forward kernel: its outputs o (activation
+    # dtype, [B, S, n_heads·head_dim]: one more hidden state) and lse (f32
+    # [B, n_heads, 8, S]: 32·n_heads bytes a token, 1/8 of a bf16 o at
+    # head_dim 128) stay live per layer beside the block's input, so the
+    # backward does not run flash_fwd a second time (attn_impl flash and
+    # ulysses; ring and xla have nothing tagged and keep nothing).
+    # "nothing_saveable" keeps nothing: every block recomputed whole.
     remat_policy: Optional[str] = None
     # Layer-granular selective remat (layers are a Python loop, so the
     # choice is per-layer): with remat on and N >= 2, every Nth block
@@ -90,6 +98,17 @@ class TransformerConfig:
                         dtype=jnp.float32, remat=False)
         defaults.update(kw)
         return cls(**defaults)
+
+
+def remat_policy_of(cfg: TransformerConfig):
+    """The ``jax.checkpoint`` policy of a block's remat (``cfg.remat`` set):
+    the ``jax.checkpoint_policies`` member ``cfg.remat_policy`` names, or,
+    with none named, the one that keeps the flash forward's tagged o and lse
+    and recomputes everything else."""
+    if cfg.remat_policy:
+        return getattr(jax.checkpoint_policies, cfg.remat_policy)
+    return jax.checkpoint_policies.save_only_these_names(
+        *FLASH_RESIDUAL_NAMES)
 
 
 def _dense(cfg: TransformerConfig, feats: int, axes, name: str) -> nn.Module:
@@ -270,8 +289,6 @@ class Transformer(nn.Module):
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         block = Block
         if cfg.remat:
-            policy = (getattr(jax.checkpoint_policies, cfg.remat_policy)
-                      if cfg.remat_policy else None)
             # prevent_cse MUST stay True here: layers are a Python loop
             # (deliberately — see module docstring), not a lax.scan, and
             # prevent_cse=False is only sound inside scan/while bodies
@@ -281,7 +298,8 @@ class Transformer(nn.Module):
             # flagship at batch 8 / seq 8192 compiled to an identical
             # 21.33 GB HBM footprint with remat on and off; with True the
             # same config fits in 9.8 GB.
-            block = nn.remat(Block, prevent_cse=True, policy=policy)
+            block = nn.remat(Block, prevent_cse=True,
+                             policy=remat_policy_of(cfg))
         for i in range(cfg.n_layers):
             blk = block
             if (cfg.remat and cfg.remat_skip_every >= 2

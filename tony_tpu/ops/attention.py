@@ -39,6 +39,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -53,6 +54,11 @@ STAT_SUB = 8
 # flash_attention: shared by every public attention entry point (flash,
 # flash_with_lse, ring, ulysses) so a re-sweep updates one constant.
 DEFAULT_BLOCK = 1024
+# ``jax.ad_checkpoint.checkpoint_name`` tags of ``_flash``'s forward outputs
+# (o, lse): the two residuals only the forward kernel can give back. A remat
+# whose policy saves these names keeps them and drops its re-run of
+# ``flash_fwd`` (models/transformer.py ``remat_policy_of``).
+FLASH_RESIDUAL_NAMES = ("flash_o", "flash_lse")
 
 
 def _prec(x):
@@ -573,6 +579,10 @@ def _flash(q, k, v, scale, causal, block_q, block_k):
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
     o, lse = _fwd_impl(q, k, v, scale, causal, block_q, block_k)
+    # Identity outside a jax.checkpoint. _flash_lse below is NOT tagged:
+    # ring attention calls it once per hop, and keeping sp partial outputs
+    # in f32 per layer is another trade.
+    o, lse = map(checkpoint_name, (o, lse), FLASH_RESIDUAL_NAMES)
     return o, (q, k, v, o, lse)
 
 
