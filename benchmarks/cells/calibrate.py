@@ -37,17 +37,23 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearsal", action="store_true")
     args = ap.parse_args(argv)
 
+    import arch
     import run
     import train
 
     bench = run.load_json(args.table)
     cell, config = run.find_cell(bench, args.workload)
-    cfg = run.load_json(os.path.join(ROOT, config["file"]))
+    config_path = os.path.join(ROOT, config["file"])
+    cfg = run.load_json(config_path)
+    architecture = arch.find(cfg, config_path,
+                             os.path.join(ROOT, cell["base"]))
     traffic = run.load_json(os.path.join(
         ROOT, cell["base"], "traffic", cell["traffic"] + ".json"))
     import jax
 
     import reference
+
+    model = arch.load(architecture, "reference")
 
     if not args.rehearsal and jax.devices()[0].platform != "tpu":
         raise SystemExit("no TPU")
@@ -60,7 +66,8 @@ def main(argv=None) -> int:
 
     def program(seed, control=""):
         opts = argparse.Namespace(seed=seed, chips=cell["chips"],
-                                  control=control, rehearsal=args.rehearsal)
+                                  control=control, rehearsal=args.rehearsal,
+                                  architecture=architecture)
         c = train.Cell(opts, cfg, traffic)
         got = c.first_steps(steps)
         c.free()
@@ -75,7 +82,7 @@ def main(argv=None) -> int:
         t0 = time.time()
         got = program(seed)
         t1 = time.time()
-        ref = reference.follow(cfg, opt, seed, batch, seq,
+        ref = reference.follow(model, cfg, opt, seed, batch, seq,
                                steps, offload_moments=offload,
                                devices=devices)
         t2 = time.time()
@@ -89,7 +96,7 @@ def main(argv=None) -> int:
         if i < args.controls:
             row["control_int8"] = gaps(program(seed, "int8"), ref)
             half = dict(reference.follow(
-                cfg, opt, seed, batch, seq, steps,
+                model, cfg, opt, seed, batch, seq, steps,
                 offload_moments=offload, devices=devices, half=True),
                 feed_mismatch=0)
             row["fault_half_batch"] = gaps(half, ref)

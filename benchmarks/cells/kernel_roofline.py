@@ -4,10 +4,12 @@ The program names its Mosaic kernels (``pallas_call(name="flash_fwd")``, …)
 and the device trace shows each call under that name, numbered by the
 compiler (``flash_fwd.1``). A reader selects its kernel's operations by that
 name alone: the least time the chip could take for the calls seen
-(``counts.flash_call_min_seconds`` at the cell's per-device shapes) over the
-device time they took. Where the trace names no such operation, as with a
-program that does not name its kernels, there is nothing to read.
+(``counts.least_seconds`` for the attention calls the configuration's
+architecture says a step needs) over the device time they took. Where the
+trace names no such operation, as with a program that does not name its
+kernels, there is nothing to read.
 """
+import arch
 import counts
 
 
@@ -19,10 +21,11 @@ def sums(run: dict, kind: str, prefix: str) -> tuple:
             if name.startswith(prefix) and " tpu_custom_call " in name]
     calls = sum(v[0] for v in mine)
     took = sum(v[1] for v in mine)
-    seconds, binds = counts.flash_call_min_seconds(
-        kind, counts.flash_shard_shape(run["config"], run["traffic"]),
-        counts.peaks(run["worker"]["device"]["kind"]))
-    return calls * seconds, took, calls, binds
+    needs = arch.load(run["architecture"], "counts").flash_calls(
+        run["config"], run["traffic"])
+    least, binds = counts.least_seconds(
+        kind, calls, needs, counts.peaks(run["worker"]["device"]["kind"]))
+    return least, took, calls, binds
 
 
 def read(run: dict, kind: str, prefix: str):

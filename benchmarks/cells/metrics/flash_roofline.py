@@ -1,8 +1,10 @@
 """The least time the chip could take for the flash forward, dq and dkv
-kernels' calls (``counts.flash_call_min_seconds`` at this cell's per-device
-shapes, times the calls the trace shows) over the device time they took."""
+kernels' calls (``counts.least_seconds``: the calls the trace shows, at the
+per-device shapes and masks the configuration's architecture says a step
+needs) over the device time they took."""
 import re
 
+import arch
 import counts
 
 NAME, UNIT, SOURCE = "flash_roofline", "%", "device_trace"
@@ -19,14 +21,14 @@ KERNELS = {"fwd": re.compile(r" tpu_custom_call \(.*\) operands=3$"),
 def _sums(run):
     ops = run["worker"]["trace"].get("ops", {})
     peak = counts.peaks(run["worker"]["device"]["kind"])
-    shape = counts.flash_shard_shape(run["config"], run["traffic"])
+    needs = arch.load(run["architecture"], "counts").flash_calls(
+        run["config"], run["traffic"])
     least = took = 0.0
     binds = {}
     for kind, pattern in KERNELS.items():
         calls = sum(v[0] for k, v in ops.items() if pattern.search(k))
-        seconds, binds[kind] = counts.flash_call_min_seconds(kind, shape,
-                                                             peak)
-        least += calls * seconds
+        seconds, binds[kind] = counts.least_seconds(kind, calls, needs, peak)
+        least += seconds
         took += sum(v[1] for k, v in ops.items() if pattern.search(k))
     return least, took, binds
 

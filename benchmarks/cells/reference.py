@@ -1,24 +1,29 @@
-"""The plain reference and the comparison that decides ``correct``.
+"""The plain reference's machinery and the comparison that decides
+``correct``.
 
-Everything a cell's verdict rests on is here and imports nothing of the
-program: the weights and token ids made from the seed, the dense decoder's
-loss in straightforward ``jax.numpy`` float32 at ``highest`` matmul precision
-(no kernels, no bf16, no remat policy of the program's), AdamW as optax
-defines it, and the gaps that are held against the limits.
+Everything a cell's verdict rests on that is the same for every architecture
+is here and imports nothing of the program: the weights and token ids made
+from the seed, AdamW as optax defines it, the first steps followed from the
+seed (``follow``), and the gaps that are held against the limits
+(``compare``). What the model is, its leaves (``leaf_specs(cfg)``) and its
+loss (``loss_fn(cfg, params, tokens)``) in straightforward ``jax.numpy``
+float32, is the architecture's to say, in
+``architectures/<model_type>/reference.py`` (``arch.py``); ``follow``,
+``make_params`` and ``change_norms`` take that module as ``model``. No
+architecture is named in this code. Matmuls run at ``highest`` precision: no
+kernels, no bf16, no remat policy of the program's.
 
-The decoder (Mistral family, as the configuration's source publishes it):
-token embedding, then per layer ``h = x + Wo·attn(rope(Wq·n), rope(Wk·n),
-Wv·n)`` with ``n = rmsnorm(x)`` and grouped-query causal softmax attention,
-``out = h + Wdown·(silu(Wgate·m) * Wup·m)`` with ``m = rmsnorm(h)``, a final
-rmsnorm, an untied head, and the mean next-token cross entropy. RoPE is the
-half-split (``rotate_half``) form of the published implementation.
+An architecture's reference may build its loss from the plain blocks below
+(``rmsnorm``, ``rope``, ``attention``, ``by_position_blocks``, ``blocks``,
+``next_token_nll_sum``, ``mean_over_rows``) or from its own. RoPE is the
+half-split (``rotate_half``) form.
 
-It runs on the device after the window has closed and the program's state is
-freed. So that it fits beside its own Adam state it works in blocks: rows one
-at a time, attention one kv-head and one block of queries at a time, the MLP
-and the logits a block of positions at a time, each block recomputed in the
-backward pass. The blocks change where temporaries live, not one operation of
-the mathematics.
+The reference runs on the device after the window has closed and the
+program's state is freed. So that it fits beside its own Adam state it works
+in blocks: rows one at a time, attention one kv-head and one block of queries
+at a time, per-position work and the logits a block of positions at a time,
+each block recomputed in the backward pass. The blocks change where
+temporaries live, not one operation of the mathematics.
 """
 
 from __future__ import annotations
@@ -31,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 ATTN_Q_BLOCK = 512      # queries per attention block
-POS_BLOCK = 2048        # positions per MLP / logits block
+POS_BLOCK = 2048        # positions per block of by_position_blocks, logits
 # A leaf whose reference gradient norm is under this share of the median
 # leaf's moves under Adam by round-off alone and is left out of the change.
 DEAD_LEAF_SHARE = 1e-3
@@ -40,33 +45,6 @@ DEAD_LEAF_SHARE = 1e-3
 # ---------------------------------------------------------------------------
 # Inputs from the seed
 # ---------------------------------------------------------------------------
-def leaf_specs(cfg: dict) -> list:
-    """``[(path, shape, std)]`` for every parameter leaf, in the sorted order
-    of the program's parameter tree (dict keys sort the same way). ``std`` is
-    None for a norm scale (ones). Kernels are [in, out]."""
-    d, v = cfg["hidden_size"], cfg["vocab_size"]
-    hd = cfg.get("head_dim") or d // cfg["num_attention_heads"]
-    q, kv = cfg["num_attention_heads"] * hd, cfg["num_key_value_heads"] * hd
-    f = cfg["intermediate_size"]
-    specs = [(("embedding",), (v, d), cfg.get("initializer_range", 0.02)),
-             (("final_norm", "scale"), (d,), None)]
-    for i in range(cfg["num_hidden_layers"]):
-        layer = f"layer_{i}"
-        specs += [
-            ((layer, "attn", "wq", "kernel"), (d, q), d ** -0.5),
-            ((layer, "attn", "wk", "kernel"), (d, kv), d ** -0.5),
-            ((layer, "attn", "wv", "kernel"), (d, kv), d ** -0.5),
-            ((layer, "attn", "wo", "kernel"), (q, d), q ** -0.5),
-            ((layer, "attn_norm", "scale"), (d,), None),
-            ((layer, "mlp", "gate", "kernel"), (d, f), d ** -0.5),
-            ((layer, "mlp", "up", "kernel"), (d, f), d ** -0.5),
-            ((layer, "mlp", "down", "kernel"), (f, d), f ** -0.5),
-            ((layer, "mlp_norm", "scale"), (d,), None),
-        ]
-    specs.append((("lm_head", "kernel"), (d, v), d ** -0.5))
-    return sorted(specs)
-
-
 def seed_key(seed: int) -> jax.Array:
     """The seed as a key. It is an argument of every jitted function below,
     never a constant inside one, so that one compiled program serves every
@@ -75,21 +53,23 @@ def seed_key(seed: int) -> jax.Array:
     return jax.random.fold_in(key, int(seed) >> 31)
 
 
-def make_leaf(cfg: dict, key: jax.Array, index: int) -> jax.Array:
-    """Leaf ``index`` of ``leaf_specs`` in float32: normal(0, std) from the
-    seed's key and the leaf's index, or ones."""
-    _, shape, std = leaf_specs(cfg)[index]
+def make_leaf(model, cfg: dict, key: jax.Array, index: int) -> jax.Array:
+    """Leaf ``index`` of ``model.leaf_specs(cfg)``, which is ``[(path, shape,
+    std)]`` in the sorted order of the program's parameter tree, in float32:
+    normal(0, std) from the seed's key and the leaf's index, or ones where
+    ``std`` is None."""
+    _, shape, std = model.leaf_specs(cfg)[index]
     if std is None:
         return jnp.ones(shape, jnp.float32)
     return jax.random.normal(jax.random.fold_in(key, index), shape,
                              jnp.float32) * jnp.float32(std)
 
 
-def make_tree(cfg: dict, leaf) -> dict:
+def make_tree(model, cfg: dict, leaf) -> dict:
     """Nested dicts in the program's layout, ``leaf(index, shape)`` at each
     leaf."""
     tree: dict = {}
-    for i, (path, shape, _) in enumerate(leaf_specs(cfg)):
+    for i, (path, shape, _) in enumerate(model.leaf_specs(cfg)):
         node = tree
         for k in path[:-1]:
             node = node.setdefault(k, {})
@@ -97,9 +77,10 @@ def make_tree(cfg: dict, leaf) -> dict:
     return tree
 
 
-def make_params(cfg: dict, key: jax.Array) -> dict:
+def make_params(model, cfg: dict, key: jax.Array) -> dict:
     """The whole parameter tree from the seed's key."""
-    return make_tree(cfg, lambda i, shape: make_leaf(cfg, key, i))
+    return make_tree(model, cfg,
+                     lambda i, shape: make_leaf(model, cfg, key, i))
 
 
 def flat(tree: dict) -> list:
@@ -122,14 +103,14 @@ def token_rows(seed: int, step: int, batch: int, seq: int,
 
 
 # ---------------------------------------------------------------------------
-# The decoder, float32
+# Plain float32 blocks, for an architecture's reference to use or not
 # ---------------------------------------------------------------------------
-def _rmsnorm(x, scale, eps):
+def rmsnorm(x, scale, eps):
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(var + eps) * scale
 
 
-def _rope(x, theta):
+def rope(x, theta):
     """x [S, H, D]; positions 0..S-1; half-split rotation."""
     s, _, d = x.shape
     freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
@@ -139,7 +120,7 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-def _blocks(n: int, want: int) -> int:
+def blocks(n: int, want: int) -> int:
     """Largest block <= want that divides n."""
     b = min(n, want)
     while n % b:
@@ -147,13 +128,13 @@ def _blocks(n: int, want: int) -> int:
     return b
 
 
-def _attention(q, k, v):
+def attention(q, k, v):
     """Causal grouped-query attention. q [S, H, D], k/v [S, Hk, D] -> [S, H,
     D]. One kv head and one block of queries at a time."""
     s, h, d = q.shape
     hk = k.shape[1]
     g = h // hk
-    bq = _blocks(s, ATTN_Q_BLOCK)
+    bq = blocks(s, ATTN_Q_BLOCK)
     nq = s // bq
     qg = q.reshape(s, hk, g, d)
     scale = d ** -0.5
@@ -178,44 +159,18 @@ def _attention(q, k, v):
     return out.reshape(s, h, d)
 
 
-def _by_position_blocks(fn, x):
+def by_position_blocks(fn, x):
     """``fn`` over blocks of positions of x [S, ...], recomputed backward."""
     s = x.shape[0]
-    b = _blocks(s, POS_BLOCK)
+    b = blocks(s, POS_BLOCK)
     out = jax.lax.map(jax.checkpoint(fn), x.reshape(s // b, b, *x.shape[1:]))
     return out.reshape(s, *out.shape[2:])
 
 
-def _layer(cfg, p, x):
-    hd = cfg.get("head_dim") or cfg["hidden_size"] // cfg[
-        "num_attention_heads"]
-    s = x.shape[0]
-    n = _rmsnorm(x, p["attn_norm"]["scale"], cfg["rms_norm_eps"])
-    q = (n @ p["attn"]["wq"]["kernel"]).reshape(s, -1, hd)
-    k = (n @ p["attn"]["wk"]["kernel"]).reshape(s, -1, hd)
-    v = (n @ p["attn"]["wv"]["kernel"]).reshape(s, -1, hd)
-    o = _attention(_rope(q, cfg["rope_theta"]), _rope(k, cfg["rope_theta"]),
-                   v)
-    h = x + o.reshape(s, -1) @ p["attn"]["wo"]["kernel"]
-    m = _rmsnorm(h, p["mlp_norm"]["scale"], cfg["rms_norm_eps"])
-
-    def mlp(mb):
-        gate = mb @ p["mlp"]["gate"]["kernel"]
-        up = mb @ p["mlp"]["up"]["kernel"]
-        return (jax.nn.silu(gate) * up) @ p["mlp"]["down"]["kernel"]
-
-    return h + _by_position_blocks(mlp, m)
-
-
-def _row_nll_sum(cfg, params, tokens):
+def next_token_nll_sum(x, head, tokens):
     """Sum over positions of the next-token negative log likelihood of one
-    row of ids [S]."""
-    x = params["embedding"][tokens]
-    for i in range(cfg["num_hidden_layers"]):
-        x = jax.checkpoint(lambda p, y: _layer(cfg, p, y))(
-            params[f"layer_{i}"], x)
-    x = _rmsnorm(x, params["final_norm"]["scale"], cfg["rms_norm_eps"])
-    head = params["lm_head"]["kernel"]
+    row of ids [S], from its final hidden states x [S, D] and the head
+    [D, V], a block of positions at a time."""
     # Position i predicts token i+1; the last position predicts nothing.
     targets = jnp.concatenate([tokens[1:], tokens[:1]])
     weight = (jnp.arange(tokens.shape[0]) < tokens.shape[0] - 1).astype(
@@ -229,19 +184,18 @@ def _row_nll_sum(cfg, params, tokens):
         return jnp.sum((lse - picked) * wb)[None]
 
     s = x.shape[0]
-    b = _blocks(s, POS_BLOCK)
+    b = blocks(s, POS_BLOCK)
     sums = jax.lax.map(jax.checkpoint(nll), (
         x.reshape(s // b, b, -1), targets.reshape(s // b, b),
         weight.reshape(s // b, b)))
     return jnp.sum(sums)
 
 
-def loss_fn(cfg: dict, params: dict, tokens: jax.Array) -> jax.Array:
+def mean_over_rows(row_nll_sum, tokens: jax.Array) -> jax.Array:
     """Mean next-token cross entropy of a batch of ids [B, S], a row at a
-    time."""
+    time: ``row_nll_sum(row)`` is one row's sum over its positions."""
     b, s = tokens.shape
-    sums = jax.lax.map(
-        jax.checkpoint(lambda row: _row_nll_sum(cfg, params, row)), tokens)
+    sums = jax.lax.map(jax.checkpoint(row_nll_sum), tokens)
     return jnp.sum(sums) / (b * (s - 1))
 
 
@@ -279,23 +233,24 @@ def leaf_norms(leaves: list) -> np.ndarray:
     return np.asarray(f(leaves), np.float64)
 
 
-def change_norms(cfg: dict, seed: int, leaves: list) -> np.ndarray:
+def change_norms(model, cfg: dict, seed: int, leaves: list) -> np.ndarray:
     """Per-leaf norm of (leaf - the seed's initial leaf), the initial leaf
     made again from the seed so that no second copy of the weights is kept."""
     def one(key, i, x):
         return jnp.sqrt(jnp.sum(jnp.square(
-            x.astype(jnp.float32) - make_leaf(cfg, key, i))))
+            x.astype(jnp.float32) - make_leaf(model, cfg, key, i))))
     f = jax.jit(lambda key, xs: jnp.stack(
         [one(key, i, x) for i, x in enumerate(xs)]))
     return np.asarray(f(seed_key(seed), leaves), np.float64)
 
 
-def follow(cfg: dict, opt: dict, seed: int, batch: int, seq: int,
+def follow(model, cfg: dict, opt: dict, seed: int, batch: int, seq: int,
            steps: int, half: bool = False, offload_moments: bool = False,
            devices=None) -> dict:
-    """The reference's own first ``steps`` steps from the seed. Returns the
-    readings the program is held to: each step's loss, the per-leaf norm of
-    the first gradient, the per-leaf norm of the parameters' change.
+    """The reference's own first ``steps`` steps from the seed, ``model``
+    being the architecture's reference (``leaf_specs``, ``loss_fn``). Returns
+    the readings the program is held to: each step's loss, the per-leaf norm
+    of the first gradient, the per-leaf norm of the parameters' change.
 
     ``half`` is the planted fault: the reference put in the program's place
     on half of the batch, the mean taken over that half (the first half of
@@ -308,14 +263,14 @@ def follow(cfg: dict, opt: dict, seed: int, batch: int, seq: int,
     def value_and_grad(params, tokens):
         with jax.default_matmul_precision("highest"):
             return jax.value_and_grad(
-                lambda p: loss_fn(cfg, p, tokens))(params)
+                lambda p: model.loss_fn(cfg, p, tokens))(params)
 
     shardings = None
     if devices is not None and len(devices) > 1:
         from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
         mesh = Mesh(np.array(devices), ("x",))
-        shardings = make_tree(cfg, lambda i, shape: NamedSharding(
+        shardings = make_tree(model, cfg, lambda i, shape: NamedSharding(
             mesh, PartitionSpec(*(
                 "x" if len(shape) > 1 and j == int(np.argmax(shape)) else None
                 for j in range(len(shape))))))
@@ -323,7 +278,7 @@ def follow(cfg: dict, opt: dict, seed: int, batch: int, seq: int,
     upd = jax.jit(lambda c, p, g, m, n: jax.tree.map(
         lambda *a: adamw_update(opt, c, *a), p, g, m, n),
         static_argnums=0, donate_argnums=(1, 2, 3, 4))
-    params = jax.jit(lambda key: make_params(cfg, key),
+    params = jax.jit(lambda key: make_params(model, cfg, key),
                      out_shardings=shardings)(seed_key(seed))
     mu = nu = None
     losses, grad_norms = [], None
@@ -351,7 +306,7 @@ def follow(cfg: dict, opt: dict, seed: int, batch: int, seq: int,
             host = jax.device_get((mu, nu))
             jax.tree.map(lambda x: x.delete(), (mu, nu))
             mu, nu = host
-    change = change_norms(cfg, seed, flat(params))
+    change = change_norms(model, cfg, seed, flat(params))
     jax.tree.map(lambda x: x.delete() if hasattr(x, "delete") else None,
                  (params, mu, nu))
     return {"losses": losses, "grad_norms": grad_norms.tolist(),

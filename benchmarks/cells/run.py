@@ -6,8 +6,10 @@
 
 A cell is its entry in ``BENCHMARK.json`` plus the files that entry names: the
 configuration's file, ``traffic/<traffic>.json`` and ``limits/<workload>.json``
-beside this file. A per-layer metric is one file under ``metrics/``, found by
-listing the directory. No cell and no metric is named in this code.
+beside this file. The configuration's ``model_type`` names its architecture,
+a directory of three files under ``architectures/`` (``arch.py``). A
+per-layer metric is one file under ``metrics/``, found by listing the
+directory. No cell, no metric and no architecture is named in this code.
 
 This process never imports JAX: a process that touches JAX holds the chip,
 and the job's user process needs it. It submits one job through ``tony-tpu
@@ -36,6 +38,8 @@ import subprocess
 import sys
 import tempfile
 import time
+
+import arch
 
 T_START = time.time()
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -68,7 +72,6 @@ def load_metrics() -> list:
     """Every per-layer metric's reader: one module per file of metrics/."""
     out = []
     folder = os.path.join(HERE, "metrics")
-    sys.path.insert(0, HERE)            # the readers import counts
     for name in sorted(os.listdir(folder)):
         if not name.endswith(".py"):
             continue
@@ -145,8 +148,11 @@ def drive(args, cell: dict, config: dict, out_dir: str, run_dir: str,
     task_env += (",JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS=0,"
                  "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES=-1")
     base = os.path.join(ROOT, cell["base"])
+    config_path = os.path.join(ROOT, config["file"])
+    cfg = load_json(config_path)
+    architecture = arch.find(cfg, config_path, base)
     worker = [sys.executable, os.path.join(HERE, "train.py"),
-              "--config", os.path.join(ROOT, config["file"]),
+              "--config", config_path, "--architecture", architecture,
               "--traffic", os.path.join(base, "traffic",
                                         cell["traffic"] + ".json"),
               "--limits", os.path.join(base, "limits",
@@ -210,7 +216,7 @@ def drive(args, cell: dict, config: dict, out_dir: str, run_dir: str,
             print(line)
     return {"worker": load_json(result_path), "spans": spans,
             "harness_start_wall": T_START,
-            "config": load_json(os.path.join(ROOT, config["file"])),
+            "config": cfg, "architecture": architecture,
             "traffic": load_json(worker[worker.index("--traffic") + 1]),
             "cell": cell}
 
@@ -298,7 +304,6 @@ def main(argv=None) -> int:
     if args.rehearsal:
         line["rehearsal"] = True
     if args.trace and not args.rehearsal:
-        sys.path.insert(0, HERE)
         import trace_reduce
 
         device["busy_s"] = worker["trace"]["busy_s"]

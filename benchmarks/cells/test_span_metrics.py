@@ -46,6 +46,7 @@ def a_run(trace: dict, spans: dict) -> dict:
     return {"worker": {"trace": trace_reduce.reduce_trace(trace),
                        "device": {"kind": "TPU v5 lite"}},
             "spans": spans,
+            "architecture": os.path.join(HERE, "architectures", "mistral"),
             "config": load(os.path.join(HERE, "configs",
                                         "mistral-7b-v0.3.json")),
             "traffic": load(os.path.join(HERE, "traffic", "seq2k.json"))}

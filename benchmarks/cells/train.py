@@ -8,6 +8,15 @@ from ``data.synthetic_lm_batches`` through the prefetching
 from the cell's configuration file and traffic file, the weights and ids from
 ``--seed``.
 
+What the model is comes from the configuration's architecture
+(``--architecture``, a directory that ``arch.find`` gave the parent): its
+``program.py`` builds the program's model and the loss handed to
+``jit_train_step``, its ``reference.py`` says the leaves and the plain loss,
+its ``counts.py`` the operations a token costs. No architecture, model class
+or parameter is named in this code, and none chooses how it is timed or
+judged: the mesh, the optimizer, the feed, the weights from the seed, the
+step, the window, the trace, the memory reading and the comparison are here.
+
 One object, the compiled step with its state, is built once. Set-up drives it
 through its first steps (which also warm it up) and reads what ``correct`` is
 decided on; the window then drives that same object with the same call and
@@ -47,6 +56,8 @@ TRACE_MAX_STEPS = 8
 def parse(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--config", required=True)
+    ap.add_argument("--architecture", required=True,
+                    help="the directory arch.find gives for --config")
     ap.add_argument("--traffic", required=True)
     ap.add_argument("--limits", required=True)
     ap.add_argument("--chips", type=int, required=True)
@@ -93,38 +104,28 @@ class Cell:
 
         import tony_tpu  # noqa: F401 — starts the telemetry reporter
         from tony_tpu import data, telemetry
-        from tony_tpu.models import Transformer, TransformerConfig
-        from tony_tpu.models.transformer import chunked_causal_lm_loss
         from tony_tpu.parallel import (MeshSpec, build_mesh,
                                        init_sharded_state, jit_train_step)
 
-        import counts
+        import arch
         import reference
 
         self.jax, self.telemetry, self.reference = jax, telemetry, reference
         self.opts, self.cfg, self.traffic = opts, cfg, traffic
         self.batch, self.seq = traffic["global_batch"], traffic["seq"]
         self.tokens_per_step = self.batch * self.seq
-        self.flops_per_step = counts.model_flops_per_token(
-            cfg, self.seq) * self.tokens_per_step
+        # All that the architecture has a say in: its leaves and plain loss,
+        # the operations a token costs, the model and the loss's wiring. How
+        # the step is built, fed, timed and judged is the same for every one.
+        self.model_ref = arch.load(opts.architecture, "reference")
+        self.flops_per_step = arch.load(
+            opts.architecture, "counts").model_flops_per_token(
+                cfg, self.seq) * self.tokens_per_step
+        model, loss_fn = arch.load(opts.architecture, "program").build(
+            cfg, traffic, opts.control)
         devices = jax.devices()[:opts.chips]
         self.mesh = build_mesh(MeshSpec.from_string(traffic["mesh"]),
                                devices=devices)
-        mcfg = TransformerConfig(
-            vocab_size=cfg["vocab_size"], dim=cfg["hidden_size"],
-            n_layers=cfg["num_hidden_layers"],
-            n_heads=cfg["num_attention_heads"],
-            n_kv_heads=cfg["num_key_value_heads"],
-            mlp_dim=cfg["intermediate_size"],
-            max_seq_len=max(self.seq, cfg["max_position_embeddings"]),
-            rope_theta=cfg["rope_theta"], norm_eps=cfg["rms_norm_eps"],
-            attn_impl="flash", remat=True, remat_policy=None,
-            tie_embeddings=cfg["tie_word_embeddings"],
-            matmul_dtype=opts.control or None)
-        if counts.head_dim(cfg) != mcfg.dim // mcfg.n_heads:
-            raise ValueError("the program derives head_dim as dim / heads; "
-                             "this configuration states another")
-        model = Transformer(mcfg)
         opt = optimizer_settings(cfg)
         tx = optax.adamw(opt["learning_rate"], b1=opt["b1"], b2=opt["b2"],
                          eps=opt["eps"], weight_decay=opt["weight_decay"])
@@ -142,19 +143,10 @@ class Cell:
         # in one call (reference.make_params), so that the reference can make
         # the same ones without taking anything from the program.
         jax.tree.map(lambda x: x.delete(), state.params)
-        params = jax.jit(lambda key: reference.make_params(cfg, key),
-                         out_shardings=state_sh.params)(
-                             reference.seed_key(opts.seed))
+        params = jax.jit(
+            lambda key: reference.make_params(self.model_ref, cfg, key),
+            out_shardings=state_sh.params)(reference.seed_key(opts.seed))
         self.state = state.replace(params=params)
-        chunk = traffic["loss_chunk"]
-
-        def loss_fn(params, batch, rng):
-            h = model.apply({"params": params}, batch["tokens"],
-                            return_hidden=True)
-            return chunked_causal_lm_loss(
-                h, params["lm_head"]["kernel"], batch["tokens"],
-                chunk_size=chunk, head_dtype=mcfg.lm_head_dtype), {}
-
         self.step = jit_train_step(loss_fn, self.mesh, state_sh, sample)
         if break_step is not None:        # the tests' planted faults
             self.step = break_step(self.step)
@@ -207,7 +199,8 @@ class Cell:
                 out["read_grad_s"] = time.time() - t0
         t0 = time.time()
         out["change_norms"] = ref.change_norms(
-            self.cfg, self.opts.seed, ref.flat(self.state.params)).tolist()
+            self.model_ref, self.cfg, self.opts.seed,
+            ref.flat(self.state.params)).tolist()
         out["read_change_s"] = time.time() - t0
         return out
 
@@ -270,6 +263,7 @@ def run_cell(opts, break_step=None) -> dict:
     held = load_json(opts.limits)
     import jax
 
+    import arch
     import counts
     import reference
 
@@ -338,10 +332,10 @@ def run_cell(opts, break_step=None) -> dict:
 
     # ---- the reference, and the comparison -------------------------------
     t_ref = time.time()
-    n_params = counts.total_params(cfg)
+    n_params = arch.load(opts.architecture, "counts").total_params(cfg)
     ref = reference.follow(
-        cfg, optimizer_settings(cfg), opts.seed, cell.batch, cell.seq,
-        held["reference"]["steps"], devices=devices,
+        cell.model_ref, cfg, optimizer_settings(cfg), opts.seed, cell.batch,
+        cell.seq, held["reference"]["steps"], devices=devices,
         offload_moments=held["reference"]["offload_moments"])
     verdict = reference.compare(program, ref, held["limits"])
     os.sync()       # likewise the reference's programs, before the next run
