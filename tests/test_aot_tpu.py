@@ -192,3 +192,70 @@ def test_train_step_scopes_reach_the_compiled_op_names(v5e_devices):
     assert in_grad and in_opt and not in_grad & in_opt
     assert any("transpose(jvp(" in n for n in in_grad), in_grad
     assert any("jvp(" in n and "transpose(" not in n for n in in_grad)
+
+
+# SmallThinker's attention at its published widths: 28 query heads of 128 in
+# groups of 7 a key/value head, a window of 4,096 in a sequence of 16,384.
+WINDOW_SHAPE = (1, 16384, 28, 4, 128)        # b, s, h, hk, d
+
+
+def test_windowed_flash_compiles_at_published_widths(v5e_devices,
+                                                     kernel_operands):
+    """The three windowed kernels pass Mosaic at the real tile sizes (the
+    band's grid axis, the clamped index maps, g = 7), under names of their
+    own."""
+    mesh = build_mesh(MeshSpec(), devices=v5e_devices[:1])
+    b, s, h, hk, d = WINDOW_SHAPE
+    spec = P(BATCH_AXES, None, "tp", None)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, window=4096).astype(
+            jnp.float32).sum()
+
+    args = (_abstract((b, s, h, d), jnp.bfloat16, mesh, spec),
+            _abstract((b, s, hk, d), jnp.bfloat16, mesh, spec),
+            _abstract((b, s, hk, d), jnp.bfloat16, mesh, spec))
+    with jax.set_mesh(mesh):
+        hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            *args).compile().as_text()
+    names = sorted(c["name"] for c in kernel_operands(hlo))
+    assert len(names) == 3, names
+    for name, kernel in zip(names, ("flash_win_dkv", "flash_win_dq",
+                                    "flash_win_fwd")):
+        assert name.startswith(kernel), names
+
+
+@pytest.mark.parametrize("matmul_dtype", ["", "int8"])
+def test_expert_layer_compiles_at_published_widths(v5e_devices,
+                                                   kernel_operands,
+                                                   matmul_dtype):
+    """The grouped matmuls pass Mosaic at SmallThinker's widths (hidden
+    2560, experts of 768, 16 of 64 held, 6 a token, row tiles of 256): the
+    scalar-prefetched expert table, the clamped row tiles, a [2560, 768]
+    matrix double-buffered under the raised VMEM limit; and with int8
+    operands and their scales in the forward products."""
+    import flax.linen as nn
+
+    from tony_tpu.models.moe import ExpertLayer, ExpertSpec
+
+    mesh = build_mesh(MeshSpec(), devices=v5e_devices[:1])
+    spec = ExpertSpec(n_experts=64, top_k=6, width=768, activation="relu",
+                      held=(0, 16), route_before_attention=True,
+                      chunk_tokens=2048)
+    layer = ExpertLayer(spec, jnp.bfloat16, matmul_dtype=matmul_dtype)
+    x = _abstract((1, 4096, 2560), jnp.bfloat16, mesh,
+                  P(BATCH_AXES, None, None))
+    tiny = jnp.zeros((1, 8, 2560), jnp.bfloat16)
+    shapes = jax.eval_shape(lambda: nn.meta.unbox(
+        layer.init(jax.random.key(0), tiny, tiny))["params"])
+    params = jax.tree.map(
+        lambda a: _abstract(a.shape, a.dtype, mesh, P()), shapes)
+
+    def loss(p, r, x):
+        return layer.apply({"params": p}, r, x).astype(jnp.float32).sum()
+
+    with jax.set_mesh(mesh):
+        hlo = jax.jit(jax.grad(loss, argnums=(0, 2))).lower(
+            params, x, x).compile().as_text()
+    names = {c["name"].split(".")[0] for c in kernel_operands(hlo)}
+    assert names == {"moe_gmm", "moe_tgmm"}, names

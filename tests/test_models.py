@@ -126,19 +126,13 @@ def test_block_remat_keeps_the_flash_forward_outputs(family, remat_policy,
     dkv run once a layer either way."""
     import flax.linen as nn
     from jaxpr_kernels import pallas_calls
-    from tony_tpu.models.moe import MoEConfig, MoETransformer, moe_lm_loss
+    from tony_tpu.models.moe import MoEConfig
     from tony_tpu.parallel.sharding import DEFAULT_RULES
 
     kw = dict(attn_impl="flash", remat=True, remat_policy=remat_policy)
-    if family == "dense":
-        cfg = TransformerConfig.tiny(**kw)
-        model, loss_of = Transformer(cfg), causal_lm_loss
-    else:
-        cfg = MoEConfig.tiny_moe(**kw)
-        model = MoETransformer(cfg)
-
-        def loss_of(out, tokens):
-            return moe_lm_loss(out, tokens, cfg.aux_loss_weight)
+    cfg = (TransformerConfig.tiny if family == "dense"
+           else MoEConfig.tiny_moe)(**kw)
+    model, loss_of = Transformer(cfg), causal_lm_loss
     tokens = jax.random.randint(jax.random.key(0), (2, 32), 0,
                                 cfg.vocab_size)
     with nn.logical_axis_rules(list(DEFAULT_RULES)):
@@ -147,8 +141,15 @@ def test_block_remat_keeps_the_flash_forward_outputs(family, remat_policy,
         calls = pallas_calls(jax.make_jaxpr(jax.grad(
             lambda p: loss_of(model.apply({"params": p}, tokens), tokens)))(
                 params))
-    assert calls == {"flash_fwd": fwd_per_layer * cfg.n_layers,
+    flash = {k: v for k, v in calls.items() if k.startswith("flash")}
+    assert flash == {"flash_fwd": fwd_per_layer * cfg.n_layers,
                      "flash_dq": cfg.n_layers, "flash_dkv": cfg.n_layers}
+    # The expert layer's grouped matmuls are named calls too (gate, up and
+    # down a layer): forward, the block's recompute, and each chunk's own.
+    assert set(calls) - set(flash) == (
+        set() if family == "dense" else {"moe_gmm", "moe_tgmm"})
+    if family == "moe":
+        assert calls["moe_tgmm"] == 3 * cfg.n_layers
 
 
 def test_transformer_trains_sharded_tp_fsdp():

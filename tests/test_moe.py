@@ -1,5 +1,6 @@
-"""MoE / expert parallelism on the virtual 8-device CPU mesh
-(SURVEY.md §2.3 — EP is a first-class requirement, no reference analogue)."""
+"""The expert layer (models/moe.py) against a plain dense masked sum, and
+expert parallelism on the virtual 8-device CPU mesh (SURVEY.md §2.3 — EP is
+a first-class requirement, no reference analogue)."""
 
 import flax.linen as nn
 import jax
@@ -8,67 +9,267 @@ import numpy as np
 import optax
 import pytest
 
-from tony_tpu.models.moe import (MoEConfig, MoEMLP, MoETransformer,
-                                 moe_lm_loss)
+from tony_tpu.models.moe import (ExpertLayer, ExpertSpec, MoEConfig,
+                                 _gmm_call, _layout, _tgmm_call,
+                                 moe_counters)
+from tony_tpu.models.transformer import Transformer, causal_lm_loss
 from tony_tpu.parallel import MeshSpec, build_mesh, init_sharded_state
 from tony_tpu.parallel.sharding import DEFAULT_RULES
+
+D = 24
 
 
 def _rules():
     return nn.logical_axis_rules(list(DEFAULT_RULES))
 
 
-def test_single_expert_equals_dense_mlp():
-    """E=1, k=1, generous capacity: routing is the identity, so the MoE MLP
-    must equal a plain gated-silu MLP with the same weights."""
-    cfg = MoEConfig.tiny_moe(n_experts=1, top_k=1, capacity_factor=2.0)
-    x = jax.random.normal(jax.random.key(0), (2, 16, cfg.dim))
-    moe = MoEMLP(cfg)
+def _spec(**kw):
+    base = dict(n_experts=8, top_k=3, width=32, activation="relu",
+                tile_rows=8, chunk_tokens=16)
+    base.update(kw)
+    return ExpertSpec(**base)
+
+
+def _inputs(spec, tokens=(2, 16), seed=0):
+    ks = jax.random.split(jax.random.key(seed), 3)
+    x = jax.random.normal(ks[0], (*tokens, D))
+    r = jax.random.normal(ks[1], (*tokens, D))
+    layer = ExpertLayer(spec, jnp.float32)
     with _rules():
-        variables = moe.init(jax.random.key(1), x)
-        out, aux = moe.apply(variables, x)
-    p = nn.meta.unbox(variables)["params"]
-    w_gate, w_up, w_down = p["gate"][0], p["up"][0], p["down"][0]
-    want = nn.silu(x @ w_gate) * (x @ w_up) @ w_down
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
-    assert float(aux) == pytest.approx(1.0)  # all mass on the one expert
+        params = nn.meta.unbox(layer.init(ks[2], r, x))["params"]
+    return layer, params, r, x
 
 
-def test_capacity_respected_and_balanced_uniform_router():
-    """With a zeroed router every token ties; top-k dispatch must respect
-    per-expert capacity exactly and spread slot-0 tokens by tie-break."""
-    cfg = MoEConfig.tiny_moe(n_experts=4, top_k=2, capacity_factor=1.0)
-    x = jax.random.normal(jax.random.key(0), (2, 32, cfg.dim))
-    moe = MoEMLP(cfg)
-    with _rules():
-        variables = moe.init(jax.random.key(1), x)
-    import flax
-
-    params = nn.meta.unbox(variables)["params"]
-    flat = flax.traverse_util.flatten_dict(params, sep="/")
-    flat = {k: (jnp.zeros_like(v) if k.startswith("router") else v)
-            for k, v in flat.items()}  # zero router → uniform probs
-    params = flax.traverse_util.unflatten_dict(flat, sep="/")
-    with _rules():
-        out, aux = MoEMLP(cfg).apply({"params": params}, x)
-    assert bool(jnp.isfinite(out).all())
+def dense_masked_sum(spec, p, r, x):
+    """The plain form: every held expert over every token, weighted by the
+    token's routing weight for it (zero where it was not chosen)."""
+    first, count = spec.held or (0, spec.n_experts)
+    act = {"relu": jax.nn.relu, "silu": jax.nn.silu}[spec.activation]
+    t, rt = x.reshape(-1, D), r.reshape(-1, D)
+    top, idx = jax.lax.top_k(rt @ p["router"], spec.top_k)
+    w = jax.nn.softmax(top, -1)
+    out = 0
+    for e in range(count):
+        we = jnp.sum(jnp.where(idx == first + e, w, 0), -1)
+        ye = (act(t @ p["gate"][e]) * (t @ p["up"][e])) @ p["down"][e]
+        out = out + we[:, None] * ye
+    return out.reshape(x.shape)
 
 
-def test_moe_transformer_trains_on_ep_mesh():
-    """Full train step on a dp×ep mesh: loss finite and decreasing, and the
-    compiled program moves tokens with all-to-all over ep."""
-    mesh = build_mesh(MeshSpec(dp=4, ep=2))
-    cfg = MoEConfig.tiny_moe()
-    model = MoETransformer(cfg)
-    tokens = jax.random.randint(jax.random.key(0), (8, 32), 0,
-                                cfg.vocab_size)
-    state, sh = init_sharded_state(model, tokens, optax.adam(3e-3), mesh)
+def _same(spec, params, r, x, layer):
+    with jax.default_matmul_precision("highest"):
+        got = layer.apply({"params": params}, r, x)
+        want = dense_masked_sum(spec, params, r, x)
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+        grads = [jax.grad(lambda p, r, x: jnp.sum(jnp.sin(f(p, r, x))),
+                          argnums=(0, 1, 2))(params, r, x)
+                 for f in (lambda p, r, x: layer.apply({"params": p}, r, x),
+                           lambda p, r, x: dense_masked_sum(spec, p, r, x))]
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(
+        a, b, atol=5e-5, rtol=5e-5), *grads)
+    return grads[0]
+
+
+@pytest.mark.parametrize("kw", [
+    dict(), dict(activation="silu"), dict(chunk_tokens=8192),
+    dict(held=(2, 4)), dict(held=(6, 2), top_k=6), dict(tile_rows=16),
+    dict(n_experts=1, top_k=1)],
+    ids=["relu", "silu", "one-chunk", "held-2..5", "held-6..7-k6", "tile16",
+         "one-expert"])
+def test_expert_layer_is_the_dense_masked_sum(kw):
+    """Forward and every gradient (experts, router, both inputs): nothing is
+    dropped and nothing densified, whatever is held here."""
+    spec = _spec(**kw)
+    layer, params, r, x = _inputs(spec)
+    _same(spec, params, r, x, layer)
+
+
+@pytest.mark.parametrize("hot", [1, 3])
+def test_no_token_is_dropped_at_any_load(hot):
+    """Every token sends its first ``hot`` choices to the same experts: the
+    fullest load a router can make. A capacity would drop rows here."""
+    spec = _spec()
+    layer, params, r, x = _inputs(spec)
+    bias = jnp.zeros((D, spec.n_experts)).at[0, :hot].set(50.0)
+    params = dict(params, router=params["router"] * 0.01 + bias)
+    r = r.at[..., 0].set(1.0)
+    _same(spec, params, r, x, layer)
+    _, state = layer.apply({"params": params}, r, x,
+                           mutable=["intermediates"])
+    counters = moe_counters(state["intermediates"])
+    assert float(counters["moe_expert_load_max_over_mean"]) >= 8 / 3 - 1e-6
+
+
+def test_an_expert_with_no_rows_gets_a_zero_gradient():
+    spec = _spec(top_k=2)
+    layer, params, r, x = _inputs(spec)
+    bias = jnp.zeros((D, spec.n_experts)).at[0, 5].set(-50.0)
+    params = dict(params, router=params["router"] * 0.01 + bias)
+    r = r.at[..., 0].set(1.0)
+    grads = _same(spec, params, r, x, layer)[0]
+    for name in ("gate", "up", "down"):
+        assert not np.asarray(grads[name][5]).any()
+        assert np.asarray(grads[name][4]).any()
+
+
+def test_the_shares_add_up():
+    """Four shares of two experts each, every one routing over all eight,
+    sum to the layer that holds all eight."""
+    whole = _spec()
+    layer, params, r, x = _inputs(whole)
+    want = layer.apply({"params": params}, r, x)
+    got = 0
+    for first in range(0, 8, 2):
+        share = _spec(held=(first, 2))
+        part = {k: (v if k == "router" else v[first:first + 2])
+                for k, v in params.items()}
+        got = got + ExpertLayer(share, jnp.float32).apply(
+            {"params": part}, r, x)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+def test_counters_of_a_share():
+    spec = _spec(held=(0, 2), top_k=2)
+    layer, params, r, x = _inputs(spec, tokens=(4, 64))
+    _, state = layer.apply({"params": params}, r, x,
+                           mutable=["intermediates"])
+    c = {k: float(v) for k, v in moe_counters(
+        state["intermediates"]).items()}
+    _, idx = jax.lax.top_k(r.reshape(-1, D) @ params["router"], 2)
+    held = np.asarray(idx) < 2
+    assert c["moe_rows_routed"] == held.sum()
+    assert c["moe_rows_unrouted_share"] == pytest.approx(
+        1 - held.any(-1).mean())
+    load = np.array([(np.asarray(idx) == e).sum() for e in range(2)])
+    assert c["moe_expert_load_max_over_mean"] == pytest.approx(
+        load.max() / load.mean())
+    assert moe_counters({}) == {}
+
+
+@pytest.mark.parametrize("transpose", [False, True])
+def test_grouped_matmul_kernel(transpose):
+    """moe_gmm over a hand-made layout with an empty expert and spare
+    tiles: live rows equal their expert's product."""
+    idx = jnp.array([[0], [2], [2], [0], [2], [2], [2], [2], [2], [2], [2]],
+                    jnp.int32)
+    held, pos, row_pair, live, te, na, sizes = _layout(idx, 0, 3, 4, 24)
+    assert sizes.tolist() == [2, 0, 9] and int(na[0]) == 5
+    assert te.tolist() == [0, 1, 2, 2, 2, 2]
+    ks = jax.random.split(jax.random.key(0), 2)
+    k, n = (16, 8)
+    lhs = jax.random.normal(ks[0], (24, n if transpose else k))
+    rhs = jax.random.normal(ks[1], (3, k, n))
+    out = _gmm_call(lhs, rhs, te, na, tile_rows=4, transpose_rhs=transpose)
+    row_expert = np.repeat(np.asarray(te), 4)
+    for r in np.flatnonzero(np.asarray(live)):
+        m = rhs[row_expert[r]]
+        np.testing.assert_allclose(
+            out[r], lhs[r] @ (m.T if transpose else m), atol=1e-5)
+
+
+def test_grouped_weight_gradient_kernel():
+    """moe_tgmm: an expert's block is the sum over its own rows, zero for an
+    expert without rows, whatever lies in the tiles past the live ones."""
+    idx = jnp.array([[0], [2], [2], [0], [2], [2], [2], [2], [2], [2], [2]],
+                    jnp.int32)
+    _, _, _, live, te, na, _ = _layout(idx, 0, 3, 4, 24)
+    ks = jax.random.split(jax.random.key(0), 2)
+    lhs = jax.random.normal(ks[0], (24, 16))
+    rhs = jnp.where(live[:, None], jax.random.normal(ks[1], (24, 8)), 0.0)
+    rhs = rhs.at[20:].set(jnp.nan)        # unwritten rows: never read
+    out = _tgmm_call(lhs, rhs, te, na, tile_rows=4, count=3)
+    row_expert = np.repeat(np.asarray(te), 4)
+    for e in range(3):
+        rows = np.flatnonzero(np.asarray(live) & (row_expert == e))
+        np.testing.assert_allclose(out[e], lhs[rows].T @ rhs[rows],
+                                   atol=1e-5)
+
+
+def test_int8_experts_quantize_the_forward_alone():
+    """``matmul_dtype="int8"``: the three forward products are the int8
+    ones of the quantized rows and matrices exactly, and the gradients are
+    those of the unquantized products at that forward (straight through).
+    Another quantized dtype is refused by name."""
+    from tony_tpu.ops.quant import quantize_symmetric
+
+    spec = _spec(held=(2, 4))
+    layer, params, r, x = _inputs(spec)
+    int8 = ExpertLayer(spec, jnp.float32, matmul_dtype="int8")
+
+    def fake(v, axis):          # what int8 keeps of a tensor, as float32
+        q, scale = quantize_symmetric(v, "int8", axis)
+        return q.astype(jnp.float32) * scale
+
+    def quantized_dense_sum(p, r, x):
+        t, rt = x.reshape(-1, D), r.reshape(-1, D)
+        top, idx = jax.lax.top_k(rt @ p["router"], spec.top_k)
+        w = jax.nn.softmax(top, -1)
+        out = 0
+        for e in range(4):
+            we = jnp.sum(jnp.where(idx == 2 + e, w, 0), -1)
+            hidden = jax.nn.relu(fake(t, -1) @ fake(p["gate"][e], 0)) \
+                * (fake(t, -1) @ fake(p["up"][e], 0))
+            out = out + we[:, None] * (fake(hidden, -1)
+                                       @ fake(p["down"][e], 0))
+        return out.reshape(x.shape)
+
+    with jax.default_matmul_precision("highest"):
+        got = int8.apply({"params": params}, r, x)
+        np.testing.assert_allclose(got, quantized_dense_sum(params, r, x),
+                                   atol=2e-5, rtol=2e-5)
+        plain = layer.apply({"params": params}, r, x)
+        gap = float(jnp.linalg.norm(got - plain) / jnp.linalg.norm(plain))
+        assert 1e-3 < gap < 5e-2, gap
+        g8, g = (jax.grad(lambda p, f=f: jnp.sum(jnp.sin(
+            f.apply({"params": p}, r, x))))(params) for f in (int8, layer))
+    for name in ("up", "down", "router"):   # gate meets ReLU's flips
+        rel = float(jnp.linalg.norm(g8[name] - g[name])
+                    / jnp.linalg.norm(g[name]))
+        assert 0 < rel < 5e-2, (name, rel)
+    with pytest.raises(ValueError, match="int8 path alone"):
+        ExpertLayer(spec, jnp.float32, matmul_dtype="fp8_e4m3").apply(
+            {"params": params}, r, x)
+
+
+# ---------------------------------------------------------------------------
+# Expert parallelism on a CPU mesh
+# ---------------------------------------------------------------------------
+def _lm(cfg, mesh, tokens):
+    model = Transformer(cfg)
+    state, sh = init_sharded_state(model, tokens, optax.adam(3e-3), mesh,
+                                   rng=jax.random.key(5))
 
     def loss_fn(p):
         with _rules():
-            return moe_lm_loss(model.apply({"params": p}, tokens), tokens,
-                               cfg.aux_loss_weight)
+            return causal_lm_loss(model.apply({"params": p}, tokens), tokens)
+
+    return state, loss_fn
+
+
+def test_ep4_equals_one_device():
+    """The same weights on dp=2 x ep=4 and on dp=8: loss and gradients."""
+    cfg = MoEConfig.tiny_moe(attn_impl="flash")
+    tokens = jax.random.randint(jax.random.key(0), (8, 32), 0,
+                                cfg.vocab_size)
+    out = []
+    for spec in (MeshSpec(dp=2, ep=4), MeshSpec(dp=8)):
+        mesh = build_mesh(spec)
+        state, loss_fn = _lm(cfg, mesh, tokens)
+        with jax.set_mesh(mesh):
+            loss, grads = jax.jit(jax.value_and_grad(loss_fn))(state.params)
+        out.append((float(loss), jax.tree.map(np.asarray, grads)))
+    assert out[0][0] == pytest.approx(out[1][0], rel=1e-6)
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(
+        a, b, atol=2e-6, rtol=2e-5), out[0][1], out[1][1])
+
+
+def test_moe_transformer_trains_on_ep_mesh():
+    """Full train step on a dp×ep mesh: loss finite and decreasing."""
+    mesh = build_mesh(MeshSpec(dp=4, ep=2))
+    cfg = MoEConfig.tiny_moe()
+    tokens = jax.random.randint(jax.random.key(0), (8, 32), 0,
+                                cfg.vocab_size)
+    state, loss_fn = _lm(cfg, mesh, tokens)
 
     @jax.jit
     def step(state):
@@ -87,61 +288,29 @@ def test_moe_transformer_trains_on_ep_mesh():
 def test_moe_expert_weights_sharded_over_ep():
     mesh = build_mesh(MeshSpec(dp=4, ep=2))
     cfg = MoEConfig.tiny_moe()
-    model = MoETransformer(cfg)
-    tokens = jnp.zeros((8, 16), jnp.int32)
-    state, sh = init_sharded_state(model, tokens, optax.adam(1e-3), mesh)
+    state, _ = _lm(cfg, mesh, jnp.zeros((8, 16), jnp.int32))
     gate = state.params["layer_0"]["moe"]["gate"]
-    assert gate.shape[0] == cfg.n_experts
+    assert gate.shape[0] == cfg.layer(0).experts.n_experts
     for shard in gate.addressable_shards:
-        assert shard.data.shape[0] == cfg.n_experts // mesh.shape["ep"]
+        assert shard.data.shape[0] == gate.shape[0] // mesh.shape["ep"]
 
 
-def test_moe_dispatch_is_all_to_all_on_ep_mesh():
+def test_ep_sums_the_shares_and_moves_no_rows():
+    """Under ep the shards' partial results meet in one reduce-scatter (or
+    the all-reduce it folds into); no all-to-all, which needed a capacity."""
     mesh = build_mesh(MeshSpec(dp=4, ep=2))
     cfg = MoEConfig.tiny_moe()
-    model = MoETransformer(cfg)
-    tokens = jnp.zeros((8, 16), jnp.int32)
-    state, sh = init_sharded_state(model, tokens, optax.adam(1e-3), mesh)
-
-    def loss_fn(p):
-        with _rules():
-            return moe_lm_loss(model.apply({"params": p}, tokens), tokens,
-                               cfg.aux_loss_weight)
-
+    state, loss_fn = _lm(cfg, mesh, jnp.zeros((8, 16), jnp.int32))
     with jax.set_mesh(mesh):
         txt = jax.jit(jax.grad(loss_fn)).lower(state.params).compile()\
             .as_text()
-    assert "all-to-all" in txt, "expert dispatch did not lower to all_to_all"
+    assert "reduce-scatter" in txt or "all-reduce" in txt
+    assert "all-to-all" not in txt
 
 
-def test_aux_loss_penalizes_imbalance():
-    """Collapsed routing (all tokens → expert 0) must score a higher aux
-    loss than uniform routing."""
-    cfg = MoEConfig.tiny_moe(n_experts=4, top_k=1)
-    x = jax.random.normal(jax.random.key(0), (1, 64, cfg.dim))
-    moe = MoEMLP(cfg)
-    with _rules():
-        variables = moe.init(jax.random.key(1), x)
-
-    import flax
-
-    flat = flax.traverse_util.flatten_dict(
-        nn.meta.unbox(variables)["params"], sep="/")
-    flat = {k: jnp.asarray(v) for k, v in flat.items()}
-    collapsed = dict(flat)
-    kernel = collapsed["router/kernel"]
-    bias_to_zero = jnp.zeros_like(kernel).at[:, 0].set(10.0)
-    collapsed["router/kernel"] = bias_to_zero
-    uniform = dict(flat)
-    uniform["router/kernel"] = jnp.zeros_like(kernel)
-
-    def aux_of(p):
-        with _rules():
-            _, aux = MoEMLP(cfg).apply(
-                {"params": flax.traverse_util.unflatten_dict(p, sep="/")}, x)
-        return float(aux)
-
-    # Uniform routing is the analytic minimum of the Switch loss (== 1.0);
-    # any skew toward one expert must score strictly worse.
-    assert aux_of(uniform) == pytest.approx(1.0, abs=1e-5)
-    assert aux_of(collapsed) > aux_of(uniform) + 0.1
+def test_ep_refuses_a_named_share():
+    mesh = build_mesh(MeshSpec(dp=4, ep=2))
+    spec = _spec(held=(0, 4))
+    layer, params, r, x = _inputs(spec)
+    with jax.set_mesh(mesh), pytest.raises(ValueError, match="no share"):
+        jax.jit(lambda p: layer.apply({"params": p}, r, x))(params)

@@ -178,6 +178,29 @@ def test_int8_loss_parity_golden_50_steps():
     np.testing.assert_allclose(quantized, golden, rtol=0.05, atol=0.05)
 
 
+def test_int8_loss_parity_with_experts():
+    """The same gate where the feed-forward is the expert layer: there
+    ``matmul_dtype="int8"`` has to reach the grouped matmuls, which are
+    most of a sparse model's products, or the knob would quantize the
+    attention projections alone and say nothing."""
+    from jaxpr_kernels import pallas_calls
+    from tony_tpu.models import Transformer
+    from tony_tpu.models.moe import MoEConfig
+
+    golden = _train_losses(MoEConfig.tiny_moe(), steps=20)
+    cfg = MoEConfig.tiny_moe(matmul_dtype="int8")
+    quantized = _train_losses(cfg, steps=20)
+    assert golden[-1] < golden[0] and quantized[-1] < quantized[0]
+    assert (quantized != golden).any()
+    np.testing.assert_allclose(quantized, golden, rtol=0.05, atol=0.05)
+    # the experts' forward products are int8 by int8 inside ``moe_gmm``
+    tokens = jnp.zeros((2, 32), jnp.int32)
+    model = Transformer(cfg)
+    params = jax.eval_shape(model.init, jax.random.key(0), tokens)
+    jaxpr = jax.make_jaxpr(model.apply)(params, tokens)
+    assert "i8[" in str(jaxpr) and pallas_calls(jaxpr)["moe_gmm"]
+
+
 def test_fp8_path_tracks_golden():
     from tony_tpu.models import TransformerConfig
 

@@ -1,0 +1,54 @@
+"""A step's own counters: what the loss function returns as aux metrics
+reaches the task's metrics file through ``telemetry.note_step_counters``,
+unread on the step's path."""
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+
+from tony_tpu import telemetry
+from tony_tpu.models import MnistMLP
+from tony_tpu.parallel import (MeshSpec, build_mesh, init_sharded_state,
+                               jit_train_step)
+
+
+@pytest.fixture(autouse=True)
+def clean():
+    telemetry.note_step_counters({})
+    yield
+    telemetry.note_step_counters({})
+
+
+def test_counters_reach_the_metrics_stream():
+    telemetry.note_step_counters({"moe_rows_routed": jnp.float32(12.0),
+                                  "plain": 3, "a_vector": jnp.ones(3)})
+    assert telemetry.step_counters() == {"moe_rows_routed": 12.0,
+                                         "plain": 3.0}
+    with telemetry.step():
+        pass
+    stats = telemetry.collect_device_stats()
+    assert stats["step_counters"] == {"moe_rows_routed": 12.0, "plain": 3.0}
+    telemetry.note_step_counters({})
+    assert "step_counters" not in telemetry.collect_device_stats()
+
+
+@pytest.mark.parametrize("with_aux", [True, False])
+def test_train_step_hands_over_the_aux_metrics_alone(with_aux):
+    mesh = build_mesh(MeshSpec(dp=8))
+    model = MnistMLP()
+    batch = {"x": jnp.ones((8, 784)), "y": jnp.zeros((8,), jnp.int32)}
+    state, sh = init_sharded_state(model, batch["x"], optax.sgd(0.1), mesh)
+
+    def loss_fn(params, batch, rng):
+        logits = model.apply({"params": params}, batch["x"])
+        loss = optax.softmax_cross_entropy_with_integer_labels(
+            logits, batch["y"]).mean()
+        return loss, ({"rows": jnp.float32(batch["x"].shape[0])}
+                      if with_aux else {})
+
+    step = jit_train_step(loss_fn, mesh, sh, batch)
+    state, metrics = step(state, batch, jax.random.key(0))
+    assert set(metrics) == {"loss", "step"} | ({"rows"} if with_aux
+                                               else set())
+    assert telemetry.step_counters() == ({"rows": 8.0} if with_aux else {})

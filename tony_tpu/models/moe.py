@@ -1,27 +1,47 @@
-"""Mixture-of-Experts transformer with expert parallelism over the ``ep``
-mesh axis.
+"""Sparse experts: one expert layer, told which experts it holds.
 
 No reference analogue — TonY has no expert/model parallelism anywhere
 (SURVEY.md §2.3, verified absent); this is TPU-first new work.
 
-Design (GShard/Switch-style dense dispatch — the TPU-idiomatic formulation):
-- Expert FFN weights are stacked ``[n_experts, ...]`` with logical axis
-  ``expert → ep``; the router is a small replicated Dense.
-- Dispatch/combine are **einsums against one-hot dispatch tensors**, not
-  gather/scatter — dense MXU work instead of dynamic indexing the TPU
-  can't tile (pallas_guide.md: avoid data-dependent shapes under jit;
-  capacity-factor padding keeps every shape static).
-- The expert exchange is an explicit ``lax.all_to_all`` pair inside a
-  *partial-manual* ``shard_map`` over the ``ep`` axis only (dp/fsdp/tp
-  stay auto): each ep shard routes its token group locally (GShard
-  "groups" = ep shards, per-group capacity), ships expert-major slices to
-  the expert owners over ICI, FFNs its resident experts, and ships results
-  back. Token tensors never pass through an all-gather.
-- Top-k routing (k configurable) with per-group per-expert capacity
-  ``c = ceil(k·T_group/E · capacity_factor)``; tokens over capacity are
-  dropped (their residual path passes through — standard Switch behaviour).
-- Aux load-balancing loss (Switch eq. 4: E · Σ_e fraction_e · prob_e) is
-  returned alongside the logits so the train loss can add it.
+The layer routes every token over ALL of the model's experts (the router
+keeps its published width) and computes the part of the result that the
+experts **held here** give: ``experts_held = (first, count)``, the chip's
+share of a deployment whose other experts live on further chips, or every
+expert where none is named. What the absent experts would add is left out;
+the shares of all the chips add up to the whole layer
+(``tests/test_moe.py``).
+
+- **Router**: a float32 matmul at ``highest`` precision on the block's
+  normed PRE-attention input (it reads what attention reads), top-k of the
+  logits, softmax over the chosen k. Routing is discrete, so the one matmul
+  whose rounding can move a choice is the one kept exact.
+- **No capacity, no dropped token.** The (token, choice) pairs that met a
+  held expert are laid out expert by expert (a counting sort: a cumulative
+  sum of one-hots gives each pair its place, one ``argsort`` gives each row
+  its pair), every expert's rows padded to the matmul's row tile, never to
+  a capacity. Shapes are static under ``jit``: the buffer has room for every
+  pair a chunk of tokens could send here, and the kernels skip the tiles
+  past the rows that came, in compute and in DMA.
+- **Grouped matmuls** are named Mosaic calls: ``moe_gmm`` (forward and
+  input gradient: each row tile times its own expert's matrix, the expert
+  read from a prefetched table) and ``moe_tgmm`` (weight gradient: the row
+  tiles of an expert accumulated into its matrix). The backward never
+  densifies: dispatch and combine are gathers in both directions.
+- Tokens go through in chunks (``chunk_tokens``), each recomputed in the
+  backward pass, so the row buffers are a chunk's and not the batch's.
+- **Expert parallelism** (an ``ep`` mesh axis > 1): the same body runs per
+  shard on all of its group's rows (rows are split over the batch axes and
+  never over ``ep``, so every ``ep`` shard has them: gathered), each shard
+  holding ``n_experts / ep`` experts from ``axis_index · count``; the
+  partial results are summed and scattered back (``psum_scatter``). That is
+  "the shares add up" as a collective; no capacity is needed because no
+  shard ever receives rows, it selects its own.
+
+Spans: ``tony.moe.route``, ``tony.moe.dispatch``, ``tony.moe.experts``,
+``tony.moe.combine`` (``jax.named_scope``). Counters, sown into the
+``intermediates`` collection and reduced by ``moe_counters``:
+``moe_rows_routed``, ``moe_rows_unrouted_share``,
+``moe_expert_load_max_over_mean``.
 """
 
 from __future__ import annotations
@@ -29,239 +49,601 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Tuple
+from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 from tony_tpu import compat
-from tony_tpu.models.transformer import (Attention, RMSNorm,
-                                         TransformerConfig, remat_policy_of)
+from tony_tpu.ops.attention import _interpret, _prec
+from tony_tpu.ops.quant import INT8, quantize_symmetric, resolve_mode
+from tony_tpu.parallel.mesh import BATCH_AXES
+
+EP_AXIS = "ep"
+ACTIVATIONS = {"silu": nn.silu, "relu": nn.relu}
+# Mosaic's scoped VMEM default (16 MiB) is under a [2560, 768] expert
+# matrix double-buffered beside its row tiles; the v5e has 128 MiB.
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 
 @dataclasses.dataclass(frozen=True)
-class MoEConfig(TransformerConfig):
-    n_experts: int = 8
-    top_k: int = 2
-    capacity_factor: float = 1.25
-    aux_loss_weight: float = 0.01
+class ExpertSpec:
+    """A layer's sparse feed-forward: ``n_experts`` router outputs,
+    ``top_k`` experts a token, gated experts of hidden ``width`` with
+    ``activation`` on the gate, and ``held = (first, count)``, the experts
+    that live here (None: all of them)."""
+    n_experts: int
+    top_k: int
+    width: int
+    activation: str = "silu"
+    held: Optional[Tuple[int, int]] = None
+    # The router reads the block's normed PRE-attention input (what
+    # attention reads) and not the experts' own input.
+    route_before_attention: bool = False
+    tile_rows: int = 256        # the grouped matmul's row tile
+    chunk_tokens: int = 8192    # tokens routed at a time
 
-    @classmethod
-    def tiny_moe(cls, **kw) -> "MoEConfig":
+    def __post_init__(self):
+        first, count = self.held or (0, self.n_experts)
+        if not (0 <= first and count >= 1
+                and first + count <= self.n_experts):
+            raise ValueError(f"experts held {self.held} are not a range of "
+                             f"the {self.n_experts} experts")
+        if not 1 <= self.top_k <= self.n_experts:
+            raise ValueError(f"top_k {self.top_k} of {self.n_experts}")
+        if self.activation not in ACTIVATIONS:
+            raise ValueError(f"activation {self.activation!r} is not one "
+                             f"of {sorted(ACTIVATIONS)}")
+
+
+class MoEConfig:
+    """Constructors of ``TransformerConfig``s whose every layer has
+    experts (``examples/moe/``)."""
+
+    @staticmethod
+    def tiny_moe(n_experts: int = 4, top_k: int = 2, **kw):
+        from tony_tpu.models.transformer import (LayerSpec,
+                                                 TransformerConfig)
+
         defaults = dict(vocab_size=256, dim=64, n_layers=2, n_heads=4,
                         n_kv_heads=2, mlp_dim=128, max_seq_len=128,
-                        dtype=jnp.float32, remat=False, n_experts=4,
-                        top_k=2)
+                        dtype=jnp.float32, remat=False)
         defaults.update(kw)
-        return cls(**defaults)
+        experts = ExpertSpec(n_experts=n_experts, top_k=top_k,
+                             width=defaults["mlp_dim"], tile_rows=8)
+        defaults.setdefault("layers", (LayerSpec(experts=experts),)
+                            * defaults["n_layers"])
+        return TransformerConfig(**defaults)
 
 
-def _routed_ffn_group(cfg: MoEConfig, xt: jax.Array, probs: jax.Array,
-                      w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
-                      n_ep: int) -> jax.Array:
-    """One routing group's expert FFN. ``xt``/``probs`` are the group's
-    [T_g, D]/[T_g, E] slices; ``w_*`` are the E/n_ep resident experts'
-    weights. Runs per-shard under shard_map when n_ep > 1."""
-    t, d = xt.shape
-    e, k = cfg.n_experts, cfg.top_k
-    capacity = max(k, int(math.ceil(k * t / e * cfg.capacity_factor)))
+# ---------------------------------------------------------------------------
+# Grouped matmul kernels: every row tile belongs to one expert
+# ---------------------------------------------------------------------------
+def _gmm_kernel(tile_expert, n_active, lhs_ref, rhs_ref, *rest,
+                transpose_rhs: bool):
+    del tile_expert
+    *scales, out_ref = rest     # int8 operands bring their two scales
 
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)          # [T_g, k]
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
-
-    # Position-in-expert with slot priority: slot 0 of every token beats
-    # slot 1, earlier tokens beat later ones (deterministic, static).
-    dispatch = jnp.zeros((t, e, capacity), cfg.dtype)
-    combine = jnp.zeros((t, e, capacity), jnp.float32)
-    offset = jnp.zeros((e,), jnp.int32)
-    for slot in range(k):
-        onehot = jax.nn.one_hot(gate_idx[:, slot], e, dtype=jnp.int32)
-        loc = jnp.cumsum(onehot, axis=0) - 1 + offset[None, :]
-        offset = offset + jnp.sum(onehot, axis=0)
-        keep = (onehot > 0) & (loc < capacity)             # [T_g, E]
-        loc_oh = jax.nn.one_hot(loc, capacity, dtype=jnp.float32)
-        sel = keep[..., None] * loc_oh                     # [T_g, E, C]
-        dispatch = dispatch + sel.astype(cfg.dtype)
-        combine = combine + gate_vals[:, slot, None, None] * sel
-
-    expert_in = jnp.einsum("tec,td->ecd", dispatch,
-                           xt.astype(cfg.dtype))           # [E, c, D]
-    if n_ep > 1:
-        # Ship each expert's slots to its owner: [E, c, D] → split experts
-        # into n_ep groups, concat received slot-chunks → [E/n_ep, n_ep·c, D].
-        expert_in = jax.lax.all_to_all(expert_in, EP_AXIS, split_axis=0,
-                                       concat_axis=1, tiled=True)
-    h = nn.silu(jnp.einsum("ecd,edf->ecf", expert_in, w_gate)) \
-        * jnp.einsum("ecd,edf->ecf", expert_in, w_up)
-    expert_out = jnp.einsum("ecf,efd->ecd", h, w_down)
-    if n_ep > 1:
-        # Ship results back slot-major: [E/n_ep, n_ep·c, D] → [E, c, D].
-        expert_out = jax.lax.all_to_all(expert_out, EP_AXIS, split_axis=1,
-                                        concat_axis=0, tiled=True)
-    return jnp.einsum("tec,ecd->td", combine.astype(cfg.dtype), expert_out)
+    @pl.when(pl.program_id(1) < n_active[0])
+    def _compute():
+        dims = (((1,), (1 if transpose_rhs else 0,)), ((), ()))
+        if scales:
+            rows_scale, cols_scale = scales     # [tile, 1], [1, tn]
+            out = jax.lax.dot_general(
+                lhs_ref[...], rhs_ref[...], dims,
+                preferred_element_type=jnp.int32).astype(jnp.float32) \
+                * rows_scale[...] * cols_scale[...]
+        else:
+            out = jax.lax.dot_general(
+                lhs_ref[...], rhs_ref[...], dims,
+                preferred_element_type=jnp.float32,
+                precision=_prec(lhs_ref))
+        out_ref[...] = out.astype(out_ref.dtype)
 
 
-EP_AXIS = "ep"
+def _tgmm_kernel(tile_expert, n_active, lhs_ref, rhs_ref, out_ref):
+    i = pl.program_id(1)
+    # Tiles of one expert are consecutive and every expert has at least
+    # one, so its [K, tn] block stays in VMEM from its first tile to its
+    # last and each block is zeroed exactly once.
+    new_expert = jnp.logical_or(
+        i == 0, tile_expert[i] != tile_expert[jnp.maximum(i - 1, 0)])
+
+    @pl.when(i < n_active[0])
+    def _compute():
+        part = jax.lax.dot_general(
+            lhs_ref[...], rhs_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=_prec(lhs_ref))
+
+        @pl.when(new_expert)
+        def _first():
+            out_ref[...] = part
+
+        @pl.when(jnp.logical_not(new_expert))
+        def _more():
+            out_ref[...] += part
 
 
-class MoEMLP(nn.Module):
-    """Top-k routed expert FFN (gated-silu experts, like the dense MLP)."""
+def _col_tile(n: int, want: int = 1024) -> int:
+    """Largest multiple of 128 that divides ``n`` and is at most ``want``;
+    ``n`` itself where it has no such divisor (tiny test widths)."""
+    for t in range(min(n, want) // 128 * 128, 0, -128):
+        if n % t == 0:
+            return t
+    return n
 
-    cfg: MoEConfig
+
+def _live_row(i, n_active):
+    """Row tiles past ``n_active`` clamp to the last live one, so they
+    fetch nothing and their (skipped) steps leave its finished block in
+    place."""
+    return jnp.minimum(i, n_active[0] - 1)
+
+
+def _params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+def _gmm_call(lhs, rhs, tile_expert, n_active, *, tile_rows: int,
+              transpose_rhs: bool = False, scales=None, out_dtype=None):
+    """``out[tile i] = lhs[tile i] @ rhs[tile_expert[i]]`` (``rhs[e]ᵀ`` with
+    ``transpose_rhs``) for the first ``n_active`` row tiles; the rows of the
+    others are left unwritten and nothing reads them. int8 operands come
+    with ``scales = (a row's [M, 1], an expert's output channel's [count, 1,
+    N])``, applied to the int32 product, and state their ``out_dtype``."""
+    m, k = lhs.shape
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    tn = _col_tile(n)
+    tiles = m // tile_rows
+
+    row = _live_row         # grid (column tile, row tile)
+    rhs_block = (None, tn, k) if transpose_rhs else (None, k, tn)
+    in_specs = [
+        pl.BlockSpec((tile_rows, k), lambda j, i, te, na: (row(i, na), 0)),
+        pl.BlockSpec(rhs_block,
+                     (lambda j, i, te, na: (te[row(i, na)], j, 0))
+                     if transpose_rhs else
+                     (lambda j, i, te, na: (te[row(i, na)], 0, j))),
+    ]
+    if scales is not None:
+        in_specs += [
+            pl.BlockSpec((tile_rows, 1),
+                         lambda j, i, te, na: (row(i, na), 0)),
+            pl.BlockSpec((None, 1, tn),
+                         lambda j, i, te, na: (te[row(i, na)], 0, j)),
+        ]
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n // tn, tiles),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((tile_rows, tn),
+                                   lambda j, i, te, na: (row(i, na), j)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype or lhs.dtype),
+        compiler_params=_params(),
+        interpret=_interpret(),
+        name="moe_gmm",
+    )(tile_expert, n_active, lhs, rhs, *(scales or ()))
+
+
+def _tgmm_call(lhs, rhs, tile_expert, n_active, *, tile_rows: int,
+               count: int):
+    """``out[e] = Σ_{tiles i of expert e} lhs[tile i]ᵀ @ rhs[tile i]`` in
+    float32, ``[count, K, N]``. Rows past an expert's own within its last
+    tile must be zero on one side (they are: a padded row's cotangent is
+    scaled by its zero weight)."""
+    m, k = lhs.shape
+    n = rhs.shape[1]
+    tn = _col_tile(n, 512)
+    tiles = m // tile_rows
+    row = _live_row
+
+    return pl.pallas_call(
+        _tgmm_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n // tn, tiles),
+            in_specs=[
+                pl.BlockSpec((tile_rows, k),
+                             lambda j, i, te, na: (row(i, na), 0)),
+                pl.BlockSpec((tile_rows, tn),
+                             lambda j, i, te, na: (row(i, na), j)),
+            ],
+            out_specs=pl.BlockSpec(
+                (None, k, tn), lambda j, i, te, na: (te[row(i, na)], 0, j)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((count, k, n), jnp.float32),
+        compiler_params=_params(),
+        interpret=_interpret(),
+        name="moe_tgmm",
+    )(tile_expert, n_active, lhs, rhs)
+
+
+def _grouped_forward(lhs, w_lo, w_q, tile_expert, n_active, tile_rows):
+    if w_q is None:
+        return _gmm_call(lhs, w_lo, tile_expert, n_active,
+                         tile_rows=tile_rows)
+    q_lhs, rows_scale = quantize_symmetric(lhs, INT8, axis=-1)
+    return _gmm_call(q_lhs, w_q[0], tile_expert, n_active,
+                     tile_rows=tile_rows, scales=(rows_scale, w_q[1]),
+                     out_dtype=lhs.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def grouped_matmul(lhs, w, w_lo, w_q, tile_expert, n_active, tile_rows):
+    """Rows ``lhs [M, K]`` times their experts' matrices ``[count, K, N]``.
+    ``w`` is the parameter, which takes the gradient (float32, summed over
+    chunks unrounded); ``w_lo`` its cast to the matmul dtype, made once a
+    layer outside the chunk loop, which the kernels read. ``w_q`` is None,
+    or ``w_lo`` in int8 with its scales (``quantize_symmetric`` over the
+    contraction): the forward product is then int8 by int8, a row's scale
+    taken here, and the gradients stay those of the unquantized product
+    (straight through, as ``ops/quant.py`` has it for a dense layer)."""
+    del w
+    return _grouped_forward(lhs, w_lo, w_q, tile_expert, n_active, tile_rows)
+
+
+def _grouped_matmul_fwd(lhs, w, w_lo, w_q, tile_expert, n_active, tile_rows):
+    del w
+    out = _grouped_forward(lhs, w_lo, w_q, tile_expert, n_active, tile_rows)
+    return out, (lhs, w_lo, tile_expert, n_active)
+
+
+def _grouped_matmul_bwd(tile_rows, res, dout):
+    lhs, w_lo, tile_expert, n_active = res
+    dlhs = _gmm_call(dout, w_lo, tile_expert, n_active, tile_rows=tile_rows,
+                     transpose_rhs=True)
+    dw = _tgmm_call(lhs, dout, tile_expert, n_active, tile_rows=tile_rows,
+                    count=w_lo.shape[0])
+    return dlhs, dw, None, None, None, None
+
+
+grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+# ---------------------------------------------------------------------------
+# The layout: (token, choice) pairs, expert by expert, padded to the tile
+# ---------------------------------------------------------------------------
+def _layout(idx, first, count: int, tile_rows: int, rows: int):
+    """Where each (token, choice) pair of ``idx [T, k]`` (expert ids) goes in
+    a buffer of ``rows`` rows that holds experts ``[first, first + count)``
+    one after another, each padded to whole tiles (at least one, so that the
+    weight gradient writes every expert's block).
+
+    Returns ``held [T, k]`` (the pair's expert lives here), ``pos [T, k]``
+    (its row; ``rows`` where not held), ``row_pair [rows]`` (the row's pair
+    ``t·k + c``; any pair on a padding row), ``row_live [rows]``,
+    ``tile_expert [rows / tile_rows]``, ``n_active [1]`` (tiles in use) and
+    ``sizes [count]`` (rows an expert)."""
+    t, k = idx.shape
+    local = idx - first
+    held = (local >= 0) & (local < count)
+    flat = jnp.where(held, local, count).reshape(-1)        # sentinel last
+    onehot = (flat[:, None] == jnp.arange(count, dtype=flat.dtype)[None, :]
+              ).astype(jnp.int32)                           # [T·k, count]
+    sizes = jnp.sum(onehot, axis=0)
+    rank = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot, axis=1)
+    padded = jnp.maximum(-(-sizes // tile_rows), 1) * tile_rows
+    ends = jnp.cumsum(padded)
+    starts = ends - padded
+    pos = jnp.where(held.reshape(-1),
+                    starts[jnp.minimum(flat, count - 1)] + rank, rows)
+    # Rows to pairs: a stable sort by expert lists an expert's pairs in
+    # token order, which is the order ``rank`` counted them in.
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    tiles = rows // tile_rows
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(ends, jnp.arange(tiles) * tile_rows, side="right"),
+        count - 1).astype(jnp.int32)
+    row = jnp.arange(rows, dtype=jnp.int32)
+    row_expert = jnp.repeat(tile_expert, tile_rows)
+    offset = row - starts[row_expert]
+    row_live = (offset < sizes[row_expert]) & (row < ends[-1])
+    sorted_starts = jnp.cumsum(sizes) - sizes
+    row_pair = order[jnp.clip(sorted_starts[row_expert] + offset, 0,
+                              t * k - 1)]
+    n_active = (ends[-1:] // tile_rows).astype(jnp.int32)
+    return (held, pos.reshape(t, k).astype(jnp.int32), row_pair, row_live,
+            tile_expert, n_active, sizes)
+
+
+def _gather_sum(src, pos, scale):
+    """``out[t] = Σ_c scale[t, c] · src[pos[t, c]]`` in float32, one choice
+    at a time (a [T, k, D] gather is never held); ``scale`` is zero where the
+    pair is not held, and such a ``pos`` is clamped, never read for its
+    value."""
+    out = None
+    safe = jnp.minimum(pos, src.shape[0] - 1)
+    for c in range(pos.shape[1]):
+        live = scale[:, c, None] != 0
+        part = jnp.where(live, src[safe[:, c]].astype(jnp.float32), 0.0) \
+            * scale[:, c, None]
+        out = part if out is None else out + part
+    return out
+
+
+@jax.custom_vjp
+def _dispatch(x, row_token, pos, held):
+    """Rows for the experts: ``xs[r] = x[row_token[r]]``. Its transpose is a
+    gather too: a token's gradient is the sum over its held pairs' rows."""
+    del pos, held
+    return x[row_token]
+
+
+def _dispatch_fwd(x, row_token, pos, held):
+    return x[row_token], (pos, held)
+
+
+def _dispatch_bwd(res, dxs):
+    pos, held = res
+    dx = _gather_sum(dxs, pos, held.astype(jnp.float32)).astype(dxs.dtype)
+    return dx, None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+def _combined(y, weights, pos, held):
+    return _gather_sum(y, pos, jnp.where(held, weights, 0.0)).astype(y.dtype)
+
+
+@jax.custom_vjp
+def _combine(y, weights, pos, held, row_pair, row_live):
+    """``out[t] = Σ_c weights[t, c] · y[pos[t, c]]`` over the held pairs."""
+    del row_pair, row_live
+    return _combined(y, weights, pos, held)
+
+
+def _combine_fwd(y, weights, pos, held, row_pair, row_live):
+    return (_combined(y, weights, pos, held),
+            (y, weights, pos, held, row_pair, row_live))
+
+
+def _combine_bwd(res, dout):
+    y, weights, pos, held, row_pair, row_live = res
+    k = weights.shape[1]
+    # A padding row's cotangent is exactly zero: the weight gradient's
+    # kernel counts on it.
+    row_weight = jnp.where(row_live, weights.reshape(-1)[row_pair], 0.0)
+    dy = (dout[row_pair // k].astype(jnp.float32)
+          * row_weight[:, None]).astype(y.dtype)
+    safe = jnp.minimum(pos, y.shape[0] - 1)
+    dweights = jnp.stack([
+        jnp.sum(dout.astype(jnp.float32) * y[safe[:, c]].astype(jnp.float32),
+                axis=-1) for c in range(k)], axis=1)
+    dweights = jnp.where(held, dweights, 0.0).astype(weights.dtype)
+    return dy, dweights, None, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _buffer_rows(tokens: int, spec: ExpertSpec, count: int) -> int:
+    """Rows a chunk's buffer needs: every pair that could meet one of the
+    ``count`` experts here (a token's choices are distinct, so at most
+    ``min(top_k, count)`` of them), and one tile of padding an expert."""
+    tile = spec.tile_rows
+    most = tokens * min(spec.top_k, count)
+    return (-(-most // tile) + count) * tile
+
+
+def routed_experts(spec: ExpertSpec, x, idx, weights, w_gate, w_up, w_down,
+                   first, dtype, int8: bool = False):
+    """What the experts ``[first, first + count)`` (``count`` from the
+    weights' leading dim) add for tokens ``x [T, D]`` whose choices are
+    ``idx [T, k]`` with ``weights [T, k]``: ``[T, D]`` in ``dtype``, the
+    three forward products in int8 where ``int8`` says so."""
+    t, d = x.shape
+    count = w_gate.shape[0]
+    act = ACTIVATIONS[spec.activation]
+    lo = [w.astype(dtype) for w in (w_gate, w_up, w_down)]
+    q = [quantize_symmetric(w, INT8, axis=1) if int8 else None for w in lo]
+    chunk = spec.chunk_tokens if t % spec.chunk_tokens == 0 else t
+    rows = _buffer_rows(chunk, spec, count)
+
+    # A chunk keeps nothing for its backward but its inputs: what it kept
+    # would be stacked over the chunks, which is the buffer chunks avoid.
+    @jax.checkpoint
+    def one_chunk(args):
+        xc, ic, wc = args
+        with jax.named_scope("tony.moe.dispatch"):
+            held, pos, row_pair, row_live, tile_expert, n_active, _ = \
+                _layout(ic, first, count, spec.tile_rows, rows)
+            xs = _dispatch(xc, row_pair // spec.top_k, pos, held)
+        with jax.named_scope("tony.moe.experts"):
+            gmm = functools.partial(grouped_matmul, tile_expert=tile_expert,
+                                    n_active=n_active,
+                                    tile_rows=spec.tile_rows)
+            hidden = act(gmm(xs, w_gate, lo[0], q[0])) \
+                * gmm(xs, w_up, lo[1], q[1])
+            y = gmm(hidden, w_down, lo[2], q[2])
+        with jax.named_scope("tony.moe.combine"):
+            return _combine(y, wc, pos, held, row_pair, row_live)
+
+    if chunk == t:
+        return one_chunk((x.astype(dtype), idx, weights))
+    parts = jax.lax.map(one_chunk, (
+        x.astype(dtype).reshape(t // chunk, chunk, d),
+        idx.reshape(t // chunk, chunk, -1),
+        weights.reshape(t // chunk, chunk, -1)))
+    return parts.reshape(t, d)
+
+
+def routing_counters(idx, first, count) -> dict:
+    """A step's routing, for experts ``[first, first + count)``: the rows
+    (token, choice) that met one of them, the share of tokens none of whose
+    choices did, and the fullest expert's rows over the mean."""
+    held = (idx >= first) & (idx < first + count)
+    load = jnp.sum((idx.reshape(-1, 1) - first
+                    == jnp.arange(count)[None, :]).astype(jnp.float32),
+                   axis=0)
+    return {
+        "moe_rows_routed": jnp.sum(held.astype(jnp.float32)),
+        "moe_rows_unrouted_share":
+            1.0 - jnp.mean(jnp.any(held, axis=-1).astype(jnp.float32)),
+        "moe_expert_load_max_over_mean":
+            jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9),
+    }
+
+
+def moe_counters(intermediates) -> dict:
+    """The layers' sown counters as one dict of scalars for a step's aux
+    metrics: rows summed over layers, the shares their mean, the load its
+    worst layer. {} for a model without experts."""
+    found: dict = {}
+    for path, value in jax.tree_util.tree_leaves_with_path(intermediates):
+        name = next((str(getattr(k, "key", "")) for k in reversed(path)
+                     if str(getattr(k, "key", "")).startswith("moe_")), None)
+        if name:
+            found.setdefault(name, []).append(value)
+    reduce = {"moe_rows_routed": jnp.sum,
+              "moe_rows_unrouted_share": jnp.mean,
+              "moe_expert_load_max_over_mean": jnp.max}
+    return {name: reduce[name](jnp.stack(values))
+            for name, values in found.items()}
+
+
+class ExpertLayer(nn.Module):
+    """Top-k routed gated experts. ``router_in`` is what the router reads
+    (the block's normed pre-attention input, or the same tensor as ``x`` for
+    a router after attention); ``x`` is what the experts read."""
+
+    spec: ExpertSpec
+    dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32
+    # ``TransformerConfig.matmul_dtype`` (the ``tony.train.matmul-dtype``
+    # knob): "int8" quantizes the experts' three forward products as
+    # ``ops/quant.py`` does a dense projection's. They are most of a sparse
+    # model's products, so a knob that stopped at the attention projections
+    # would do next to nothing here and not say so
+    # (``tests/test_quant.py``: the loss-parity gate with experts).
+    matmul_dtype: str = ""
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
-        cfg = self.cfg
+    def __call__(self, router_in: jax.Array, x: jax.Array) -> jax.Array:
+        spec = self.spec
         b, s, d = x.shape
         t = b * s
-        e = cfg.n_experts
+        first, count = spec.held or (0, spec.n_experts)
+        mode = resolve_mode(self.matmul_dtype)
+        if mode not in (None, INT8):
+            raise ValueError(f"the grouped matmuls have an int8 path alone, "
+                             f"not {mode!r}")
+        routed = functools.partial(routed_experts, int8=mode == INT8)
 
-        xt = x.reshape(t, d)
-        # Router in f32: stability matters more than speed for a [d, E] dot.
-        router = nn.Dense(
-            e, use_bias=False, dtype=jnp.float32,
-            param_dtype=cfg.param_dtype, name="router",
-            kernel_init=nn.with_logical_partitioning(
-                nn.initializers.lecun_normal(), ("embed", "expert_logits")))
-        probs = jax.nn.softmax(router(xt.astype(jnp.float32)), axis=-1)
+        router = self.param(
+            "router", nn.with_logical_partitioning(
+                nn.initializers.lecun_normal(), ("embed", "expert_logits")),
+            (d, spec.n_experts), self.param_dtype)
 
         def w(name, shape, axes):
             return self.param(name, nn.with_logical_partitioning(
-                nn.initializers.lecun_normal(), axes), shape,
-                cfg.param_dtype).astype(cfg.dtype)
+                nn.initializers.lecun_normal(batch_axis=(0,)), axes), shape,
+                self.param_dtype)
 
-        w_gate = w("gate", (e, d, cfg.mlp_dim), ("expert", "embed", "mlp"))
-        w_up = w("up", (e, d, cfg.mlp_dim), ("expert", "embed", "mlp"))
-        w_down = w("down", (e, cfg.mlp_dim, d), ("expert", "mlp", "embed"))
+        w_gate = w("gate", (count, d, spec.width), ("expert", "embed", "mlp"))
+        w_up = w("up", (count, d, spec.width), ("expert", "embed", "mlp"))
+        w_down = w("down", (count, spec.width, d), ("expert", "mlp", "embed"))
 
-        n_ep = compat.mesh_axis_size(EP_AXIS)
-        if n_ep > 1:
-            from jax.sharding import PartitionSpec as P
+        with jax.named_scope("tony.moe.route"):
+            # float32 at highest precision: the one matmul whose rounding
+            # can move a discrete choice.
+            logits = jnp.dot(router_in.reshape(t, d).astype(jnp.float32),
+                             router.astype(jnp.float32),
+                             precision=jax.lax.Precision.HIGHEST)
+            top, idx = jax.lax.top_k(logits, spec.top_k)
+            weights = jax.nn.softmax(top, axis=-1)
+            for name, value in routing_counters(idx, first, count).items():
+                self.sow("intermediates", name, value)
 
-            if t % n_ep or e % n_ep:
-                raise ValueError(
-                    f"tokens ({t}) and experts ({e}) must divide the ep "
-                    f"axis ({n_ep})")
-            out = compat.partial_shard_map(
-                functools.partial(_routed_ffn_group, cfg, n_ep=n_ep),
-                EP_AXIS,
-                in_specs=(P(EP_AXIS), P(EP_AXIS), P(EP_AXIS), P(EP_AXIS),
-                          P(EP_AXIS)),
-                out_specs=P(EP_AXIS),
-            )(xt, probs, w_gate, w_up, w_down)
-        else:
-            out = _routed_ffn_group(cfg, xt, probs, w_gate, w_up, w_down,
-                                    n_ep=1)
-        out = out.reshape(b, s, d)
+        xt = x.reshape(t, d)
+        # Mosaic kernels cannot be partitioned automatically: under a bound
+        # mesh the body runs in a shard_map manual over every axis that is
+        # not manual already (size-1 axes included, as compat.per_shard).
+        mesh = compat.current_mesh()
+        auto = () if mesh is None else tuple(
+            a for a in mesh.axis_names if a not in mesh.manual_axes)
+        if not auto:
+            return routed(spec, xt, idx, weights, w_gate, w_up, w_down,
+                          first, self.dtype).reshape(b, s, d)
+        n_ep = mesh.shape[EP_AXIS] if EP_AXIS in auto else 1
+        rows = tuple(a for a in BATCH_AXES if a in auto)
+        n_rows = math.prod(mesh.shape[a] for a in rows)
+        if t % n_rows:      # computed whole, and alike, on every device
+            rows, n_rows = (), 1
+        if n_ep > 1 and (spec.held is not None or count % n_ep
+                         or (t // n_rows) % n_ep):
+            raise ValueError(
+                f"an ep axis of {n_ep} shares out all {count} experts "
+                f"(held={spec.held}) and scatters {t // n_rows} tokens: "
+                f"both must divide, and no share may be named")
 
-        # Switch aux loss: E · Σ_e (token fraction to e) · (mean router prob).
-        gate_idx = jnp.argmax(probs, axis=-1)
-        token_frac = jnp.mean(
-            jax.nn.one_hot(gate_idx, e, dtype=jnp.float32), axis=0)
-        prob_frac = jnp.mean(probs, axis=0)
-        aux = e * jnp.sum(token_frac * prob_frac)
-        return out, aux
+        def shard(xt, idx, weights, w_gate, w_up, w_down):
+            if n_ep == 1:
+                return routed(spec, xt, idx, weights, w_gate, w_up, w_down,
+                              first, self.dtype)
+            # Every shard has all of its group's rows (they are not split
+            # over ep) and selects its own experts' pairs; the shares add
+            # up, and each shard keeps a slice of the sum.
+            mine = jax.lax.axis_index(EP_AXIS) * (count // n_ep)
+            part = routed(spec, xt, idx, weights, w_gate, w_up, w_down,
+                          mine, self.dtype)
+            return jax.lax.psum_scatter(part, EP_AXIS, scatter_dimension=0,
+                                        tiled=True)
 
-
-class MoEBlock(nn.Module):
-    cfg: MoEConfig
-
-    @nn.compact
-    def __call__(self, x, positions):
-        cfg = self.cfg
-        h = x + Attention(cfg, name="attn")(
-            RMSNorm(cfg.norm_eps, cfg.param_dtype, name="attn_norm")(x),
-            positions)
-        mlp_out, aux = MoEMLP(cfg, name="moe")(
-            RMSNorm(cfg.norm_eps, cfg.param_dtype, name="mlp_norm")(h))
-        out = h + mlp_out
-        return nn.with_logical_constraint(out, ("batch", "seq", "embed")), aux
-
-
-class MoETransformer(nn.Module):
-    """Causal LM with routed-expert FFNs: tokens → (logits, aux_loss)."""
-
-    cfg: MoEConfig
-
-    @nn.compact
-    def __call__(self, tokens, positions=None):
-        cfg = self.cfg
-        if positions is None:
-            pos = jnp.arange(tokens.shape[1], dtype=jnp.int32)
-            positions = jnp.broadcast_to(pos[None, :], tokens.shape)
-        emb = self.param(
-            "embedding", nn.with_logical_partitioning(
-                nn.initializers.normal(0.02), ("vocab", "embed")),
-            (cfg.vocab_size, cfg.dim), cfg.param_dtype)
-        x = emb[tokens].astype(cfg.dtype)
-        x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
-        block = MoEBlock
-        if cfg.remat:
-            # prevent_cse=True: layers are a Python loop, and with False
-            # XLA CSEs the recomputation away and silently un-remats the
-            # model (same defect found and measured in
-            # models/transformer.py; False is only sound inside
-            # scan/while bodies — see parallel/pipeline.py for the
-            # legitimate case). The policy is the dense transformer's.
-            block = nn.remat(MoEBlock, prevent_cse=True,
-                             policy=remat_policy_of(cfg))
-        aux_total = jnp.zeros((), jnp.float32)
-        for i in range(cfg.n_layers):
-            x, aux = block(cfg, name=f"layer_{i}")(x, positions)
-            aux_total = aux_total + aux
-        x = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")(x)
-        logits = nn.Dense(
-            cfg.vocab_size, use_bias=False, dtype=jnp.float32,
-            param_dtype=cfg.param_dtype, name="lm_head",
-            kernel_init=nn.with_logical_partitioning(
-                nn.initializers.lecun_normal(), ("embed", "vocab")))(
-                    x.astype(jnp.float32))
-        return logits, aux_total / cfg.n_layers
-
-
-def moe_lm_loss(model_out, tokens, aux_weight: float) -> jax.Array:
-    from tony_tpu.models.transformer import causal_lm_loss
-
-    logits, aux = model_out
-    return causal_lm_loss(logits, tokens) + aux_weight * aux
+        tok = P(rows or None)
+        held_w = P(EP_AXIS) if n_ep > 1 else P()
+        out = jax.shard_map(
+            shard, axis_names=set(auto),
+            in_specs=(tok, tok, tok, held_w, held_w, held_w),
+            out_specs=P(rows + (EP_AXIS,)) if n_ep > 1 else tok,
+            check_vma=False)(xt, idx, weights, w_gate, w_up, w_down)
+        return out.reshape(b, s, d)
 
 
 def dryrun_ep_step(devices, ep: int) -> float:
-    """One FULL MoE train step (fwd + bwd + optimizer update) on an ep≥2
-    mesh, asserting the compiled program dispatches experts via all_to_all.
-    Used by ``__graft_entry__.dryrun_multichip``; returns the loss."""
+    """One FULL expert-layer train step (fwd + bwd + optimizer update) on an
+    ep≥2 mesh, asserting the compiled program sums the shards' shares with a
+    reduce-scatter over ``ep``. Used by ``__graft_entry__.dryrun_multichip``;
+    returns the loss."""
     import optax
 
+    from tony_tpu.models.transformer import Transformer, causal_lm_loss
     from tony_tpu.parallel import MeshSpec, build_mesh, init_sharded_state
     from tony_tpu.parallel.sharding import DEFAULT_RULES
 
     n = len(devices)
     mesh = build_mesh(MeshSpec(dp=n // ep, ep=ep), devices=devices)
     cfg = MoEConfig.tiny_moe()
-    model = MoETransformer(cfg)
+    model = Transformer(cfg)
     tokens = jax.random.randint(jax.random.key(0), (2 * (n // ep), 32), 0,
                                 cfg.vocab_size)
     state, _sh = init_sharded_state(model, tokens, optax.adam(1e-3), mesh)
 
     def loss_fn(p):
         with nn.logical_axis_rules(list(DEFAULT_RULES)):
-            return moe_lm_loss(model.apply({"params": p}, tokens), tokens,
-                               cfg.aux_loss_weight)
+            return causal_lm_loss(model.apply({"params": p}, tokens), tokens)
 
     def step(state):
         loss, grads = jax.value_and_grad(loss_fn)(state.params)
         return state.apply_gradients(grads), loss
 
-    # set_mesh binds the abstract mesh MoEMLP reads to pick the ep path;
-    # without it n_ep resolves to 1 and the dry run would only validate the
-    # replicated fallback (advisor finding, round 2).
+    # set_mesh binds the abstract mesh ExpertLayer reads to pick the ep
+    # path; without it n_ep resolves to 1 and the dry run would only
+    # validate the one-device body.
     with jax.set_mesh(mesh):
         compiled = jax.jit(step).lower(state).compile()
         hlo = compiled.as_text()
-        assert "all-to-all" in hlo, \
-            "ep dryrun compiled WITHOUT all_to_all expert dispatch"
+        assert "reduce-scatter" in hlo or "all-reduce" in hlo, \
+            "ep dryrun compiled WITHOUT a sum of the shards' shares"
         state, loss = compiled(state)
     loss = float(loss)
     assert jnp.isfinite(loss), f"ep MoE train step diverged: {loss}"

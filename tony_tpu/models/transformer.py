@@ -10,23 +10,39 @@ onto TPU hardware:
 - attention is pluggable: Pallas flash kernel (default), ring attention for
   sequence-parallel long context, Ulysses, or the XLA reference;
 - static shapes and `remat`-friendly block structure (scan over layers is
-  deliberately NOT used so pipeline stages can slice layers later).
+  deliberately NOT used so pipeline stages can slice layers later);
+- one loop over layers that may differ: ``TransformerConfig.layers`` says
+  of each its attention mask (full causal or a window), RoPE on or off,
+  and its feed-forward (the dense ``MLP`` or sparse experts,
+  ``models/moe.py``). Without it every layer is the dense default.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from tony_tpu.models.moe import ExpertLayer, ExpertSpec
 from tony_tpu.ops.attention import (FLASH_RESIDUAL_NAMES, flash_attention,
                                     reference_attention)
 from tony_tpu.ops.quant import QDense
 from tony_tpu.ops.ring import ring_attention
 from tony_tpu.ops.ulysses import ulysses_attention
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """One layer of the stack. ``window=w``: query i attends keys
+    i − w < j ≤ i (None: the full causal triangle). ``rope=False``: no
+    position embedding on this layer's q and k. ``experts``: the sparse
+    feed-forward in place of the dense ``MLP`` (None: dense)."""
+    window: Optional[int] = None
+    rope: bool = True
+    experts: Optional[ExpertSpec] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -78,6 +94,24 @@ class TransformerConfig:
     # identical to the pre-quantization model. An unsupported backend
     # degrades to bf16 with a one-time beacon warning.
     matmul_dtype: Optional[str] = None
+    # A head's width where the model states one (q width n_heads·head_dim
+    # need not equal dim); None derives dim // n_heads.
+    head_dim: Optional[int] = None
+    # One LayerSpec a layer, read by Transformer's one loop; None makes
+    # every layer the default (full causal, RoPE, dense MLP).
+    layers: Optional[Tuple[LayerSpec, ...]] = None
+
+    def __post_init__(self):
+        if self.layers is not None and len(self.layers) != self.n_layers:
+            raise ValueError(f"{len(self.layers)} layer specs for "
+                             f"n_layers={self.n_layers}")
+
+    @property
+    def head_size(self) -> int:
+        return self.head_dim or self.dim // self.n_heads
+
+    def layer(self, i: int) -> LayerSpec:
+        return self.layers[i] if self.layers is not None else LayerSpec()
 
     @classmethod
     def llama3_8b(cls, **kw) -> "TransformerConfig":
@@ -167,11 +201,12 @@ class RMSNorm(nn.Module):
 
 class Attention(nn.Module):
     cfg: TransformerConfig
+    spec: LayerSpec = LayerSpec()
 
     @nn.compact
     def __call__(self, x, positions):
-        cfg = self.cfg
-        head_dim = cfg.dim // cfg.n_heads
+        cfg, spec = self.cfg, self.spec
+        head_dim = cfg.head_size
         b, s, _ = x.shape
         # Plain Dense with a fused (heads·head_dim) output: the fused dim is
         # heads-major, so sharding it over tp == sharding heads over tp.
@@ -183,8 +218,9 @@ class Attention(nn.Module):
                    "wk")(x).reshape(b, s, cfg.n_kv_heads, head_dim)
         v = _dense(cfg, cfg.n_kv_heads * head_dim, ("embed", "kv_heads"),
                    "wv")(x).reshape(b, s, cfg.n_kv_heads, head_dim)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        if spec.rope:
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
         q = nn.with_logical_constraint(q, ("batch", "seq", "heads", "kv"))
         k = nn.with_logical_constraint(k, ("batch", "seq", "kv_heads", "kv"))
         v = nn.with_logical_constraint(v, ("batch", "seq", "kv_heads", "kv"))
@@ -192,20 +228,25 @@ class Attention(nn.Module):
         if cfg.attn_impl == "flash":
             o = flash_attention(q, k, v, causal=True,
                                 block_q=cfg.attn_block_q,
-                                block_k=cfg.attn_block_k)
+                                block_k=cfg.attn_block_k,
+                                window=spec.window)
         elif cfg.attn_impl == "xla":
             g = cfg.n_heads // cfg.n_kv_heads
             o = reference_attention(q, jnp.repeat(k, g, axis=2),
-                                    jnp.repeat(v, g, axis=2), causal=True)
+                                    jnp.repeat(v, g, axis=2), causal=True,
+                                    window=spec.window)
         elif cfg.attn_impl == "ring":
             # GQA-native: K/V ride the ring at kv-head width (no repeat).
+            # Ring and Ulysses refuse a window.
             o = ring_attention(q, k, v, axis_name="sp", causal=True,
                                block_q=cfg.attn_block_q,
-                               block_k=cfg.attn_block_k)
+                               block_k=cfg.attn_block_k,
+                               window=spec.window)
         elif cfg.attn_impl == "ulysses":
             o = ulysses_attention(q, k, v, axis_name="sp", causal=True,
                                   block_q=cfg.attn_block_q,
-                                  block_k=cfg.attn_block_k)
+                                  block_k=cfg.attn_block_k,
+                                  window=spec.window)
         else:
             raise ValueError(f"unknown attn_impl {cfg.attn_impl!r}")
         o = nn.with_logical_constraint(o, ("batch", "seq", "heads", "kv"))
@@ -228,15 +269,22 @@ class MLP(nn.Module):
 
 class Block(nn.Module):
     cfg: TransformerConfig
+    spec: LayerSpec = LayerSpec()
 
     @nn.compact
     def __call__(self, x, positions):
-        cfg = self.cfg
-        h = x + Attention(cfg, name="attn")(
-            RMSNorm(cfg.norm_eps, cfg.param_dtype, name="attn_norm")(x),
-            positions)
-        out = h + MLP(cfg, name="mlp")(
-            RMSNorm(cfg.norm_eps, cfg.param_dtype, name="mlp_norm")(h))
+        cfg, spec = self.cfg, self.spec
+        n = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="attn_norm")(x)
+        h = x + Attention(cfg, spec, name="attn")(n, positions)
+        m = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="mlp_norm")(h)
+        if spec.experts is None:
+            out = h + MLP(cfg, name="mlp")(m)
+        else:
+            # The router may read what attention reads (the normed block
+            # input); the experts read the post-attention normed state.
+            out = h + ExpertLayer(spec.experts, cfg.dtype, cfg.param_dtype,
+                                  cfg.matmul_dtype or "", name="moe")(
+                n if spec.experts.route_before_attention else m, m)
         return nn.with_logical_constraint(out, ("batch", "seq", "embed"))
 
 
@@ -305,7 +353,7 @@ class Transformer(nn.Module):
             if (cfg.remat and cfg.remat_skip_every >= 2
                     and i % cfg.remat_skip_every == 0):
                 blk = Block     # selective: this layer's activations live
-            x = blk(cfg, name=f"layer_{i}")(x, positions)
+            x = blk(cfg, cfg.layer(i), name=f"layer_{i}")(x, positions)
         x = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")(x)
         if return_hidden:
             return x

@@ -105,20 +105,41 @@ def _store_stat(ref, col):
     ref[0, 0] = jnp.broadcast_to(jnp.transpose(col), ref.shape[2:])
 
 
-def _last_valid_kj(i, block_q, block_k):
-    """Last k-block index with any unmasked causal element for q-tile
-    ``i``. Single source of truth for BOTH the kernels' compute guards and
-    the index-map DMA clamps — they must never disagree."""
-    return (i * block_q + block_q - 1) // block_k
+def _valid_kj(i, block_q, block_k, window=None):
+    """(first, last) k-block index with any unmasked element for q-tile
+    ``i``: causal, nothing after the tile's last row; under a window (query
+    r sees keys r − w < c ≤ r), nothing before its first row's first key
+    ``i·bq − w + 1``. Single source of truth for BOTH the kernels' compute
+    guards and the index-map DMA clamps — they must never disagree."""
+    last = (i * block_q + block_q - 1) // block_k
+    if window is None:
+        return 0, last
+    lo = i * block_q - window + 1
+    return (max(lo, 0) if isinstance(lo, int)
+            else jnp.maximum(lo, 0)) // block_k, last
 
 
-def _first_valid_qi(j, block_q, block_k):
-    """First q-block index with any unmasked causal element for k-tile
-    ``j`` (identity: ceil((j·bk − bq + 1)/bq) == floor(j·bk/bq))."""
-    return (j * block_k) // block_q
+def _valid_qi(j, block_q, block_k, window=None):
+    """(first, last) q-block index with any unmasked element for k-tile
+    ``j`` (first: ceil((j·bk − bq + 1)/bq) == floor(j·bk/bq)). ``last`` is
+    None without a window (every later q-tile sees the k-tile); under a
+    window the tile's last key is seen up to row ``(j+1)·bk − 2 + w``."""
+    first = (j * block_k) // block_q
+    if window is None:
+        return first, None
+    return first, ((j + 1) * block_k - 2 + window) // block_q
 
 
-def _mask_scores(s, qi, kj, block_q, block_k, causal, seq_q, seq_k):
+def _band_blocks(n_outer: int, n_inner: int, valid) -> int:
+    """Length of a windowed kernel's innermost grid axis: the most inner
+    tiles any outer tile has between its first and last valid one
+    (``valid(index) -> (first, last)`` on plain ints)."""
+    return max(min(last, n_inner - 1) - first + 1
+               for first, last in map(valid, range(n_outer)))
+
+
+def _mask_scores(s, qi, kj, block_q, block_k, causal, seq_q, seq_k,
+                 window=None):
     """Set invalid scores to NEG_INF so they vanish through exp().
 
     VPU passes over the [bq, bk] score tile are the flash bottleneck at
@@ -126,7 +147,11 @@ def _mask_scores(s, qi, kj, block_q, block_k, causal, seq_q, seq_k):
     from 1-D iotas ([bq,1] vs [1,bk] — register-cheap), and the
     sequence-edge guards (grid padding when seq % block != 0) are emitted
     only for ragged shapes: the production path (divisible seq) pays 2
-    passes for causal, 0 for non-causal.
+    passes for causal, 0 for non-causal. A window is one compare more from
+    the same iotas. (A row whose keys in this tile are all masked keeps
+    m = NEG_INF and adds exp(0) garbage; NEG_INF is finite, so the first
+    tile that holds one of its keys scales that garbage by exp(NEG_INF − m)
+    = 0, and under a causal mask every row has its own diagonal.)
 
     Returns (masked s, valid) — ``valid`` is None when only the causal
     compare ran (no padded rows/cols exist, so exp(masked) needs no extra
@@ -145,6 +170,8 @@ def _mask_scores(s, qi, kj, block_q, block_k, causal, seq_q, seq_k):
             valid = valid & (rows >= cols)
     elif causal:
         valid = rows >= cols
+    if window is not None:
+        valid = valid & (rows < cols + window)
     if valid is None:
         return s, None
     s = jnp.where(valid, s, NEG_INF)
@@ -153,8 +180,10 @@ def _mask_scores(s, qi, kj, block_q, block_k, causal, seq_q, seq_k):
 
 def reference_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                         causal: bool = True,
-                        scale: Optional[float] = None) -> jax.Array:
+                        scale: Optional[float] = None,
+                        window: Optional[int] = None) -> jax.Array:
     """Plain XLA attention ([B,S,H,D] layout) — the correctness oracle.
+    ``window=w`` (causal): query i sees keys i − w < j ≤ i.
     Einsums run at HIGHEST precision: on TPU the DEFAULT is bf16 multiplies,
     which would make the oracle less accurate than the kernel under test."""
     scale = scale if scale is not None else q.shape[-1] ** -0.5
@@ -163,6 +192,8 @@ def reference_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if causal:
         sq, sk = q.shape[1], k.shape[1]
         mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq - window)
         s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
@@ -175,16 +206,20 @@ def reference_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                 scale: float, causal: bool, block_q: int, block_k: int,
                 num_k_blocks: int, seq_q: int, seq_k: int,
-                fused_rowsum: bool):
+                fused_rowsum: bool, window: Optional[int] = None):
     if fused_rowsum:
         m_scr, acc_scr = scratch
         l_scr = None
     else:
         m_scr, l_scr, acc_scr = scratch
     qi = pl.program_id(2)
-    kj = pl.program_id(3)
+    j = pl.program_id(3)
+    # ``num_k_blocks`` is the grid's last axis: every k tile, or under a
+    # window the band's, counted from the q tile's first valid one.
+    first, last = _valid_kj(qi, block_q, block_k, window)
+    kj = j if window is None else first + j
 
-    @pl.when(kj == 0)
+    @pl.when(j == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         acc_scr[:] = jnp.zeros_like(acc_scr)
@@ -194,7 +229,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
     # Causal: skip fully-masked tiles (k strictly after the q tile's end).
     run = True
     if causal:
-        run = kj <= _last_valid_kj(qi, block_q, block_k)
+        run = kj <= last
 
     @pl.when(run)
     def _compute():
@@ -209,7 +244,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             preferred_element_type=jnp.float32,
             precision=_prec(q))                   # [block_q, block_k]
         s, _ = _mask_scores(s, qi, kj, block_q, block_k, causal, seq_q,
-                            seq_k)
+                            seq_k, window)
         # All row stats stay [block_q, 1] COLUMN vectors: reductions use
         # keepdims and the scratch is (block_q, 1), so no lane↔sublane
         # relayout ever happens on the hot path (1-D lane vectors with
@@ -237,7 +272,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                 precision=_prec(v))
         m_scr[:] = m_new
 
-    @pl.when(kj == num_k_blocks - 1)
+    @pl.when(j == num_k_blocks - 1)
     def _finalize():
         if fused_rowsum:
             acc = acc_scr[:]
@@ -252,13 +287,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
 # ---------------------------------------------------------------------------
 # Backward kernels (standard flash backward, two passes)
 # ---------------------------------------------------------------------------
-def _p_block(s, lse, qi, kj, block_q, block_k, causal, seq_q, seq_k):
+def _p_block(s, lse, qi, kj, block_q, block_k, causal, seq_q, seq_k,
+             window=None):
     """exp(s − lse) with NEG_INF masking (causal entries vanish through the
     exp). Ragged shapes additionally zero p explicitly: padded lse/do reads
     are undefined memory on TPU, so exp(s − lse) can't be trusted there —
     for divisible shapes that where() is statically elided."""
     sm, valid = _mask_scores(s, qi, kj, block_q, block_k, causal, seq_q,
-                             seq_k)
+                             seq_k, window)
     p = jnp.exp(sm - lse)                       # lse is [bq, 1]
     if valid is not None:
         p = jnp.where(valid, p, 0.0)
@@ -267,17 +303,20 @@ def _p_block(s, lse, qi, kj, block_q, block_k, causal, seq_q, seq_k):
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    acc_scr, *, scale: float, causal: bool, block_q: int,
-                   block_k: int, num_k_blocks: int, seq_q: int, seq_k: int):
+                   block_k: int, num_k_blocks: int, seq_q: int, seq_k: int,
+                   window: Optional[int] = None):
     qi = pl.program_id(2)
-    kj = pl.program_id(3)
+    j = pl.program_id(3)
+    first, last = _valid_kj(qi, block_q, block_k, window)
+    kj = j if window is None else first + j
 
-    @pl.when(kj == 0)
+    @pl.when(j == 0)
     def _init():
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     run = True
     if causal:
-        run = kj <= _last_valid_kj(qi, block_q, block_k)
+        run = kj <= last
 
     @pl.when(run)
     def _compute():
@@ -296,7 +335,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             preferred_element_type=jnp.float32,
             precision=_prec(q))
         p = _p_block(s, lse, qi, kj, block_q, block_k, causal, seq_q,
-                     seq_k)                                 # [bq, bk]
+                     seq_k, window)                         # [bq, bk]
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=_prec(v))
@@ -305,7 +344,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                                   preferred_element_type=jnp.float32,
                                   precision=_prec(k))
 
-    @pl.when(kj == num_k_blocks - 1)
+    @pl.when(j == num_k_blocks - 1)
     def _finalize():
         dq_ref[0, 0] = acc_scr[:].astype(dq_ref.dtype)
 
@@ -314,10 +353,16 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr, *, scale: float,
                     causal: bool, block_q: int, block_k: int,
                     num_q_blocks: int, num_inner: int, seq_q: int,
-                    seq_k: int):
+                    seq_k: int, window: Optional[int] = None,
+                    num_q_total: int = 0):
     kj = pl.program_id(2)
     t = pl.program_id(3)          # folds (group member, q block)
+    # ``num_q_blocks`` q tiles a group member: every one, or under a window
+    # the band's, counted from the k tile's first valid one.
+    first, last = _valid_qi(kj, block_q, block_k, window)
     qi = t % num_q_blocks
+    if window is not None:
+        qi = first + qi
 
     @pl.when(t == 0)
     def _init():
@@ -325,9 +370,12 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
     run = True
-    if causal:
+    if window is not None:
+        # q tiles wholly after the last row that sees the k tile's end.
+        run = qi <= jnp.minimum(last, num_q_total - 1)
+    elif causal:
         # q tiles strictly before the k tile's start contribute nothing.
-        run = qi >= _first_valid_qi(kj, block_q, block_k)
+        run = qi >= first
 
     @pl.when(run)
     def _compute():
@@ -345,7 +393,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32,
             precision=_prec(q))
         p = _p_block(s, lse, qi, kj, block_q, block_k, causal, seq_q,
-                     seq_k)                                 # [bq, bk]
+                     seq_k, window)                         # [bq, bk]
         dv_scr[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=_prec(do))
@@ -390,15 +438,23 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def _fwd_impl(q, k, v, scale, causal, block_q, block_k, out_dtype=None):
+def _fwd_impl(q, k, v, scale, causal, block_q, block_k, out_dtype=None,
+              window=None):
     return per_shard(
         functools.partial(_fwd_call, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
-                          out_dtype=out_dtype),
+                          out_dtype=out_dtype, window=window),
         _KERNEL_DIM_AXES)(q, k, v)
 
 
-def _fwd_call(q, k, v, *, scale, causal, block_q, block_k, out_dtype):
+def _kernel_name(kind: str, window) -> str:
+    """Windowed calls carry names of their own in the device trace, so that
+    a reader of the full kernels' names never counts them."""
+    return f"flash_{kind}" if window is None else f"flash_win_{kind}"
+
+
+def _fwd_call(q, k, v, *, scale, causal, block_q, block_k, out_dtype,
+              window=None):
     b, h, sq, d = q.shape
     hk = k.shape[1]
     g = h // hk
@@ -409,21 +465,21 @@ def _fwd_call(q, k, v, *, scale, causal, block_q, block_k, out_dtype):
     block_k = min(block_k, _round_up(sk, 16))
     nq = pl.cdiv(sq, block_q)
     nk = pl.cdiv(sk, block_k)
-
-    def kv_j(i, j):
-        # Clamp fully-masked causal tiles to the previous fetch so the
-        # pipeline skips the DMA (revisited blocks are not re-fetched).
-        return jnp.minimum(j, _last_valid_kj(i, block_q, block_k)) \
-            if causal else j
+    # Under a window the last grid axis walks the band alone: a skipped
+    # tile still costs a grid step, and most of a long row's are outside.
+    nkb = nk if window is None else _band_blocks(
+        nq, nk, lambda i: _valid_kj(i, block_q, block_k, window))
+    kv_j = functools.partial(_kv_index, block_q=block_q, block_k=block_k,
+                             causal=causal, window=window)
 
     fused_rowsum = d < 128
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, num_k_blocks=nk, seq_q=sq, seq_k=sk,
-        fused_rowsum=fused_rowsum)
+        block_k=block_k, num_k_blocks=nkb, seq_q=sq, seq_k=sk,
+        fused_rowsum=fused_rowsum, window=window)
     o, lse = pl.pallas_call(
         kernel,
-        grid=(b, h, nq, nk),
+        grid=(b, h, nq, nkb),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_k, d),
@@ -451,13 +507,24 @@ def _fwd_call(q, k, v, *, scale, causal, block_q, block_k, out_dtype):
              pltpu.VMEM((block_q, 1), jnp.float32),
              pltpu.VMEM((block_q, d), jnp.float32)]),
         interpret=_interpret(),
-        name="flash_fwd",
+        name=_kernel_name("fwd", window),
     )(q, k, v)
     return o, lse
 
 
+def _kv_index(i, j, *, block_q, block_k, causal, window):
+    """The k tile that grid step ``j`` of q tile ``i`` fetches. Fully-masked
+    causal tiles clamp to the previous fetch so the pipeline skips the DMA
+    (revisited blocks are not re-fetched); under a window ``j`` counts from
+    the q tile's first valid k tile."""
+    if not causal:
+        return j
+    first, last = _valid_kj(i, block_q, block_k, window)
+    return jnp.minimum(j if window is None else first + j, last)
+
+
 def _bwd_impl(q, k, v, o, lse, do, scale, causal, block_q, block_k,
-              dlse=None):
+              dlse=None, window=None):
     b, h, sq, _ = q.shape
     delta_rows = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32),
                          axis=-1)                    # [B, H, S]
@@ -471,11 +538,12 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, block_q, block_k,
         (b, h, STAT_SUB, sq))                        # sublane-bcast like lse
     return per_shard(
         functools.partial(_bwd_call, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
+                          block_q=block_q, block_k=block_k, window=window),
         _KERNEL_DIM_AXES)(q, k, v, do, lse, delta)
 
 
-def _bwd_call(q, k, v, do, lse, delta, *, scale, causal, block_q, block_k):
+def _bwd_call(q, k, v, do, lse, delta, *, scale, causal, block_q, block_k,
+              window=None):
     b, h, sq, d = q.shape
     hk = k.shape[1]
     g = h // hk
@@ -484,16 +552,16 @@ def _bwd_call(q, k, v, do, lse, delta, *, scale, causal, block_q, block_k):
     block_k = min(block_k, _round_up(sk, 16))
     nq = pl.cdiv(sq, block_q)
     nk = pl.cdiv(sk, block_k)
-
-    def kv_j(i, j):
-        return jnp.minimum(j, _last_valid_kj(i, block_q, block_k)) \
-            if causal else j
+    nkb = nk if window is None else _band_blocks(
+        nq, nk, lambda i: _valid_kj(i, block_q, block_k, window))
+    kv_j = functools.partial(_kv_index, block_q=block_q, block_k=block_k,
+                             causal=causal, window=window)
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, num_k_blocks=nk,
-                          seq_q=sq, seq_k=sk),
-        grid=(b, h, nq, nk),
+                          block_q=block_q, block_k=block_k, num_k_blocks=nkb,
+                          seq_q=sq, seq_k=sk, window=window),
+        grid=(b, h, nq, nkb),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_k, d),
@@ -511,28 +579,36 @@ def _bwd_call(q, k, v, do, lse, delta, *, scale, causal, block_q, block_k):
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_interpret(),
-        name="flash_dq",
+        name=_kernel_name("dq", window),
     )(q, k, v, do, lse, delta)
 
     # dk/dv: one grid cell per kv head; the g q-head group members are
-    # folded into the innermost loop (t = gi·nq + qi) and accumulated in
+    # folded into the innermost loop (t = gi·nqb + qi) and accumulated in
     # VMEM — repeated K/V is never materialized, in either direction.
-    ni = g * nq
+    # nqb q tiles a member: all of them, or under a window the band's.
+    nqb = nq if window is None else _band_blocks(
+        nk, nq, lambda j: _valid_qi(j, block_q, block_k, window))
+    ni = g * nqb
 
     def qh(hk_, t):
-        return hk_ * g + t // nq
+        return hk_ * g + t // nqb
 
     def q_i(j, t):
-        i = t % nq
-        # First q-tile with any unmasked element for k-tile j (causal);
-        # clamping masked tiles to it skips their DMA.
-        return jnp.maximum(i, _first_valid_qi(j, block_q, block_k)) \
-            if causal else i
+        i = t % nqb
+        if not causal:
+            return i
+        first, last = _valid_qi(j, block_q, block_k, window)
+        if window is None:
+            # First q-tile with any unmasked element for k-tile j; clamping
+            # masked tiles to it skips their DMA.
+            return jnp.maximum(i, first)
+        return jnp.minimum(first + i, jnp.minimum(last, nq - 1))
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, num_q_blocks=nq,
-                          num_inner=ni, seq_q=sq, seq_k=sk),
+                          block_q=block_q, block_k=block_k, num_q_blocks=nqb,
+                          num_inner=ni, seq_q=sq, seq_k=sk, window=window,
+                          num_q_total=nq),
         grid=(b, hk, nk, ni),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d),
@@ -563,7 +639,7 @@ def _bwd_call(q, k, v, do, lse, delta, *, scale, causal, block_q, block_k):
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=_interpret(),
-        name="flash_dkv",
+        name=_kernel_name("dkv", window),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -571,14 +647,16 @@ def _bwd_call(q, k, v, do, lse, delta, *, scale, causal, block_q, block_k):
 # ---------------------------------------------------------------------------
 # Public API with custom VJP
 # ---------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, scale, causal, block_q, block_k):
-    o, _ = _fwd_impl(q, k, v, scale, causal, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, scale, causal, block_q, block_k, window):
+    o, _ = _fwd_impl(q, k, v, scale, causal, block_q, block_k,
+                     window=window)
     return o
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
-    o, lse = _fwd_impl(q, k, v, scale, causal, block_q, block_k)
+def _flash_fwd(q, k, v, scale, causal, block_q, block_k, window):
+    o, lse = _fwd_impl(q, k, v, scale, causal, block_q, block_k,
+                       window=window)
     # Identity outside a jax.checkpoint. _flash_lse below is NOT tagged:
     # ring attention calls it once per hop, and keeping sp partial outputs
     # in f32 per layer is another trade.
@@ -586,9 +664,10 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(scale, causal, block_q, block_k, res, do):
+def _flash_bwd(scale, causal, block_q, block_k, window, res, do):
     q, k, v, o, lse = res
-    return _bwd_impl(q, k, v, o, lse, do, scale, causal, block_q, block_k)
+    return _bwd_impl(q, k, v, o, lse, do, scale, causal, block_q, block_k,
+                     window=window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -673,8 +752,17 @@ def flash_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, scale: Optional[float] = None,
                     block_q: int = DEFAULT_BLOCK,
-                    block_k: int = DEFAULT_BLOCK) -> jax.Array:
+                    block_k: int = DEFAULT_BLOCK,
+                    window: Optional[int] = None) -> jax.Array:
     """Flash attention, layout ``[B, S, H, D]`` (GQA: H_kv may divide H).
+
+    ``window=w`` (causal only) lets query ``i`` see keys ``i − w < j ≤ i``.
+    The three kernels then skip, in compute and in DMA, the k tiles wholly
+    before a q tile's window as they skip those after its diagonal, their
+    innermost grid axis walks that band alone, and the device trace names
+    them ``flash_win_fwd`` / ``flash_win_dq`` / ``flash_win_dkv``. A window
+    that covers the whole sequence is the full causal call, under the full
+    kernels' names.
 
     Differentiable (custom flash backward); accumulation in f32 regardless
     of input dtype (bf16 in, bf16 out, f32 softmax state on-chip), matmuls
@@ -693,5 +781,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     actual (rounded-up) sequence, so short-seq/test calls are unaffected.
     """
     qh, kh, vh, scale = _check_and_transpose(q, k, v, causal, scale)
-    oh = _flash(qh, kh, vh, scale, causal, block_q, block_k)
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError(f"a window ({window}) needs causal=True and at "
+                             f"least one key a query")
+        if window >= q.shape[1]:
+            window = None       # every query sees its whole causal prefix
+    oh = _flash(qh, kh, vh, scale, causal, block_q, block_k, window)
     return oh.transpose(0, 2, 1, 3)

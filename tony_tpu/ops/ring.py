@@ -89,11 +89,21 @@ def bound_axis_size(axis_name: str):
     return None
 
 
+def refuse_window(impl: str, window) -> None:
+    """The sequence-parallel attentions know the full causal mask alone: a
+    hop or a head swap would have to carry the window's second bound."""
+    if window is not None:
+        raise ValueError(
+            f"{impl} attention has no windowed mask (window={window}): a "
+            f"layer with a window needs attn_impl 'flash' or 'xla'")
+
+
 def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    axis_name: str = "sp", causal: bool = True,
                    scale: Optional[float] = None,
                    block_q: int = DEFAULT_BLOCK,
-                   block_k: int = DEFAULT_BLOCK) -> jax.Array:
+                   block_k: int = DEFAULT_BLOCK,
+                   window: Optional[int] = None) -> jax.Array:
     """Per-shard ring attention ([B, S_local, H, D] in/out; GQA: K/V may
     carry H_kv heads with H_kv | H). Call inside shard_map with the
     sequence dim sharded over ``axis_name``.
@@ -105,6 +115,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     therefore ~flat in the sp degree (asserted by
     ``test_ring_error_flat_in_sp_degree``); the wire/rotation dtype of the
     K/V chunks stays the input dtype — ICI bandwidth is unchanged."""
+    refuse_window("ring", window)
     b, s_loc, h, d = q.shape
     hk = k.shape[2]
     if k.shape[2] != v.shape[2]:

@@ -24,13 +24,15 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                       axis_name: str = "sp", causal: bool = True,
                       scale: Optional[float] = None,
                       block_q: int = DEFAULT_BLOCK,
-                      block_k: int = DEFAULT_BLOCK) -> jax.Array:
+                      block_k: int = DEFAULT_BLOCK,
+                      window: Optional[int] = None) -> jax.Array:
     """Per-shard Ulysses attention ([B, S_local, H, D] in/out), for use
     inside shard_map. Requires both q and k/v head counts divisible by the
     axis size."""
 
-    from tony_tpu.ops.ring import bound_axis_size
+    from tony_tpu.ops.ring import bound_axis_size, refuse_window
 
+    refuse_window("ulysses", window)
     if bound_axis_size(axis_name) is None:
         # No axes bound at all (model init / single-shard apply): no swap.
         return flash_attention(q, k, v, causal=causal, scale=scale,

@@ -123,7 +123,13 @@ def jit_train_step(
 
     def wrapped(state, batch, rng):
         with jax.set_mesh(mesh):
-            return jitted(state, batch, rng)
+            state, metrics = jitted(state, batch, rng)
+        # The loss function's own aux metrics (a model's counters) go to
+        # the task's metrics file, unread here: the step is not waited for.
+        aux = {k: v for k, v in metrics.items() if k not in ("loss", "step")}
+        if aux:
+            telemetry.note_step_counters(aux)
+        return state, metrics
 
     def lower(state, batch, rng):
         """``jax.jit(...).lower`` under the same bound mesh — accepts

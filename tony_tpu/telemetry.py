@@ -415,6 +415,34 @@ def _reset_phase_state() -> None:
         _phase_ring.clear()
 
 
+# The newest step's own counters (a model's aux metrics: jit_train_step
+# hands them over as they come back from the device, unread). The reporter
+# reads their values when it writes the metrics file, off the step's path.
+_counter_lock = threading.Lock()
+_step_counters: Dict[str, Any] = {}
+
+
+def note_step_counters(counters: Dict[str, Any]) -> None:
+    """Keep ``counters`` (name → scalar, device arrays welcome) as the
+    newest step's; they appear under ``step_counters`` in the task's
+    metrics file. Costs the step nothing: no value is read here."""
+    with _counter_lock:
+        _step_counters.clear()
+        _step_counters.update(counters)
+
+
+def step_counters() -> Dict[str, float]:
+    with _counter_lock:
+        held = dict(_step_counters)
+    out = {}
+    for name, value in held.items():
+        try:
+            out[name] = float(value)
+        except Exception:  # noqa: BLE001 — a deleted buffer, a non-scalar
+            continue
+    return out
+
+
 def step_done(started_at: float, flops: float = 0.0,
               tokens: float = 0.0) -> None:
     """Record one completed training step that began at ``started_at``
@@ -679,6 +707,9 @@ def collect_device_stats() -> Dict[str, float]:
         # Step-time attribution: rides the metrics file → heartbeat
         # beacon → tony_step_phase_seconds gauges + the `top` phase bar.
         out["step_phases"] = phases  # type: ignore[assignment]
+    counters = step_counters()
+    if counters:
+        out["step_counters"] = counters  # type: ignore[assignment]
     prof = profile_state()
     if prof is not None:
         # On-demand device capture status/result (the coordinator emits
