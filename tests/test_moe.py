@@ -9,14 +9,21 @@ import numpy as np
 import optax
 import pytest
 
+from tony_tpu.models import moe
 from tony_tpu.models.moe import (ExpertLayer, ExpertSpec, MoEConfig,
-                                 _gmm_call, _layout, _tgmm_call,
-                                 moe_counters)
-from tony_tpu.models.transformer import Transformer, causal_lm_loss
+                                 _buffer_rows, _combine, _gmm_call, _layout,
+                                 _tgmm_call, moe_counters, routing_counters)
+from tony_tpu.models.transformer import (Transformer, TransformerConfig,
+                                         causal_lm_loss)
 from tony_tpu.parallel import MeshSpec, build_mesh, init_sharded_state
 from tony_tpu.parallel.sharding import DEFAULT_RULES
 
 D = 24
+
+
+def _tiles(rows, tile=8):
+    """Rows an expert takes of a buffer, by hand: whole tiles, at least one."""
+    return max(-(-int(rows) // tile), 1) * tile
 
 
 def _rules():
@@ -100,6 +107,81 @@ def test_no_token_is_dropped_at_any_load(hot):
     assert float(counters["moe_expert_load_max_over_mean"]) >= 8 / 3 - 1e-6
 
 
+# What 20 tokens a chunk send to experts 2 and 3, the quarter held of eight,
+# two choices a token: (tokens that choose 2, tokens that choose 3, the live
+# rows of a 56-row buffer in tiles of 8 that this makes).
+LOADS = {"none-held": (0, 0, 16), "one-expert": (20, 0, 32),
+         "a-tile-more": (20, 10, 40), "fullest": (20, 20, 48)}
+
+
+@pytest.mark.parametrize("load,segment_rows", [
+    ("even", 16), ("none-held", 16), ("one-expert", 16), ("a-tile-more", 16),
+    ("fullest", 16), ("fullest", 8), ("a-tile-more", 40), ("fullest", 40)])
+def test_a_quarter_held_at_every_load(monkeypatch, load, segment_rows):
+    """XLA's passes over the row buffers stop at the live prefix, a segment at
+    a time: output and every gradient are the dense masked sum's whether the
+    live rows are the padding tiles alone, end on a segment's boundary (32
+    rows in segments of 16), a tile past it, or fill the buffer as far as a
+    router can (the last segment of 40 starts early and overlaps the first,
+    whose rows the passes that work in place must leave alone; segments of 8
+    divide the buffer and need no such care)."""
+    monkeypatch.setattr(moe, "SEGMENT_ROWS", segment_rows)
+    spec = _spec(held=(2, 2), top_k=2, chunk_tokens=20)
+    layer, params, r, x = _inputs(spec, tokens=(2, 20))
+    assert _buffer_rows(20, spec, 2) == 56
+    if load != "even":
+        to_2, to_3, live = LOADS[load]
+        # Position s reads feature s alone, and the router sends it where the
+        # plan says: its other choice is expert 0 or 1, held elsewhere.
+        first = jnp.where(jnp.arange(20) < to_2, 2, 0)
+        second = jnp.where(jnp.arange(20) < to_3, 3, 1)
+        router = jnp.zeros((D, 8)).at[jnp.arange(20), first].set(50.0) \
+            .at[jnp.arange(20), second].set(45.0)
+        params = dict(params, router=router)
+        r = jnp.broadcast_to(jnp.eye(20, D), (2, 20, D)) \
+            * jnp.array([1.0, 1.1])[:, None, None]
+    _same(spec, params, r, x, layer)
+    _, state = layer.apply({"params": params}, r, x,
+                           mutable=["intermediates"])
+    share = float(moe_counters(
+        state["intermediates"])["moe_buffer_rows_live_share"])
+    if load != "even":
+        assert share == pytest.approx(live / 56)
+    assert 16 / 56 - 1e-6 <= share <= 48 / 56 + 1e-6
+
+
+def test_combine_takes_its_weight_gradient_on_the_row_side():
+    """``dweights[t, c] = Σ_d dout[t, d] · y[pos[t, c], d]``: the row side's
+    one pass (a row's ``Σ_d dout · y`` beside its ``dy``, then a gather of
+    scalars) against a gather of ``y``'s rows a choice, to float32
+    rounding."""
+    t, k, d, tile = 24, 3, 16, 8
+    ks = jax.random.split(jax.random.key(7), 4)
+    idx = jax.lax.top_k(jax.random.normal(ks[0], (t, 8)), k)[1]
+    rows = _buffer_rows(t, _spec(), 4)
+    held, pos, row_pair, row_live, _, n_active, _ = _layout(
+        idx, 2, 4, tile, rows)
+    y = jax.random.normal(ks[1], (rows, d))
+    weights = jax.nn.softmax(jax.random.normal(ks[2], (t, k)))
+    dout = jax.random.normal(ks[3], (t, d))
+    _, vjp = jax.vjp(lambda y, w: _combine(y, w, pos, held, row_pair,
+                                           row_live, n_active, tile),
+                     y, weights)
+    dy, dweights = vjp(dout)
+    safe = np.minimum(np.asarray(pos), rows - 1)
+    want = np.stack([np.sum(np.asarray(dout) * np.asarray(y)[safe[:, c]],
+                            axis=-1) for c in range(k)], axis=1)
+    want = np.where(np.asarray(held), want, 0.0)
+    assert np.asarray(held).any() and not np.asarray(held).all()
+    np.testing.assert_allclose(dweights, want, rtol=1e-6, atol=1e-6)
+    live = int(n_active[0]) * tile
+    row_weight = np.where(np.asarray(row_live),
+                          np.asarray(weights).reshape(-1)[row_pair], 0.0)
+    np.testing.assert_allclose(
+        dy[:live], (np.asarray(dout)[np.asarray(row_pair) // k]
+                    * row_weight[:, None])[:live], rtol=1e-6, atol=1e-6)
+
+
 def test_an_expert_with_no_rows_gets_a_zero_gradient():
     spec = _spec(top_k=2)
     layer, params, r, x = _inputs(spec)
@@ -143,7 +225,51 @@ def test_counters_of_a_share():
     load = np.array([(np.asarray(idx) == e).sum() for e in range(2)])
     assert c["moe_expert_load_max_over_mean"] == pytest.approx(
         load.max() / load.mean())
+    # By hand: a chunk of 16 tokens, each expert's rows in whole tiles of 8
+    # and at least one, over the 6 tiles a chunk's buffer has.
+    chunks = np.asarray(idx).reshape(-1, 16 * 2)
+    live = [sum(_tiles((chunk == e).sum()) for e in range(2))
+            for chunk in chunks]
+    assert _buffer_rows(16, spec, 2) == 48 and len(live) == 16
+    assert c["moe_buffer_rows_live_share"] == pytest.approx(
+        np.mean(live) / 48)
     assert moe_counters({}) == {}
+
+
+def test_the_live_share_is_one_device_s():
+    """Tokens split two ways and experts four ways over devices: a chunk is
+    a device's tokens and a buffer holds a device's experts, so the share is
+    the mean over the eight (token group, expert group) pairs."""
+    spec = _spec(top_k=2, chunk_tokens=8192)
+    idx = jax.lax.top_k(jax.random.normal(jax.random.key(3), (64, 8)), 2)[1]
+    got = float(routing_counters(spec, idx, 0, 8, token_groups=2,
+                                 expert_groups=4)
+                ["moe_buffer_rows_live_share"])
+    live = [sum(_tiles((np.asarray(idx)[g * 32:(g + 1) * 32] == e).sum())
+                for e in (2 * s, 2 * s + 1))
+            for g in range(2) for s in range(4)]
+    assert got == pytest.approx(np.mean(live) / _buffer_rows(32, spec, 2))
+
+
+def test_a_dense_step_never_meets_the_expert_layer(monkeypatch):
+    """A configuration without experts traces nothing of ``models/moe.py``:
+    its compiled step cannot move with that file."""
+    def refuse(*args, **kwargs):
+        raise AssertionError("the expert layer was traced")
+
+    for name in ("routed_experts", "routing_counters", "_live_rows",
+                 "_layout", "_gmm_call", "_tgmm_call"):
+        monkeypatch.setattr(moe, name, refuse)
+    monkeypatch.setattr(ExpertLayer, "__call__", refuse)
+    cfg = TransformerConfig(vocab_size=64, dim=32, n_layers=2, n_heads=2,
+                            n_kv_heads=1, mlp_dim=64, max_seq_len=16,
+                            dtype=jnp.float32)
+    model = Transformer(cfg)
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    params = model.init(jax.random.key(0), tokens)["params"]
+    text = jax.jit(jax.grad(lambda p: causal_lm_loss(
+        model.apply({"params": p}, tokens), tokens))).lower(params).as_text()
+    assert "moe" not in text
 
 
 @pytest.mark.parametrize("transpose", [False, True])

@@ -52,3 +52,34 @@ def test_train_step_hands_over_the_aux_metrics_alone(with_aux):
     assert set(metrics) == {"loss", "step"} | ({"rows"} if with_aux
                                                else set())
     assert telemetry.step_counters() == ({"rows": 8.0} if with_aux else {})
+
+
+def test_an_expert_model_s_counters_reach_the_step_counters():
+    """The four counters of a model with experts, the live share of its row
+    buffers among them, through ``jit_train_step`` as a program hands them
+    over (``moe_counters`` of what the layers sowed)."""
+    from tony_tpu.models.moe import MoEConfig, moe_counters
+    from tony_tpu.models.transformer import Transformer, causal_lm_loss
+
+    mesh = build_mesh(MeshSpec(dp=8))
+    model = Transformer(MoEConfig.tiny_moe(n_layers=1))
+    batch = {"tokens": jnp.zeros((8, 16), jnp.int32)}
+    state, sh = init_sharded_state(model, batch["tokens"], optax.sgd(0.1),
+                                   mesh)
+
+    def loss_fn(params, batch, rng):
+        logits, sown = model.apply({"params": params}, batch["tokens"],
+                                   mutable=["intermediates"])
+        return (causal_lm_loss(logits, batch["tokens"]),
+                moe_counters(sown["intermediates"]))
+
+    step = jit_train_step(loss_fn, mesh, sh, batch)
+    step(state, batch, jax.random.key(0))
+    counters = telemetry.step_counters()
+    assert set(counters) == {"moe_rows_routed", "moe_rows_unrouted_share",
+                             "moe_expert_load_max_over_mean",
+                             "moe_buffer_rows_live_share"}
+    # All four experts held, 16 tokens a device choosing 2: 32 rows and at
+    # most a tile of 8 an expert in a buffer of (4 + 4) tiles.
+    assert 32 / 64 <= counters["moe_buffer_rows_live_share"] <= 1.0
+    assert counters["moe_rows_routed"] == 8 * 16 * 2

@@ -21,7 +21,9 @@ the shares of all the chips add up to the whole layer
   its pair), every expert's rows padded to the matmul's row tile, never to
   a capacity. Shapes are static under ``jit``: the buffer has room for every
   pair a chunk of tokens could send here, and the kernels skip the tiles
-  past the rows that came, in compute and in DMA.
+  past the rows that came, in compute and in DMA. So do XLA's passes over
+  the row buffers in the backward (``_live_rows``): the live rows are a
+  prefix of the buffer, ``n_active`` tiles long.
 - **Grouped matmuls** are named Mosaic calls: ``moe_gmm`` (forward and
   input gradient: each row tile times its own expert's matrix, the expert
   read from a prefetched table) and ``moe_tgmm`` (weight gradient: the row
@@ -41,7 +43,7 @@ Spans: ``tony.moe.route``, ``tony.moe.dispatch``, ``tony.moe.experts``,
 ``tony.moe.combine`` (``jax.named_scope``). Counters, sown into the
 ``intermediates`` collection and reduced by ``moe_counters``:
 ``moe_rows_routed``, ``moe_rows_unrouted_share``,
-``moe_expert_load_max_over_mean``.
+``moe_expert_load_max_over_mean``, ``moe_buffer_rows_live_share``.
 """
 
 from __future__ import annotations
@@ -68,6 +70,9 @@ ACTIVATIONS = {"silu": nn.silu, "relu": nn.relu}
 # Mosaic's scoped VMEM default (16 MiB) is under a [2560, 768] expert
 # matrix double-buffered beside its row tiles; the v5e has 128 MiB.
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+# XLA's passes over a row buffer go a segment of whole tiles at a time and
+# stop where the live rows end (``_live_rows``).
+SEGMENT_ROWS = 4096
 
 
 @dataclasses.dataclass(frozen=True)
@@ -299,10 +304,15 @@ def _grouped_matmul_fwd(lhs, w, w_lo, w_q, tile_expert, n_active, tile_rows):
 
 def _grouped_matmul_bwd(tile_rows, res, dout):
     lhs, w_lo, tile_expert, n_active = res
-    dlhs = _gmm_call(dout, w_lo, tile_expert, n_active, tile_rows=tile_rows,
-                     transpose_rhs=True)
     dw = _tgmm_call(lhs, dout, tile_expert, n_active, tile_rows=tile_rows,
                     count=w_lo.shape[0])
+    # The weight gradient first: it is the last reader of ``lhs``, whose
+    # buffer is then free before the input gradient's is made. Left to the
+    # scheduler, gate's and up's ``xs`` outlives both of their input
+    # gradients and the compiled step is 0.23 GB larger.
+    dw, dout = jax.lax.optimization_barrier((dw, dout))
+    dlhs = _gmm_call(dout, w_lo, tile_expert, n_active, tile_rows=tile_rows,
+                     transpose_rhs=True)
     return dlhs, dw, None, None, None, None
 
 
@@ -312,6 +322,12 @@ grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 # ---------------------------------------------------------------------------
 # The layout: (token, choice) pairs, expert by expert, padded to the tile
 # ---------------------------------------------------------------------------
+def _padded(sizes, tile_rows: int):
+    """Rows an expert takes of the buffer: its own, in whole tiles, and at
+    least one (the weight gradient writes every expert's block)."""
+    return jnp.maximum(-(-sizes // tile_rows), 1) * tile_rows
+
+
 def _layout(idx, first, count: int, tile_rows: int, rows: int):
     """Where each (token, choice) pair of ``idx [T, k]`` (expert ids) goes in
     a buffer of ``rows`` rows that holds experts ``[first, first + count)``
@@ -331,7 +347,7 @@ def _layout(idx, first, count: int, tile_rows: int, rows: int):
               ).astype(jnp.int32)                           # [T·k, count]
     sizes = jnp.sum(onehot, axis=0)
     rank = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot, axis=1)
-    padded = jnp.maximum(-(-sizes // tile_rows), 1) * tile_rows
+    padded = _padded(sizes, tile_rows)
     ends = jnp.cumsum(padded)
     starts = ends - padded
     pos = jnp.where(held.reshape(-1),
@@ -353,6 +369,35 @@ def _layout(idx, first, count: int, tile_rows: int, rows: int):
     n_active = (ends[-1:] // tile_rows).astype(jnp.int32)
     return (held, pos.reshape(t, k).astype(jnp.int32), row_pair, row_live,
             tile_expert, n_active, sizes)
+
+
+def _live_rows(fn, written, read, n_active, tile_rows: int):
+    """``written[r], … = fn(written[r], …, read[r], …)`` for the rows ``r`` of
+    the live prefix ``[0, n_active · tile_rows)`` of buffers with one leading
+    dim; the rows past it stay as they are, and nothing reads them. ``fn``
+    works row by row on a segment of whole tiles (``SEGMENT_ROWS``); the loop's
+    trip count follows ``n_active``, so autodiff never meets it: only the
+    hand-written halves of a ``custom_vjp`` call this. A buffer in ``written``
+    that is dead afterwards is updated in place."""
+    rows = written[0].shape[0]
+    seg = min(max(SEGMENT_ROWS // tile_rows, 1) * tile_rows, rows)
+
+    def segment(i, bufs):
+        # Where segments do not divide the buffer the last one starts early,
+        # and the rows an earlier one wrote keep what it wrote.
+        start = jnp.minimum(i * seg, rows - seg)
+        old = [jax.lax.dynamic_slice_in_dim(b, start, seg) for b in bufs]
+        new = fn(*old, *(jax.lax.dynamic_slice_in_dim(a, start, seg)
+                         for a in read))
+        if rows % seg:
+            fresh = start + jnp.arange(seg) >= i * seg
+            new = [jnp.where(fresh.reshape(-1, *(1,) * (o.ndim - 1)), n, o)
+                   for n, o in zip(new, old)]
+        return tuple(jax.lax.dynamic_update_slice_in_dim(b, n, start, 0)
+                     for b, n in zip(bufs, new))
+
+    live = n_active[0] * tile_rows
+    return jax.lax.fori_loop(0, -(-live // seg), segment, tuple(written))
 
 
 def _gather_sum(src, pos, scale):
@@ -391,36 +436,98 @@ def _dispatch_bwd(res, dxs):
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _for_gate_and_up(xs, n_active, tile_rows):
+    """``xs`` twice, for the two products that read it: the transpose sums
+    their two cotangents over the live rows, in the first one's buffer."""
+    del n_active
+    return xs, xs
+
+
+def _for_gate_and_up_fwd(xs, n_active, tile_rows):
+    return (xs, xs), n_active
+
+
+def _for_gate_and_up_bwd(tile_rows, n_active, dboth):
+    (dxs,) = _live_rows(lambda a, b: (a + b,), dboth[:1], dboth[1:],
+                        n_active, tile_rows)
+    return dxs, None
+
+
+_for_gate_and_up.defvjp(_for_gate_and_up_fwd, _for_gate_and_up_bwd)
+
+
+def _gate_times_up(activation: str):
+    act = ACTIVATIONS[activation]
+    return lambda gate, up: act(gate) * up
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _gated(gate, up, n_active, tile_rows, activation):
+    """``act(gate) · up``. Forward it is one fused pass over the whole
+    buffer (a loop would first fill a fresh buffer with zeros, which costs
+    what the dead rows do); its transpose works over the live rows, in the
+    buffers of ``gate`` and ``up``."""
+    del n_active
+    return _gate_times_up(activation)(gate, up)
+
+
+def _gated_fwd(gate, up, n_active, tile_rows, activation):
+    return _gate_times_up(activation)(gate, up), (gate, up, n_active)
+
+
+def _gated_bwd(tile_rows, activation, res, dhidden):
+    gate, up, n_active = res
+
+    def transpose(g, u, dh):    # what autodiff writes, a segment at a time
+        return jax.vjp(_gate_times_up(activation), g, u)[1](dh)
+
+    dgate, dup = _live_rows(transpose, (gate, up), (dhidden,), n_active,
+                            tile_rows)
+    return dgate, dup, None
+
+
+_gated.defvjp(_gated_fwd, _gated_bwd)
+
+
 def _combined(y, weights, pos, held):
     return _gather_sum(y, pos, jnp.where(held, weights, 0.0)).astype(y.dtype)
 
 
-@jax.custom_vjp
-def _combine(y, weights, pos, held, row_pair, row_live):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _combine(y, weights, pos, held, row_pair, row_live, n_active, tile_rows):
     """``out[t] = Σ_c weights[t, c] · y[pos[t, c]]`` over the held pairs."""
-    del row_pair, row_live
+    del row_pair, row_live, n_active
     return _combined(y, weights, pos, held)
 
 
-def _combine_fwd(y, weights, pos, held, row_pair, row_live):
+def _combine_fwd(y, weights, pos, held, row_pair, row_live, n_active,
+                 tile_rows):
     return (_combined(y, weights, pos, held),
-            (y, weights, pos, held, row_pair, row_live))
+            (y, weights, pos, held, row_pair, row_live, n_active))
 
 
-def _combine_bwd(res, dout):
-    y, weights, pos, held, row_pair, row_live = res
+def _combine_bwd(tile_rows, res, dout):
+    y, weights, pos, held, row_pair, row_live, n_active = res
     k = weights.shape[1]
-    # A padding row's cotangent is exactly zero: the weight gradient's
-    # kernel counts on it.
-    row_weight = jnp.where(row_live, weights.reshape(-1)[row_pair], 0.0)
-    dy = (dout[row_pair // k].astype(jnp.float32)
-          * row_weight[:, None]).astype(y.dtype)
-    safe = jnp.minimum(pos, y.shape[0] - 1)
-    dweights = jnp.stack([
-        jnp.sum(dout.astype(jnp.float32) * y[safe[:, c]].astype(jnp.float32),
-                axis=-1) for c in range(k)], axis=1)
-    dweights = jnp.where(held, dweights, 0.0).astype(weights.dtype)
-    return dy, dweights, None, None, None, None
+    pair_weight = weights.reshape(-1)
+
+    def row_side(y_rows, _, pair, live):
+        # One gather of ``dout``'s rows gives both cotangents: ``dy`` takes
+        # ``y``'s place, and a row's ``Σ_d dout · y`` is its pair's weight
+        # gradient. A padding row's ``dy`` is exactly zero: the weight
+        # gradient's kernel counts on it.
+        dout_rows = dout[pair // k].astype(jnp.float32)
+        weight = jnp.where(live, pair_weight[pair], 0.0)
+        return ((dout_rows * weight[:, None]).astype(y.dtype),
+                jnp.sum(dout_rows * y_rows.astype(jnp.float32), axis=-1))
+
+    dy, dweight_rows = _live_rows(
+        row_side, (y, jnp.zeros(y.shape[:1], jnp.float32)),
+        (row_pair, row_live), n_active, tile_rows)
+    dweights = jnp.where(held, dweight_rows[jnp.minimum(pos, y.shape[0] - 1)],
+                         0.0).astype(weights.dtype)
+    return dy, dweights, None, None, None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -435,6 +542,12 @@ def _buffer_rows(tokens: int, spec: ExpertSpec, count: int) -> int:
     return (-(-most // tile) + count) * tile
 
 
+def _chunk_tokens(spec: ExpertSpec, tokens: int) -> int:
+    """Tokens routed at a time: ``chunk_tokens``, or all where that does not
+    divide them."""
+    return tokens if tokens % spec.chunk_tokens else spec.chunk_tokens
+
+
 def routed_experts(spec: ExpertSpec, x, idx, weights, w_gate, w_up, w_down,
                    first, dtype, int8: bool = False):
     """What the experts ``[first, first + count)`` (``count`` from the
@@ -443,10 +556,9 @@ def routed_experts(spec: ExpertSpec, x, idx, weights, w_gate, w_up, w_down,
     three forward products in int8 where ``int8`` says so."""
     t, d = x.shape
     count = w_gate.shape[0]
-    act = ACTIVATIONS[spec.activation]
     lo = [w.astype(dtype) for w in (w_gate, w_up, w_down)]
     q = [quantize_symmetric(w, INT8, axis=1) if int8 else None for w in lo]
-    chunk = spec.chunk_tokens if t % spec.chunk_tokens == 0 else t
+    chunk = _chunk_tokens(spec, t)
     rows = _buffer_rows(chunk, spec, count)
 
     # A chunk keeps nothing for its backward but its inputs: what it kept
@@ -462,11 +574,14 @@ def routed_experts(spec: ExpertSpec, x, idx, weights, w_gate, w_up, w_down,
             gmm = functools.partial(grouped_matmul, tile_expert=tile_expert,
                                     n_active=n_active,
                                     tile_rows=spec.tile_rows)
-            hidden = act(gmm(xs, w_gate, lo[0], q[0])) \
-                * gmm(xs, w_up, lo[1], q[1])
+            xs_gate, xs_up = _for_gate_and_up(xs, n_active, spec.tile_rows)
+            hidden = _gated(gmm(xs_gate, w_gate, lo[0], q[0]),
+                            gmm(xs_up, w_up, lo[1], q[1]), n_active,
+                            spec.tile_rows, spec.activation)
             y = gmm(hidden, w_down, lo[2], q[2])
         with jax.named_scope("tony.moe.combine"):
-            return _combine(y, wc, pos, held, row_pair, row_live)
+            return _combine(y, wc, pos, held, row_pair, row_live, n_active,
+                            spec.tile_rows)
 
     if chunk == t:
         return one_chunk((x.astype(dtype), idx, weights))
@@ -477,20 +592,32 @@ def routed_experts(spec: ExpertSpec, x, idx, weights, w_gate, w_up, w_down,
     return parts.reshape(t, d)
 
 
-def routing_counters(idx, first, count) -> dict:
+def routing_counters(spec: ExpertSpec, idx, first, count, token_groups=1,
+                     expert_groups=1) -> dict:
     """A step's routing, for experts ``[first, first + count)``: the rows
     (token, choice) that met one of them, the share of tokens none of whose
-    choices did, and the fullest expert's rows over the mean."""
+    choices did, the fullest expert's rows over the mean, and the share of a
+    chunk's row buffer that is live (the rows that came and their padding, by
+    the layout's own sizes: ``n_active · tile_rows`` over the buffer's rows),
+    a mean over the chunks. Where the tokens are split ``token_groups`` ways
+    and the experts ``expert_groups`` ways over devices, a chunk and a buffer
+    are one device's."""
     held = (idx >= first) & (idx < first + count)
-    load = jnp.sum((idx.reshape(-1, 1) - first
-                    == jnp.arange(count)[None, :]).astype(jnp.float32),
-                   axis=0)
+    chunk = _chunk_tokens(spec, idx.shape[0] // token_groups)
+    sizes = jnp.sum((idx.reshape(-1, chunk * idx.shape[1], 1) - first
+                     == jnp.arange(count)).astype(jnp.int32), axis=1)
+    load = jnp.sum(sizes, axis=0).astype(jnp.float32)
+    live = jnp.sum(_padded(sizes, spec.tile_rows).reshape(
+        -1, expert_groups, count // expert_groups), axis=-1)
     return {
         "moe_rows_routed": jnp.sum(held.astype(jnp.float32)),
         "moe_rows_unrouted_share":
             1.0 - jnp.mean(jnp.any(held, axis=-1).astype(jnp.float32)),
         "moe_expert_load_max_over_mean":
             jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9),
+        "moe_buffer_rows_live_share":
+            jnp.mean(live.astype(jnp.float32))
+            / _buffer_rows(chunk, spec, count // expert_groups),
     }
 
 
@@ -506,7 +633,8 @@ def moe_counters(intermediates) -> dict:
             found.setdefault(name, []).append(value)
     reduce = {"moe_rows_routed": jnp.sum,
               "moe_rows_unrouted_share": jnp.mean,
-              "moe_expert_load_max_over_mean": jnp.max}
+              "moe_expert_load_max_over_mean": jnp.max,
+              "moe_buffer_rows_live_share": jnp.mean}
     return {name: reduce[name](jnp.stack(values))
             for name, values in found.items()}
 
@@ -553,27 +681,12 @@ class ExpertLayer(nn.Module):
         w_up = w("up", (count, d, spec.width), ("expert", "embed", "mlp"))
         w_down = w("down", (count, spec.width, d), ("expert", "mlp", "embed"))
 
-        with jax.named_scope("tony.moe.route"):
-            # float32 at highest precision: the one matmul whose rounding
-            # can move a discrete choice.
-            logits = jnp.dot(router_in.reshape(t, d).astype(jnp.float32),
-                             router.astype(jnp.float32),
-                             precision=jax.lax.Precision.HIGHEST)
-            top, idx = jax.lax.top_k(logits, spec.top_k)
-            weights = jax.nn.softmax(top, axis=-1)
-            for name, value in routing_counters(idx, first, count).items():
-                self.sow("intermediates", name, value)
-
-        xt = x.reshape(t, d)
         # Mosaic kernels cannot be partitioned automatically: under a bound
         # mesh the body runs in a shard_map manual over every axis that is
         # not manual already (size-1 axes included, as compat.per_shard).
         mesh = compat.current_mesh()
         auto = () if mesh is None else tuple(
             a for a in mesh.axis_names if a not in mesh.manual_axes)
-        if not auto:
-            return routed(spec, xt, idx, weights, w_gate, w_up, w_down,
-                          first, self.dtype).reshape(b, s, d)
         n_ep = mesh.shape[EP_AXIS] if EP_AXIS in auto else 1
         rows = tuple(a for a in BATCH_AXES if a in auto)
         n_rows = math.prod(mesh.shape[a] for a in rows)
@@ -585,6 +698,23 @@ class ExpertLayer(nn.Module):
                 f"an ep axis of {n_ep} shares out all {count} experts "
                 f"(held={spec.held}) and scatters {t // n_rows} tokens: "
                 f"both must divide, and no share may be named")
+
+        with jax.named_scope("tony.moe.route"):
+            # float32 at highest precision: the one matmul whose rounding
+            # can move a discrete choice.
+            logits = jnp.dot(router_in.reshape(t, d).astype(jnp.float32),
+                             router.astype(jnp.float32),
+                             precision=jax.lax.Precision.HIGHEST)
+            top, idx = jax.lax.top_k(logits, spec.top_k)
+            weights = jax.nn.softmax(top, axis=-1)
+            for name, value in routing_counters(
+                    spec, idx, first, count, n_rows, n_ep).items():
+                self.sow("intermediates", name, value)
+
+        xt = x.reshape(t, d)
+        if not auto:
+            return routed(spec, xt, idx, weights, w_gate, w_up, w_down,
+                          first, self.dtype).reshape(b, s, d)
 
         def shard(xt, idx, weights, w_gate, w_up, w_down):
             if n_ep == 1:
