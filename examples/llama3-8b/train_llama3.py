@@ -1,7 +1,7 @@
 """Llama-3-8B pretraining step: fsdp x tp sharding, flash attention,
 bf16 activations, f32 params, checkpoint/resume via the job checkpoint
-dir. The flagship target (BASELINE.json): geometry from the public
-Llama-3-8B config (32L / 4096d / 32h / 8kv / 14336 mlp / 128k vocab)."""
+dir. Geometry from the public Llama-3-8B config (32L / 4096d / 32h /
+8kv / 14336 mlp / 128k vocab)."""
 import os
 import sys
 

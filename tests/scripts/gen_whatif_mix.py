@@ -1,6 +1,6 @@
 """Deterministic generator for tests/fixtures/whatif_mix — the 50-job
-recorded tenant mix behind the what-if simulator's unit matrix, the CI
-no-deps smoke and the BENCH_WHATIF suite.
+recorded tenant mix behind the what-if simulator's unit matrix and the
+CI no-deps smoke.
 
 The mix is engineered so each counterfactual axis has a measurable
 signal:

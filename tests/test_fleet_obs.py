@@ -25,8 +25,6 @@ from tony_tpu.fleet.daemon import FleetDaemon, QUEUED, RUNNING
 
 pytestmark = pytest.mark.faults
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
 
 @pytest.fixture(autouse=True)
 def _clean_faults():
@@ -668,33 +666,3 @@ def test_daemon_trace_closes_all_spans_on_orderly_stop(tmp_path):
     names = {e["name"] for e in payload["traceEvents"]
              if e.get("ph") == "X"}
     assert {"fleet.queue", "fleet.job"} <= names
-
-
-def test_bench_fleet_fixtures_gate_regressions():
-    from tony_tpu.profiling import benchdiff
-
-    base = json.load(open(os.path.join(
-        REPO, "benchmarks", "fixtures", "bench_fleet_base.json")))
-    regressed = json.load(open(os.path.join(
-        REPO, "benchmarks", "fixtures", "bench_fleet_regressed.json")))
-    ok = benchdiff.diff_bench(base, base)
-    assert not ok["regressions"]
-    bad = benchdiff.diff_bench(base, regressed)
-    names = {r["metric"] for r in bad["regressions"]}
-    assert any("goodput_fraction" in n for n in names)
-    assert any("queue_wait_p99_s" in n for n in names)
-    assert any("preemptions_per_job" in n for n in names)
-    assert any("warm_start_fraction" in n for n in names)
-
-
-def test_benchdiff_fleet_directions():
-    from tony_tpu.profiling.benchdiff import _direction
-
-    assert _direction(("detail", "mix", "fleet_goodput_fraction")) == \
-        "higher"
-    assert _direction(("detail", "mix", "warm_start_fraction")) == \
-        "higher"
-    assert _direction(("detail", "mix", "queue_wait_p50_s")) == "lower"
-    assert _direction(("detail", "mix", "queue_wait_p99_s")) == "lower"
-    assert _direction(("detail", "mix", "preemptions_per_job")) == \
-        "lower"

@@ -218,8 +218,8 @@ def test_gen_whatif_mix_regenerates_checked_in_fixture(tmp_path):
         checked_in = f.read()
     assert fresh == checked_in, \
         "gen_whatif_mix.py no longer reproduces tests/fixtures/" \
-        "whatif_mix byte-for-byte — regenerate the fixture (and " \
-        "re-record BENCH_WHATIF) or fix the drift"
+        "whatif_mix byte-for-byte — regenerate the fixture or fix " \
+        "the drift"
 
 
 def test_recorded_sim_run_parity_replays_clean(tmp_path):

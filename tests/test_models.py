@@ -79,17 +79,16 @@ def test_chunked_loss_matches_full_loss():
 
 
 @pytest.mark.parametrize("attn_impl,variants", [
-    ("xla", (dict(remat=False), dict(remat=True),
-             dict(remat=True, remat_skip_every=2))),
+    ("xla", (dict(remat=False), dict(remat=True))),
     # What the remat keeps of the flash forward (its tagged o and lse under
     # None, nothing under "nothing_saveable") are the values a re-run gives.
     ("flash", (dict(remat=False), dict(remat=True, remat_policy=None),
                dict(remat=True, remat_policy="nothing_saveable"))),
 ])
 def test_selective_remat_is_numerically_inert(attn_impl, variants):
-    """remat_skip_every and remat_policy change memory/recompute scheduling
-    only — loss and gradients must be bit-comparable to full remat and to
-    no remat (it's the r5 perf lever; a numerics change would be a bug)."""
+    """remat and remat_policy change memory/recompute scheduling only — loss
+    and gradients must be bit-comparable to full remat and to no remat (a
+    numerics change would be a bug)."""
     import flax.linen as nn
     from tony_tpu.parallel.sharding import DEFAULT_RULES
 

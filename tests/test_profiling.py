@@ -5,9 +5,8 @@ e2e drill: `tony-tpu profile` against a live 2-task job.
 Units cover: phase ring bounds and sum-to-wall, the bottleneck
 classifier's golden matrix (all five verdicts), the executor's
 profile-directive dedup, the beacon round-trip into Prometheus text /
-metrics.live / perf.json, profile.start refusal shapes, the
-profile.capture fault site degrading cleanly, and the bench regression
-gate against the checked-in CI fixtures.
+metrics.live / perf.json, profile.start refusal shapes, and the
+profile.capture fault site degrading cleanly.
 """
 
 import collections
@@ -27,14 +26,9 @@ from tony_tpu.profiling import (CKPT_BOUND, COMMS_BOUND, COMPUTE_BOUND,
                                 INPUT_BOUND, JOURNAL_BOUND,
                                 RENDEZVOUS_BOUND, RPC_BOUND,
                                 UNDERUTILIZED, build_perf_report,
-                                classify, classify_coord, diff_bench,
-                                phase_fractions)
-from tony_tpu.profiling import benchdiff
+                                classify, classify_coord, phase_fractions)
 
 pytestmark = pytest.mark.faults
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-FIXTURES = os.path.join(REPO, "benchmarks", "fixtures")
 
 
 @pytest.fixture(autouse=True)
@@ -412,73 +406,6 @@ def test_profile_start_refusal_shapes(tmp_path):
         assert not res["ok"] and "max-artifacts" in res["message"]
     finally:
         _close(coord)
-
-
-# ---------------------------------------------------------------------------
-# Bench regression gate (the CI fixtures are the contract)
-# ---------------------------------------------------------------------------
-def test_bench_diff_fixture_pass_and_regression():
-    base = json.load(open(os.path.join(FIXTURES, "bench_base.json")))
-    ok = json.load(open(os.path.join(FIXTURES, "bench_ok.json")))
-    bad = json.load(open(os.path.join(FIXTURES, "bench_regressed.json")))
-    res_ok = diff_bench(base, ok)
-    assert res_ok["regressions"] == [] and res_ok["compared"] > 10
-    res_bad = diff_bench(base, bad)
-    flagged = {r["metric"] for r in res_bad["regressions"]}
-    assert "detail.orchestration.submit_to_first_step_s" in flagged
-    assert "detail.phase_probe.step_phases_s.data_wait" in flagged
-    assert "detail.tokenfile_train.tokens_per_sec" in flagged
-    # The grad-sync comms gate: the regressed fixture's comms_fraction
-    # jump (0.03 -> 0.19) is flagged lower-is-better.
-    assert "detail.phase_probe.comms_fraction" in flagged
-    # the CLI entry exits 0 / 1 accordingly
-    assert benchdiff.main([os.path.join(FIXTURES, "bench_base.json"),
-                           os.path.join(FIXTURES, "bench_ok.json")]) == 0
-    assert benchdiff.main([os.path.join(FIXTURES, "bench_base.json"),
-                           os.path.join(FIXTURES,
-                                        "bench_regressed.json")]) == 1
-
-
-def test_bench_diff_comms_fraction_direction():
-    """comms_fraction is lower-better: a drop is an improvement, a jump
-    past tolerance is a regression — never the other way round."""
-    base = {"value": 1.0, "detail": {"phase_probe":
-                                     {"comms_fraction": 0.10}}}
-    worse = {"value": 1.0, "detail": {"phase_probe":
-                                      {"comms_fraction": 0.30}}}
-    better = {"value": 1.0, "detail": {"phase_probe":
-                                       {"comms_fraction": 0.02}}}
-    assert [r["metric"] for r in diff_bench(base, worse)["regressions"]] \
-        == ["detail.phase_probe.comms_fraction"]
-    res = diff_bench(base, better)
-    assert res["regressions"] == []
-    assert [r["metric"] for r in res["improvements"]] \
-        == ["detail.phase_probe.comms_fraction"]
-
-
-def test_bench_diff_never_compares_config_echoes():
-    a = {"value": 100.0, "detail": {"loss": 10.0, "params": 317,
-                                    "batch": 4, "seq": 2048}}
-    b = {"value": 100.0, "detail": {"loss": 99.0, "params": 1,
-                                    "batch": 1, "seq": 1}}
-    res = diff_bench(a, b)
-    assert res["regressions"] == [] and res["compared"] == 1
-
-
-def test_bench_diff_unwraps_harness_parsed_shape():
-    base = {"parsed": {"value": 100.0}}
-    cand = {"value": 80.0}
-    res = diff_bench(base, cand)
-    assert [r["metric"] for r in res["regressions"]] == ["value"]
-
-
-def test_bench_diff_missing_metrics_listed_not_flagged():
-    base = {"value": 100.0,
-            "detail": {"tokenfile_train": {"tokens_per_sec": 5.0}}}
-    cand = {"value": 100.0}
-    res = diff_bench(base, cand)
-    assert res["regressions"] == []
-    assert res["missing"] == ["detail.tokenfile_train.tokens_per_sec"]
 
 
 # ---------------------------------------------------------------------------

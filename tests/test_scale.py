@@ -7,8 +7,7 @@ nested-phase disjointness, dispatch subtraction), the journal observer,
 the histogram quantile helper, and the coord.slow-tick fault site. The
 acceptance drill runs a REAL coordinator against 256 beat-only virtual
 tasks — real RPC frames, real journal records — and asserts the
-span/phase invariants at width in tier-1 time. The BENCH_SCALE fixtures
-prove `tony-tpu bench diff` gates the scale family.
+span/phase invariants at width in tier-1 time.
 """
 
 import json
@@ -27,12 +26,12 @@ from tony_tpu.coordinator.coordphases import (CoordPhases,
                                               histogram_quantile)
 from tony_tpu.coordinator.journal import SessionJournal
 from tony_tpu.coordinator.session import SessionStatus
-from tony_tpu.profiling import JOURNAL_BOUND, classify_coord, diff_bench
+from tony_tpu.profiling import JOURNAL_BOUND, classify_coord
 
 pytestmark = pytest.mark.faults
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-FIXTURES = os.path.join(REPO, "benchmarks", "fixtures")
+FIXTURES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "fixtures")
 
 
 @pytest.fixture(autouse=True)
@@ -307,6 +306,75 @@ def test_virtual_resize_at_width_completes(tmp_path):
 
 
 @pytest.mark.timeout_s(60)
+def test_virtual_migrate_at_width_completes(tmp_path):
+    """A live move at width through the real drain→park→relaunch→barrier
+    path: the operator's request is accepted, every member re-registers
+    on the destination under the op's mgen, and the gang is pinned there
+    with the session still RUNNING and no retry epoch burned."""
+    conf = _scale_conf(16, **{K.ELASTIC_ENABLED: True,
+                              K.ELASTIC_BARRIER_TIMEOUT_S: 45})
+    coord, runner = _run_coord(tmp_path, conf, "app_vmig")
+    try:
+        _wait(lambda: coord.elastic.established, 30, "established gang")
+        res = coord.migrate_application("slice-1")
+        assert res["ok"], res
+        _wait(lambda: not coord.elastic.resizing, 45, "migration to land")
+        assert coord.session.jobs["worker"].node_pool == "slice-1"
+        assert coord.session.jobs["worker"].instances == 16
+        assert coord.elastic.mgen == 2
+        assert coord.session.status == SessionStatus.RUNNING
+        assert coord._infra_retries_used == 0
+    finally:
+        coord.request_stop("drill complete")
+        runner.join(timeout=45)
+
+
+@pytest.mark.timeout_s(120)
+def test_fleet_daemon_drains_a_tenant_mix_of_virtual_gangs(tmp_path):
+    """A live fleet daemon spawning real `tony-tpu submit` clients on
+    virtual executors drains a small tenant mix (a quota-capped tenant,
+    priorities, sizes 1–4 on a 2×4 pool): every job ends FINISHED, and
+    the goodput ledger's rollup of the run sums to the held
+    chip-seconds."""
+    from tony_tpu.fleet.daemon import FleetDaemon
+
+    job_conf = {"tony.worker.command": "virtual",
+                K.SCALE_VIRTUAL_EXECUTORS: "true",
+                K.SCALE_VIRTUAL_RUN_S: "0.5",
+                K.TASK_HEARTBEAT_INTERVAL_MS: "300",
+                K.COORDINATOR_MONITOR_INTERVAL_MS: "100",
+                K.DIAGNOSIS_ENABLED: "false"}
+    daemon = FleetDaemon(str(tmp_path / "fleet"), slices=2,
+                         hosts_per_slice=4, quotas="capped=2",
+                         tick_s=0.2, ledger_interval_s=1.0)
+    runner = threading.Thread(target=daemon.run, daemon=True)
+    runner.start()
+    try:
+        mix = [("alpha", 4, 2), ("bravo", 3, 1), ("alpha", 2, 0),
+               ("capped", 2, 0), ("capped", 1, 0), ("bravo", 1, 2)]
+        for tenant, hosts, priority in mix:
+            assert daemon.submit(tenant, hosts, priority=priority,
+                                 conf=dict(job_conf))["ok"]
+
+        def drained():
+            rows = daemon.status().get("jobs", [])
+            return len(rows) == len(mix) and all(
+                r["state"] in ("FINISHED", "FAILED", "CANCELLED")
+                for r in rows) and rows
+        rows = _wait(drained, 100, "the mix to drain")
+        assert [r["state"] for r in rows] == ["FINISHED"] * len(mix), rows
+        fleet = daemon.status()["ledger"]["fleet"]
+        assert fleet["held_chip_s"] > 0
+        assert 0 < fleet["goodput_fraction"] <= 1
+        assert sum(fleet["phase_chip_s"].values()) == pytest.approx(
+            fleet["held_chip_s"], rel=0.05)
+    finally:
+        daemon.request_stop()
+        runner.join(timeout=45)
+    assert not runner.is_alive(), "fleet daemon did not stop"
+
+
+@pytest.mark.timeout_s(60)
 def test_coord_slow_tick_shows_in_tick_accounting(tmp_path):
     """An injected 50ms/tick control-plane stall must surface in the
     self-observation tick numbers (the incident shape `top`'s coord row
@@ -327,50 +395,14 @@ def test_coord_slow_tick_shows_in_tick_accounting(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# BENCH_SCALE regression gate (fixtures are the contract, like PR 9's)
+# The coordinator classifier on fractions a 512-wide gang produced
 # ---------------------------------------------------------------------------
-def test_bench_scale_fixtures_gate_the_family():
-    base = json.load(open(os.path.join(FIXTURES,
-                                       "bench_scale_base.json")))
-    bad = json.load(open(os.path.join(FIXTURES,
-                                      "bench_scale_regressed.json")))
-    res_self = diff_bench(base, base)
-    assert res_self["regressions"] == [] and res_self["compared"] > 10
-    res_bad = diff_bench(base, bad)
-    flagged = {r["metric"] for r in res_bad["regressions"]}
-    assert "detail.w512.rendezvous_s" in flagged
-    assert "detail.w512.beats_per_sec" in flagged
-    assert "detail.w512.tick_duration_s" in flagged
-    assert "detail.w512.journal_records_per_sec" in flagged
-    assert "detail.w512.fsync_stall_fraction" in flagged
-    assert "detail.w512.resize_latency_s" in flagged
-    # config echoes (tasks, hb_interval_ms) are never compared
-    assert not any(m.endswith((".tasks", ".hb_interval_ms"))
-                   for m in flagged)
-
-
-def test_bench_scale_r01_artifact_shape():
-    """BENCH_SCALE_r01.json is the family's first recorded point: ≥3
-    widths including ≥512 virtual tasks, each carrying the four
-    acceptance metrics, phases summing to wall within 5%."""
-    doc = json.load(open(os.path.join(REPO, "BENCH_SCALE_r01.json")))
-    widths = [v for v in doc["detail"].values()
-              if isinstance(v, dict) and "tasks" in v]
-    assert len(widths) >= 3
-    assert any(p["tasks"] >= 512 for p in widths)
-    for p in widths:
-        for key in ("rendezvous_s", "beats_per_sec", "tick_duration_s",
-                    "journal_records_per_sec"):
-            assert key in p, f"width point missing {key}"
-        assert abs(p["phase_sum_ratio"] - 1.0) < 0.05
-
-
 def test_classify_coord_on_real_bench_fractions():
-    """The w512 point of the recorded bench classifies JOURNAL_BOUND —
-    fsync-per-record is the first loop to fall over, exactly where the
-    group-commit restructure (ROADMAP item 5) aims."""
-    doc = json.load(open(os.path.join(REPO, "BENCH_SCALE_r01.json")))
-    w512 = doc["detail"]["w512"]
+    """Phase fractions recorded from a 512-wide virtual gang classify
+    JOURNAL_BOUND — fsync-per-record is the first loop to fall over,
+    which is where a group commit (ROADMAP D4) would aim."""
+    with open(os.path.join(FIXTURES, "coord_phases_w512.json")) as f:
+        w512 = json.load(f)
     v = classify_coord(w512["coord_phases"])
     assert v["category"] == w512["verdict"] == JOURNAL_BOUND
     assert any("journal_fsync" in e for e in v["evidence"])

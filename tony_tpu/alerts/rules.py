@@ -609,8 +609,8 @@ def default_job_pack(conf: Any = None) -> List[Rule]:
              threshold=_f(conf, K.ALERTS_FSYNC_P99_S, 0.05),
              for_s=for_s * 3, severity=SEV_WARN,
              summary="write-ahead journal fsync p99 breached the "
-                     "JOURNAL_BOUND budget (BENCH_SCALE_r01 measured "
-                     "63ms at 512 wide — ROADMAP item 3 by numbers)"),
+                     "JOURNAL_BOUND budget (a 512-wide gang on one "
+                     "CPU box showed 63ms)"),
         Slo(name="step-time-slo",
             series="tony_task_steps_per_sec", op="<",
             threshold=_f(conf, K.ALERTS_MIN_STEPS_PER_SEC, 0.0),

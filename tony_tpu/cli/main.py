@@ -407,20 +407,6 @@ def _cmd_profile(args: argparse.Namespace) -> int:
         rpc.close()
 
 
-def _cmd_bench(args: argparse.Namespace) -> int:
-    """`tony-tpu bench diff <base.json> <candidate.json>` — the bench
-    regression gate (tony_tpu/profiling/benchdiff.py): nonzero exit when
-    the candidate regresses any comparable metric (headline throughput,
-    cold-start phases, step phases) past the tolerance."""
-    from tony_tpu.profiling import benchdiff
-
-    argv = [args.base, args.candidate, "--tolerance",
-            str(args.tolerance)]
-    if args.json:
-        argv.append("--json")
-    return benchdiff.main(argv)
-
-
 _SPARK_BLOCKS = "▁▂▃▄▅▆▇█"
 
 
@@ -1870,19 +1856,6 @@ def build_parser() -> argparse.ArgumentParser:
     pf.add_argument("--interval", type=float, default=1.0,
                     help="status poll cadence in seconds")
     pf.set_defaults(fn=_cmd_profile)
-
-    bn = sub.add_parser(
-        "bench",
-        help="bench utilities: `bench diff <base.json> <candidate.json>` "
-             "compares headline + per-phase numbers with a tolerance and "
-             "exits nonzero on regression (the BENCH_r* gate)")
-    bn_sub = bn.add_subparsers(dest="bench_cmd", required=True)
-    bd = bn_sub.add_parser("diff", help="compare two bench jsons")
-    bd.add_argument("base")
-    bd.add_argument("candidate")
-    bd.add_argument("--tolerance", type=float, default=0.10)
-    bd.add_argument("--json", action="store_true")
-    bd.set_defaults(fn=_cmd_bench)
 
     tr = sub.add_parser(
         "trace",

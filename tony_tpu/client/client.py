@@ -70,9 +70,9 @@ class TonyTpuClient:
         self._last_task_infos: List[dict] = []
         # Distributed tracing: the client is where the job's ONE trace
         # starts — the submit span is the root every coordinator/executor
-        # span hangs under, and the anchor bench.py measures
-        # submit→first-step from. Buffered locally, shipped over
-        # trace.push once the coordinator answers its first report.
+        # span hangs under, and the anchor submit→first-step is measured
+        # from. Buffered locally, shipped over trace.push once the
+        # coordinator answers its first report.
         # A FLEET-granted job adopts the fleet's trace id instead of
         # minting one (the daemon stamps tony.internal.fleet-trace-id
         # on the grant's conf), so `tony-tpu trace --fleet` renders the

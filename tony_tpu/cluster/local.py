@@ -276,10 +276,10 @@ class VirtualExecutorBackend(Backend):
     beat-only in-process virtual executor (executor/virtual.py) — real
     registration/heartbeat/result RPC traffic against the coordinator,
     no subprocess, no user command — so the control plane is exercised
-    at 128–1024 tasks per box (``bench.py --suite scale``,
-    tests/test_scale.py). One shared :class:`VirtualGang` pump serves
-    every task; its coordinates come from the first launch spec's env
-    (the same identity contract a real executor reads)."""
+    at 128–1024 tasks per box (tests/test_scale.py). One shared
+    :class:`VirtualGang` pump serves every task; its coordinates come
+    from the first launch spec's env (the same identity contract a real
+    executor reads)."""
 
     def __init__(self, workdir: str, hb_interval_s: float = 1.0,
                  steps_per_s: float = 5.0, run_s: float = 0.0,

@@ -429,9 +429,9 @@ ALERTS_DATA_WAIT_FRACTION = _key(
 ALERTS_FSYNC_P99_S = _key(
     "tony.alerts.fsync-p99-s", 0.05, float,
     "journal-fsync-p99 rule threshold (seconds): warn when the "
-    "windowed p99 of tony_journal_fsync_seconds breaches it. Default "
-    "aims ROADMAP item 3 by numbers — BENCH_SCALE_r01 measured p99 "
-    "63ms at 512 virtual tasks, the JOURNAL_BOUND regime.")
+    "windowed p99 of tony_journal_fsync_seconds breaches it. The "
+    "default sits just under the p99 of 63ms that a coordinator of 512 "
+    "virtual tasks showed on one CPU box, the JOURNAL_BOUND regime.")
 ALERTS_MIN_STEPS_PER_SEC = _key(
     "tony.alerts.min-steps-per-sec", 0.0, float,
     "step-time-slo floor: a task sample below this steps/s rate is "
@@ -483,7 +483,7 @@ COORD_PHASE_RING_TICKS = _key(
     "tick duration and phase fractions are computed over this many "
     "monitor ticks. Bounded by design, like the step-phase ring.")
 
-# --- width harness (cluster/local.py virtual mode, bench --suite scale) ---
+# --- width harness (cluster/local.py virtual mode, tests/test_scale.py) ---
 SCALE_VIRTUAL_EXECUTORS = _key(
     "tony.scale.virtual-executors", False, bool,
     "LocalSim width harness: the local backend launches each task as an "
@@ -491,7 +491,7 @@ SCALE_VIRTUAL_EXECUTORS = _key(
     "of a subprocess — real RPC frames, real journal records, real "
     "heartbeat/beacon traffic, NO user process — so rendezvous, "
     "heartbeat and resize paths are exercised at 128–1024 tasks per box "
-    "in CI-sized time (bench.py --suite scale). Never for real "
+    "in CI-sized time (tests/test_scale.py). Never for real "
     "training: the tasks only pretend to step.")
 SCALE_VIRTUAL_STEPS_PER_S = _key(
     "tony.scale.virtual-steps-per-s", 5.0, float,

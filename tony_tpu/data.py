@@ -122,8 +122,8 @@ class ShardedBatchIterator:
         # by __next__ at Thread construction: a worker that outlives a
         # close()+restart (join timeout) must keep talking to ITS queue,
         # never the successor's — and must not read or mutate the shared
-        # step counter either (ADVICE r5: a late `self._step += 1` from
-        # an abandoned worker made the restarted one silently skip a
+        # step counter either (a late `self._step += 1` from an
+        # abandoned worker made the restarted one silently skip a
         # batch). Snapshotting inside the loop body was not enough: an
         # abandoned worker that had not yet been SCHEDULED when the
         # restart happened would snapshot the successor's state and feed

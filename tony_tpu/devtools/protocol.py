@@ -619,7 +619,7 @@ def _check_metrics_registry(linter, srcs) -> None:
             linter._emit(
                 "metrics-registry", src.rel, node.lineno,
                 f"series {name!r} is not registered in "
-                f"tony_tpu.metrics.SERIES — the docs/portal/benchdiff "
+                f"tony_tpu.metrics.SERIES — the docs/portal "
                 f"surfaces can't see it (register it, with its help "
                 f"line, or fix the typo)", src)
     series_line = 1

@@ -502,8 +502,8 @@ class TaskExecutor:
         """Record the submit→first-step tail: a complete span from user-
         process start to the FIRST telemetry step, end-anchored on the
         user process's own wall timestamp (telemetry first_step_done_ts)
-        rather than this poll's arrival time. The span bench.py measures
-        its submit_to_first_step_s from."""
+        rather than this poll's arrival time. The span that ends
+        ``tracing.cold_start_breakdown``'s submit→first-step window."""
         if self._first_step_emitted or steps < 1 \
                 or not self.tracer.enabled or not self._user_start_us:
             return
@@ -758,7 +758,7 @@ class TaskExecutor:
             log.warning("TEST hook: skipping registration; sleeping")
             # Outlive the coordinator's registration timeout but stay
             # bounded: an unbounded multiple of a production-sized timeout
-            # left zombie sleepers wedging suite teardown (VERDICT r3 #7).
+            # left zombie sleepers wedging suite teardown.
             time.sleep(min(timeout_s * 4, 120))
             return None
 
@@ -1091,9 +1091,8 @@ class TaskExecutor:
                     # The group is reaped (execute_shell's finally); drop
                     # the pgid file so later backend kills can't TERM a
                     # recycled group id while the executor lingers
-                    # through reporting/teardown (ADVICE r4: same-user
-                    # pgid reuse isn't caught by the PermissionError
-                    # guard).
+                    # through reporting/teardown (same-user pgid reuse
+                    # isn't caught by the PermissionError guard).
                     try:
                         os.unlink(os.path.join(os.getcwd(),
                                                constants.USER_PGID_FILE))

@@ -44,10 +44,8 @@ fleet-wide and per-tenant headline exported as
 
 Stdlib-only and side-effect-free: the daemon folds it under the
 ``fleet.ledger`` fault site (a fold failure degrades the fleet to
-counters-only, never blocks a tick), `tony-tpu check` re-folds it
-offline to enforce sum-to-wall on every drill artifact, and
-``bench.py --suite fleet`` records the rollup as the BENCH_FLEET
-headline.
+counters-only, never blocks a tick) and `tony-tpu check` re-folds it
+offline to enforce sum-to-wall on every drill artifact.
 """
 
 from __future__ import annotations

@@ -35,8 +35,8 @@ no wall clock, no randomness — so the same journal plus the same
 overrides produce a byte-identical report (test-enforced). The
 simulator can also RECORD a run as a real fleet journal
 (:class:`JournalRecorder`) — parity-clean by construction — which is
-how the checked-in ``tests/fixtures/whatif_mix`` 50-job fixture and the
-BENCH_WHATIF suite are generated.
+how the checked-in ``tests/fixtures/whatif_mix`` 50-job fixture is
+generated.
 
 Known limits (documented in docs/operations.md "Capacity planning and
 what-if"): observed durations were measured UNDER the recorded
@@ -82,8 +82,8 @@ HOLD_METRIC = {
     fpolicy.PRIORITY_HELD: "priority_hold_s",
 }
 
-#: metric direction for the diff report (mirrors profiling/benchdiff.py
-#: suffix conventions; used to mark each delta improves/regresses).
+#: metric direction for the diff report (used to mark each delta
+#: improves/regresses).
 LOWER_BETTER = (
     "queue_wait_p50_s", "queue_wait_p99_s", "queue_wait_mean_s",
     "makespan_s", "preemptions", "preemptions_per_job", "migrations",
@@ -1201,8 +1201,7 @@ def parity_replay(tl: ftimeline.FleetTimeline) -> Dict[str, Any]:
 def diff_metrics(base: Dict[str, Any],
                  counter: Dict[str, Any]) -> Dict[str, Any]:
     """Per-metric delta with an improves/regresses verdict from the
-    metric's direction (same convention profiling/benchdiff.py gates
-    on)."""
+    metric's direction (``LOWER_BETTER`` / ``HIGHER_BETTER``)."""
     out: Dict[str, Any] = {}
     for key in sorted(set(base) | set(counter)):
         b, c = base.get(key), counter.get(key)

@@ -50,8 +50,8 @@ DEFAULT_LATENCY_BUCKETS_S = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
 #: in one place. tonylint's ``metrics-registry`` rule enforces it both
 #: ways (an exported name must be registered; a registered name must
 #: have an exporting call site), and ``tony-tpu check`` verifies every
-#: family in a job's ``metrics.prom`` against it — so the docs, the
-#: portal and benchdiff can never drift against what actually exports.
+#: family in a job's ``metrics.prom`` against it — so the docs and the
+#: portal can never drift against what actually exports.
 SERIES: Dict[str, str] = {
     # -- per-task utilization (heartbeat-beacon-fed gauges) --------------
     "tony_task_steps_completed": "step counter from the progress beacon",

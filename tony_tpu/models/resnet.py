@@ -1,7 +1,7 @@
 """ResNet (v1.5 bottleneck) — the allreduce-DP parity workload.
 
-Reference parity target: "HorovodRuntime ResNet-50 ImageNet (NCCL allreduce
-→ ICI allreduce)" (BASELINE.json configs). TPU-first choices: NHWC layout
+The data-parallel image workload (the reference's Horovod ResNet-50 job,
+with the gradient allreduce over ICI). TPU-first choices: NHWC layout
 (XLA's native conv layout on TPU), bf16 compute, GroupNorm instead of
 BatchNorm — no cross-replica batch-stat sync, so pure-DP scaling needs only
 the gradient psum and the step stays a single fused XLA program (BatchNorm

@@ -1,8 +1,7 @@
 """Decoder-only transformer (llama-family architecture), TPU-first.
 
-The flagship model for the Llama-3-8B-on-TPU target (BASELINE.json
-"new JAXRuntime: Llama-3-8B multi-host SPMD"). Design choices map straight
-onto TPU hardware:
+The model every cell of the benchmark and the Llama-3-8B example build.
+Design choices map straight onto TPU hardware:
 
 - every weight carries logical axes (``embed``/``mlp``/``heads``/``vocab``)
   so `tony_tpu.parallel` can lay it out on any dp/fsdp/tp/sp mesh;
@@ -72,12 +71,6 @@ class TransformerConfig:
     # ulysses; ring and xla have nothing tagged and keep nothing).
     # "nothing_saveable" keeps nothing: every block recomputed whole.
     remat_policy: Optional[str] = None
-    # Layer-granular selective remat (layers are a Python loop, so the
-    # choice is per-layer): with remat on and N >= 2, every Nth block
-    # runs UN-remat'd — its activations stay live (1/N of the no-remat
-    # footprint) and its recompute disappears (1/N of the remat FLOPs
-    # tax). 0/1 = remat every block (the default, max memory savings).
-    remat_skip_every: int = 0
     # Flash kernel tile sizes (see ops/attention.py block sweep notes).
     attn_block_q: int = 1024
     attn_block_k: int = 1024
@@ -349,11 +342,7 @@ class Transformer(nn.Module):
             block = nn.remat(Block, prevent_cse=True,
                              policy=remat_policy_of(cfg))
         for i in range(cfg.n_layers):
-            blk = block
-            if (cfg.remat and cfg.remat_skip_every >= 2
-                    and i % cfg.remat_skip_every == 0):
-                blk = Block     # selective: this layer's activations live
-            x = blk(cfg, cfg.layer(i), name=f"layer_{i}")(x, positions)
+            x = block(cfg, cfg.layer(i), name=f"layer_{i}")(x, positions)
         x = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")(x)
         if return_hidden:
             return x

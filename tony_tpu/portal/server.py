@@ -431,7 +431,7 @@ class PortalServer:
         priority=job=10&set=k=v&sweep=k=a,b,c``. Always recomputed —
         the journal grows while the daemon lives, and each query is a
         different experiment; the 50-job scale this targets re-folds in
-        well under a second (BENCH_WHATIF budget: 5 s)."""
+        well under a second."""
         if not self.fleet_dir:
             return self._send(req, 404, "text/plain",
                               b"no fleet dir configured or discovered")

@@ -13,14 +13,9 @@ act on:
   ``tony-tpu diagnose`` as a perf advisory;
 - ``verdict.build_perf_report`` — the ``<job_dir>/perf.json`` artifact
   the coordinator writes at finish (phase totals sum exactly to the
-  attributed wall);
-- ``benchdiff`` — the regression gate over BENCH jsons
-  (``tony-tpu bench diff`` / ``bench.py --against``), so a cold-start or
-  per-phase regression is caught at bench time, not at the next manual
-  re-anchor.
+  attributed wall).
 """
 
-from tony_tpu.profiling.benchdiff import diff_bench  # noqa: F401
 from tony_tpu.profiling.verdict import (COMPUTE_BOUND,  # noqa: F401
                                         CKPT_BOUND, COMMS_BOUND,
                                         COORD_HEALTHY, COORD_VERDICTS,

@@ -504,8 +504,8 @@ class RpcClient:
                 "authenticated client requires v3 (dual-nonce MACs)")
         # Contribute our own freshness: the combined nonce goes into every
         # MAC both ways, so recorded responses from an old connection can
-        # never satisfy this one (ADVICE r4: the hello alone gave the
-        # client no replay protection).
+        # never satisfy this one (the hello alone gave the client no
+        # replay protection).
         client_nonce = os.urandom(16)
         return (sock, hello["nonce"] + client_nonce, client_nonce,
                 int(hello.get("g", 0) or 0))

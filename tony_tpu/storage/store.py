@@ -501,7 +501,7 @@ class GcsStore(Store):
         if not key:
             # gs://bucket[/]: there is no object with an empty name (the
             # API would 400 on '…/o/'); answer via the prefix listing like
-            # the other stores do (ADVICE r4).
+            # the other stores do.
             return self.isdir(url)
         try:
             self._request("GET", self._obj_url(bucket, key))

@@ -38,7 +38,7 @@ _started = threading.Lock()
 _thread: Optional[threading.Thread] = None
 
 # ---------------------------------------------------------------------------
-# Step-time utilization (VERDICT r3 #8; reference samples GPU duty cycle via
+# Step-time utilization (the reference samples GPU duty cycle via
 # nvidia-smi, TaskMonitor.java:116-170 + GpuDiscoverer.java:88-131 — on TPU
 # there is no device-side util counter to shell out to, so the signal is
 # derived from the training loop itself: wrap each step in
@@ -50,8 +50,8 @@ _step_lock = threading.Lock()
 _steps = {"count": 0, "busy_s": 0.0, "flops": 0.0, "tokens": 0.0,
           "first_start": 0.0, "last_end": 0.0, "first_end_wall": 0.0}
 
-# Peak dense bf16 matmul FLOP/s of one device — THE table (bench.py and
-# chip_smoke.py read it from here). Keys are the exact ``device_kind``
+# Peak dense bf16 matmul FLOP/s of one device — THE table (chip_smoke.py
+# reads it from here). Keys are the exact ``device_kind``
 # strings jax reports, as libtpu 0.0.34 names them (read off
 # ``jax.experimental.topologies`` for v4 / v5e / v5p / v6e); values are
 # Google Cloud's per-chip figures ("System architecture" pages for TPU v4,

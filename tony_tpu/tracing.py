@@ -43,8 +43,8 @@ Record grammar (one JSON object per line):
 
 ``to_trace_events`` exports the log as Chrome/Perfetto ``trace_events``
 JSON (``tony-tpu trace <app>``, portal ``/trace/<app>`` view). Unmatched
-B records are reported as unclosed — the golden e2e test and bench.py
-treat a nonzero count as a tracing regression.
+B records are reported as unclosed — the golden e2e test treats a
+nonzero count as a tracing regression.
 """
 
 from __future__ import annotations
