@@ -18,6 +18,7 @@ Small shapes: the pair costs a few seconds.
 
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -257,5 +258,18 @@ def test_expert_layer_compiles_at_published_widths(v5e_devices,
     with jax.set_mesh(mesh):
         hlo = jax.jit(jax.grad(loss, argnums=(0, 2))).lower(
             params, x, x).compile().as_text()
-    names = {c["name"].split(".")[0] for c in kernel_operands(hlo)}
+    calls = kernel_operands(hlo)
+    names = {c["name"].split(".")[0] for c in calls}
     assert names == {"moe_gmm", "moe_tgmm"}, names
+    # The two chunks' weight gradients are summed inside moe_tgmm: the loop
+    # over chunks holds three calls, each handed its running sum as a fifth
+    # operand whose buffer the result takes (XLA honours the alias: nothing
+    # copies a leaf), and no fusion adds two leaves.
+    sums = [c for c in calls if c["name"].startswith("moe_tgmm")]
+    assert [len(c["shapes"]) for c in sums] == [5] * 3, sums
+    leaf = r"f32\[16,(?:2560,768|768,2560)\]"
+    assert len(re.findall(
+        rf"= {leaf}\S* custom-call\(.*output_to_operand_aliasing", hlo)) == 3
+    assert not re.findall(rf"= {leaf}\S* copy\(", hlo)
+    assert not re.findall(
+        rf"= {leaf}\S* fusion\({leaf}\S* %[\w.\-]+, {leaf}", hlo)

@@ -2,17 +2,21 @@
 expert parallelism on the virtual 8-device CPU mesh (SURVEY.md §2.3 — EP is
 a first-class requirement, no reference analogue)."""
 
+import dataclasses
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax.extend import core as jax_core
 
 from tony_tpu.models import moe
 from tony_tpu.models.moe import (ExpertLayer, ExpertSpec, MoEConfig,
                                  _buffer_rows, _combine, _gmm_call, _layout,
-                                 _tgmm_call, moe_counters, routing_counters)
+                                 _tgmm_call, moe_counters, routed_experts,
+                                 routing_counters)
 from tony_tpu.models.transformer import (Transformer, TransformerConfig,
                                          causal_lm_loss)
 from tony_tpu.parallel import MeshSpec, build_mesh, init_sharded_state
@@ -293,22 +297,131 @@ def test_grouped_matmul_kernel(transpose):
             out[r], lhs[r] @ (m.T if transpose else m), atol=1e-5)
 
 
-def test_grouped_weight_gradient_kernel():
+@pytest.mark.parametrize("running_sum", [False, True],
+                         ids=["from-zero", "from-a-running-sum"])
+def test_grouped_weight_gradient_kernel(running_sum):
     """moe_tgmm: an expert's block is the sum over its own rows, zero for an
-    expert without rows, whatever lies in the tiles past the live ones."""
+    expert without rows, whatever lies in the tiles past the live ones. Handed
+    a running sum it returns that sum with the same added, and an expert
+    without rows keeps its sum to the bit."""
     idx = jnp.array([[0], [2], [2], [0], [2], [2], [2], [2], [2], [2], [2]],
                     jnp.int32)
-    _, _, _, live, te, na, _ = _layout(idx, 0, 3, 4, 24)
-    ks = jax.random.split(jax.random.key(0), 2)
+    _, _, _, live, te, na, sizes = _layout(idx, 0, 3, 4, 24)
+    assert sizes.tolist() == [2, 0, 9] and int(na[0]) == 5 < te.shape[0]
+    ks = jax.random.split(jax.random.key(0), 3)
     lhs = jax.random.normal(ks[0], (24, 16))
     rhs = jnp.where(live[:, None], jax.random.normal(ks[1], (24, 8)), 0.0)
     rhs = rhs.at[20:].set(jnp.nan)        # unwritten rows: never read
-    out = _tgmm_call(lhs, rhs, te, na, tile_rows=4, count=3)
+    acc = jax.random.normal(ks[2], (3, 16, 8)) if running_sum else None
+    out = _tgmm_call(lhs, rhs, te, na, tile_rows=4, count=3, acc=acc)
     row_expert = np.repeat(np.asarray(te), 4)
     for e in range(3):
         rows = np.flatnonzero(np.asarray(live) & (row_expert == e))
-        np.testing.assert_allclose(out[e], lhs[rows].T @ rhs[rows],
-                                   atol=1e-5)
+        want = lhs[rows].T @ rhs[rows] + (acc[e] if running_sum else 0.0)
+        np.testing.assert_allclose(out[e], want, atol=1e-5)
+    if running_sum:
+        alone = _tgmm_call(lhs, rhs, te, na, tile_rows=4, count=3)
+        np.testing.assert_allclose(out, acc + alone, atol=1e-6, rtol=1e-6)
+        np.testing.assert_array_equal(out[1], acc[1])
+    else:
+        assert not np.asarray(out[1]).any()
+
+
+def _routed(spec, int8=False, tokens=32, seed=3):
+    """``routed_experts`` over hand-made routing, as a function of all that
+    takes a gradient: the tokens, the routing weights, the three leaves."""
+    first, count = spec.held
+    ks = jax.random.split(jax.random.key(seed), 6)
+    x = jax.random.normal(ks[0], (tokens, D))
+    idx = jax.lax.top_k(jax.random.normal(ks[1], (tokens, spec.n_experts)),
+                        spec.top_k)[1]
+    weights = jax.nn.softmax(jax.random.normal(ks[2], (tokens, spec.top_k)))
+    leaves = tuple(jax.random.normal(k, shape) / 4 for k, shape in zip(
+        ks[3:], [(count, D, spec.width)] * 2 + [(count, spec.width, D)]))
+
+    def through(spec):
+        return lambda x, weights, *leaves: routed_experts(
+            spec, x, idx, weights, *leaves, first, jnp.float32, int8=int8)
+
+    def dense(x, weights, gate, up, down):
+        out = 0
+        for e in range(count):
+            we = jnp.sum(jnp.where(idx == first + e, weights, 0), -1)
+            out = out + we[:, None] * (
+                (jax.nn.relu(x @ gate[e]) * (x @ up[e])) @ down[e])
+        return out
+
+    return through, dense, (x, weights, *leaves)
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["plain", "int8-forward"])
+def test_four_chunks_give_the_gradient_one_chunk_gives(int8):
+    """A quarter of the experts held, the tokens in four chunks of 8 or in one
+    of 32: the result and the gradients of the tokens, the routing weights
+    and the three expert leaves are the same (the leaves' summed over the
+    chunks inside ``moe_tgmm``, a running sum carried from chunk to chunk),
+    and, without quantization, the dense masked sum's."""
+    spec = _spec(held=(2, 2), top_k=2, chunk_tokens=8)
+    through, dense, args = _routed(spec, int8)
+
+    def value_and_grads(f):
+        return jax.value_and_grad(lambda *a: jnp.sum(jnp.sin(f(*a))),
+                                  argnums=tuple(range(5)))(*args)
+
+    with jax.default_matmul_precision("highest"):
+        four = value_and_grads(through(spec))
+        others = [value_and_grads(through(
+            dataclasses.replace(spec, chunk_tokens=8192)))]
+        if not int8:
+            others.append(value_and_grads(dense))
+    assert all(np.asarray(g).any() for g in four[1])
+    for other in others:
+        jax.tree.map(lambda a, b: np.testing.assert_allclose(
+            a, b, atol=2e-5, rtol=2e-5), four, other)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for inner in (value if isinstance(value, (tuple, list))
+                          else (value,)):
+                inner = getattr(inner, "jaxpr", inner)      # a closed one
+                if isinstance(inner, jax_core.Jaxpr):
+                    yield from _eqns(inner)
+
+
+def test_the_chunk_loop_s_backward_sums_inside_the_kernel():
+    """The backward of a layer of four chunks is one loop over the chunks
+    whose carry is the three leaves' running sums: each trip hands each of
+    its three ``moe_tgmm`` calls its sum (the fifth operand after the two
+    prefetched tables and the two row buffers) in the result's own buffer,
+    and no ``add`` of the loop yields anything of a leaf's shape."""
+    spec = _spec(held=(2, 2), top_k=2, chunk_tokens=8)
+    through, _, args = _routed(spec)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(through(spec)(*a)), argnums=(2, 3, 4)))(*args)
+    leaf_shapes = {a.shape for a in args[2:]}
+    loops = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "scan"
+             and {v.aval.shape for v in e.outvars[:e.params["num_carry"]]}
+             == leaf_shapes]
+    assert len(loops) == 1, [e.params["num_carry"] for e in loops]
+    (loop,) = loops
+    assert loop.params["length"] == 4 and loop.params["num_carry"] == 3
+    body = list(_eqns(loop.params["jaxpr"].jaxpr))
+
+    def sums(eqns):     # how each moe_tgmm call aliases its operands
+        return [e.params["input_output_aliases"] for e in eqns
+                if e.primitive.name == "pallas_call"
+                and e.params["name"] == "moe_tgmm"]
+
+    assert sums(body) == [((4, 0),)] * 3, sums(body)
+    # The forward's loop holds no such call, and nothing outside this one.
+    assert sums(_eqns(jaxpr.jaxpr)) == sums(body)
+    adds = [e for e in body if e.primitive.name in ("add", "add_any")
+            and e.outvars[0].aval.shape in leaf_shapes]
+    assert not adds, adds
 
 
 def test_int8_experts_quantize_the_forward_alone():

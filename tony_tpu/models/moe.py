@@ -30,7 +30,12 @@ the shares of all the chips add up to the whole layer
   tiles of an expert accumulated into its matrix). The backward never
   densifies: dispatch and combine are gathers in both directions.
 - Tokens go through in chunks (``chunk_tokens``), each recomputed in the
-  backward pass, so the row buffers are a chunk's and not the batch's.
+  backward pass, so the row buffers are a chunk's and not the batch's. The
+  loop over chunks is one ``custom_vjp`` (``_chunks``) whose backward is
+  hand-written: a scan over the chunks that carries the three expert
+  leaves' float32 gradient sums, each chunk's ``moe_tgmm`` calls starting
+  their result from the sum so far, in its buffer. The sum over chunks is
+  taken inside the kernel; XLA adds no leaf to a leaf.
 - **Expert parallelism** (an ``ep`` mesh axis > 1): the same body runs per
   shard on all of its group's rows (rows are split over the batch axes and
   never over ``ep``, so every ``ep`` shard has them: gathered), each shard
@@ -150,11 +155,13 @@ def _gmm_kernel(tile_expert, n_active, lhs_ref, rhs_ref, *rest,
         out_ref[...] = out.astype(out_ref.dtype)
 
 
-def _tgmm_kernel(tile_expert, n_active, lhs_ref, rhs_ref, out_ref):
+def _tgmm_kernel(tile_expert, n_active, lhs_ref, rhs_ref, *rest):
+    *acc_ref, out_ref = rest    # a running sum comes as one more block
     i = pl.program_id(1)
     # Tiles of one expert are consecutive and every expert has at least
     # one, so its [K, tn] block stays in VMEM from its first tile to its
-    # last and each block is zeroed exactly once.
+    # last and each block is started exactly once: from the first product,
+    # or from the running sum's block and the first product.
     new_expert = jnp.logical_or(
         i == 0, tile_expert[i] != tile_expert[jnp.maximum(i - 1, 0)])
 
@@ -166,7 +173,7 @@ def _tgmm_kernel(tile_expert, n_active, lhs_ref, rhs_ref, out_ref):
 
         @pl.when(new_expert)
         def _first():
-            out_ref[...] = part
+            out_ref[...] = acc_ref[0][...] + part if acc_ref else part
 
         @pl.when(jnp.logical_not(new_expert))
         def _more():
@@ -240,36 +247,47 @@ def _gmm_call(lhs, rhs, tile_expert, n_active, *, tile_rows: int,
 
 
 def _tgmm_call(lhs, rhs, tile_expert, n_active, *, tile_rows: int,
-               count: int):
-    """``out[e] = Σ_{tiles i of expert e} lhs[tile i]ᵀ @ rhs[tile i]`` in
-    float32, ``[count, K, N]``. Rows past an expert's own within its last
-    tile must be zero on one side (they are: a padded row's cotangent is
-    scaled by its zero weight)."""
+               count: int, acc=None):
+    """``out[e] = acc[e] + Σ_{tiles i of expert e} lhs[tile i]ᵀ @ rhs[tile
+    i]`` in float32, ``[count, K, N]``; ``acc`` is a running sum of that
+    shape, which the result takes the place of, or None for zero. Rows past
+    an expert's own within its last tile must be zero on one side (they
+    are: a padded row's cotangent is scaled by its zero weight), so an
+    expert without rows keeps its sum."""
     m, k = lhs.shape
     n = rhs.shape[1]
     tn = _col_tile(n, 512)
     tiles = m // tile_rows
     row = _live_row
+    # Every block of the result is visited, and read (where there is a sum
+    # to read) before it is written: the sum's buffer can be the result's.
+    out_spec = pl.BlockSpec(
+        (None, k, tn), lambda j, i, te, na: (te[row(i, na)], 0, j))
+    in_specs = [
+        pl.BlockSpec((tile_rows, k), lambda j, i, te, na: (row(i, na), 0)),
+        pl.BlockSpec((tile_rows, tn), lambda j, i, te, na: (row(i, na), j)),
+    ]
+    operands = [tile_expert, n_active, lhs, rhs]
+    aliases = {}
+    if acc is not None:
+        aliases = {len(operands): 0}    # the prefetched tables count
+        in_specs.append(out_spec)
+        operands.append(acc)
 
     return pl.pallas_call(
         _tgmm_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(n // tn, tiles),
-            in_specs=[
-                pl.BlockSpec((tile_rows, k),
-                             lambda j, i, te, na: (row(i, na), 0)),
-                pl.BlockSpec((tile_rows, tn),
-                             lambda j, i, te, na: (row(i, na), j)),
-            ],
-            out_specs=pl.BlockSpec(
-                (None, k, tn), lambda j, i, te, na: (te[row(i, na)], 0, j)),
+            in_specs=in_specs,
+            out_specs=out_spec,
         ),
         out_shape=jax.ShapeDtypeStruct((count, k, n), jnp.float32),
+        input_output_aliases=aliases,
         compiler_params=_params(),
         interpret=_interpret(),
         name="moe_tgmm",
-    )(tile_expert, n_active, lhs, rhs)
+    )(*operands)
 
 
 def _grouped_forward(lhs, w_lo, w_q, tile_expert, n_active, tile_rows):
@@ -282,30 +300,36 @@ def _grouped_forward(lhs, w_lo, w_q, tile_expert, n_active, tile_rows):
                      out_dtype=lhs.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def grouped_matmul(lhs, w, w_lo, w_q, tile_expert, n_active, tile_rows):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def grouped_matmul(lhs, w, w_lo, w_q, dw_so_far, tile_expert, n_active,
+                   tile_rows):
     """Rows ``lhs [M, K]`` times their experts' matrices ``[count, K, N]``.
-    ``w`` is the parameter, which takes the gradient (float32, summed over
-    chunks unrounded); ``w_lo`` its cast to the matmul dtype, made once a
-    layer outside the chunk loop, which the kernels read. ``w_q`` is None,
-    or ``w_lo`` in int8 with its scales (``quantize_symmetric`` over the
-    contraction): the forward product is then int8 by int8, a row's scale
-    taken here, and the gradients stay those of the unquantized product
-    (straight through, as ``ops/quant.py`` has it for a dense layer)."""
-    del w
+    ``w`` is the parameter, which takes the gradient (float32); ``w_lo`` its
+    cast to the matmul dtype, made once a layer outside the chunk loop,
+    which the kernels read. ``w_q`` is None, or ``w_lo`` in int8 with its
+    scales (``quantize_symmetric`` over the contraction): the forward
+    product is then int8 by int8, a row's scale taken here, and the
+    gradients stay those of the unquantized product (straight through, as
+    ``ops/quant.py`` has it for a dense layer). ``dw_so_far`` is None, or
+    the float32 sum of ``w``'s gradient over the chunks that went before:
+    what comes back as ``w``'s cotangent is then that sum with this call's
+    share added, unrounded, inside ``moe_tgmm`` (``_chunks_bwd`` carries it
+    from chunk to chunk)."""
+    del w, dw_so_far
     return _grouped_forward(lhs, w_lo, w_q, tile_expert, n_active, tile_rows)
 
 
-def _grouped_matmul_fwd(lhs, w, w_lo, w_q, tile_expert, n_active, tile_rows):
+def _grouped_matmul_fwd(lhs, w, w_lo, w_q, dw_so_far, tile_expert, n_active,
+                        tile_rows):
     del w
     out = _grouped_forward(lhs, w_lo, w_q, tile_expert, n_active, tile_rows)
-    return out, (lhs, w_lo, tile_expert, n_active)
+    return out, (lhs, w_lo, dw_so_far, tile_expert, n_active)
 
 
 def _grouped_matmul_bwd(tile_rows, res, dout):
-    lhs, w_lo, tile_expert, n_active = res
+    lhs, w_lo, dw_so_far, tile_expert, n_active = res
     dw = _tgmm_call(lhs, dout, tile_expert, n_active, tile_rows=tile_rows,
-                    count=w_lo.shape[0])
+                    count=w_lo.shape[0], acc=dw_so_far)
     # The weight gradient first: it is the last reader of ``lhs``, whose
     # buffer is then free before the input gradient's is made. Left to the
     # scheduler, gate's and up's ``xs`` outlives both of their input
@@ -313,7 +337,7 @@ def _grouped_matmul_bwd(tile_rows, res, dout):
     dw, dout = jax.lax.optimization_barrier((dw, dout))
     dlhs = _gmm_call(dout, w_lo, tile_expert, n_active, tile_rows=tile_rows,
                      transpose_rhs=True)
-    return dlhs, dw, None, None, None, None
+    return dlhs, dw, None, None, None, None, None
 
 
 grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
@@ -548,47 +572,88 @@ def _chunk_tokens(spec: ExpertSpec, tokens: int) -> int:
     return tokens if tokens % spec.chunk_tokens else spec.chunk_tokens
 
 
+def _one_chunk(spec: ExpertSpec, xc, ic, wc, ws, lo, q, dws_so_far, first):
+    """A chunk's tokens ``xc [chunk, D]`` through the experts held here;
+    ``ws``, ``lo``, ``q`` and ``dws_so_far`` are ``grouped_matmul``'s ``w``,
+    ``w_lo``, ``w_q`` and ``dw_so_far`` for gate, up and down."""
+    count = lo[0].shape[0]
+    rows = _buffer_rows(xc.shape[0], spec, count)
+    with jax.named_scope("tony.moe.dispatch"):
+        held, pos, row_pair, row_live, tile_expert, n_active, _ = \
+            _layout(ic, first, count, spec.tile_rows, rows)
+        xs = _dispatch(xc, row_pair // spec.top_k, pos, held)
+    with jax.named_scope("tony.moe.experts"):
+        gate, up, down = (
+            functools.partial(grouped_matmul, w=w, w_lo=w_lo, w_q=w_q,
+                              dw_so_far=dw, tile_expert=tile_expert,
+                              n_active=n_active, tile_rows=spec.tile_rows)
+            for w, w_lo, w_q, dw in zip(ws, lo, q, dws_so_far))
+        xs_gate, xs_up = _for_gate_and_up(xs, n_active, spec.tile_rows)
+        hidden = _gated(gate(xs_gate), up(xs_up), n_active, spec.tile_rows,
+                        spec.activation)
+        y = down(hidden)
+    with jax.named_scope("tony.moe.combine"):
+        return _combine(y, wc, pos, held, row_pair, row_live, n_active,
+                        spec.tile_rows)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _chunks(spec: ExpertSpec, int8: bool, x, idx, weights, ws, first):
+    """``_one_chunk`` over the leading dim of ``x [n, chunk, D]``, ``idx``
+    and ``weights [n, chunk, k]``, the three matrices ``ws`` cast to ``x``'s
+    dtype (and to int8 where ``int8`` says so) once for all chunks. The
+    backward is a loop of its own and not the transpose autodiff makes of
+    this one, which would add each chunk's float32 ``[count, K, N]`` weight
+    gradients to its carry as a pass of XLA's: three reads and writes of a
+    leaf a chunk that ``moe_tgmm`` can do on its way."""
+    return _chunks_fwd(spec, int8, x, idx, weights, ws, first)[0]
+
+
+def _chunks_fwd(spec, int8, x, idx, weights, ws, first):
+    lo = tuple(w.astype(x.dtype) for w in ws)
+    q = tuple(quantize_symmetric(w, INT8, axis=1) if int8 else None
+              for w in lo)
+    out = jax.lax.map(
+        lambda c: _one_chunk(spec, *c, ws, lo, q, (None,) * 3, first),
+        (x, idx, weights))
+    # A chunk keeps nothing for its backward but its inputs: what it kept
+    # would be stacked over the chunks, which is the buffer chunks avoid.
+    return out, (x, idx, weights, ws, lo, q, first)
+
+
+def _chunks_bwd(spec, int8, res, dout):
+    x, idx, weights, ws, lo, q, first = res
+
+    def step(dws_so_far, chunk):
+        # The chunk again, and its cotangents as autodiff writes them from
+        # the hand-written halves; the weights' are the sums carried on.
+        xc, ic, wc, dc = chunk
+        _, vjp = jax.vjp(
+            lambda xc, wc, ws: _one_chunk(spec, xc, ic, wc, ws, lo, q,
+                                          dws_so_far, first), xc, wc, ws)
+        dxc, dwc, dws = vjp(dc)
+        return dws, (dxc, dwc)
+
+    zeros = tuple(jnp.zeros(w.shape, jnp.float32) for w in ws)
+    dws, (dx, dweights) = jax.lax.scan(step, zeros, (x, idx, weights, dout))
+    return dx, None, dweights, dws, None
+
+
+_chunks.defvjp(_chunks_fwd, _chunks_bwd)
+
+
 def routed_experts(spec: ExpertSpec, x, idx, weights, w_gate, w_up, w_down,
                    first, dtype, int8: bool = False):
     """What the experts ``[first, first + count)`` (``count`` from the
     weights' leading dim) add for tokens ``x [T, D]`` whose choices are
     ``idx [T, k]`` with ``weights [T, k]``: ``[T, D]`` in ``dtype``, the
-    three forward products in int8 where ``int8`` says so."""
+    three forward products in int8 where ``int8`` says so. The tokens go
+    ``_chunk_tokens`` at a time; one chunk is a loop of one trip."""
     t, d = x.shape
-    count = w_gate.shape[0]
-    lo = [w.astype(dtype) for w in (w_gate, w_up, w_down)]
-    q = [quantize_symmetric(w, INT8, axis=1) if int8 else None for w in lo]
-    chunk = _chunk_tokens(spec, t)
-    rows = _buffer_rows(chunk, spec, count)
-
-    # A chunk keeps nothing for its backward but its inputs: what it kept
-    # would be stacked over the chunks, which is the buffer chunks avoid.
-    @jax.checkpoint
-    def one_chunk(args):
-        xc, ic, wc = args
-        with jax.named_scope("tony.moe.dispatch"):
-            held, pos, row_pair, row_live, tile_expert, n_active, _ = \
-                _layout(ic, first, count, spec.tile_rows, rows)
-            xs = _dispatch(xc, row_pair // spec.top_k, pos, held)
-        with jax.named_scope("tony.moe.experts"):
-            gmm = functools.partial(grouped_matmul, tile_expert=tile_expert,
-                                    n_active=n_active,
-                                    tile_rows=spec.tile_rows)
-            xs_gate, xs_up = _for_gate_and_up(xs, n_active, spec.tile_rows)
-            hidden = _gated(gmm(xs_gate, w_gate, lo[0], q[0]),
-                            gmm(xs_up, w_up, lo[1], q[1]), n_active,
-                            spec.tile_rows, spec.activation)
-            y = gmm(hidden, w_down, lo[2], q[2])
-        with jax.named_scope("tony.moe.combine"):
-            return _combine(y, wc, pos, held, row_pair, row_live, n_active,
-                            spec.tile_rows)
-
-    if chunk == t:
-        return one_chunk((x.astype(dtype), idx, weights))
-    parts = jax.lax.map(one_chunk, (
-        x.astype(dtype).reshape(t // chunk, chunk, d),
-        idx.reshape(t // chunk, chunk, -1),
-        weights.reshape(t // chunk, chunk, -1)))
+    n = t // _chunk_tokens(spec, t)
+    parts = _chunks(spec, int8, x.astype(dtype).reshape(n, -1, d),
+                    idx.reshape(n, t // n, -1), weights.reshape(n, t // n, -1),
+                    (w_gate, w_up, w_down), first)
     return parts.reshape(t, d)
 
 
