@@ -273,3 +273,82 @@ def test_expert_layer_compiles_at_published_widths(v5e_devices,
     assert not re.findall(rf"= {leaf}\S* copy\(", hlo)
     assert not re.findall(
         rf"= {leaf}\S* fusion\({leaf}\S* %[\w.\-]+, {leaf}", hlo)
+
+
+# The cell ``lagS.seq8k``'s whole step as the benchmark builds it: the bytes
+# XLA:TPU gives the compiled program on one v5e (arguments + outputs −
+# aliased + temporaries), which the chip's `step_hbm_gb_per_chip.lagS` reads
+# to the digit. A change of the program's schedule moves it: say so in
+# PERF.md and put the new number here.
+LAGS_STEP_BYTES = 12_746_787_840
+
+
+@pytest.mark.timeout_s(900)
+def test_the_laguna_cell_s_step_compiles_for_one_v5e_and_fits(v5e_devices):
+    """Laguna-S-2.1's share at published widths (672,125,952 parameters:
+    10.75 GB of state) through ``jit_train_step`` at 2 × 8,192 tokens: the
+    step passes XLA:TPU and Mosaic (heads of 24 and 36 over 4, windowed
+    calls at 512 × 512 tiles, grouped matmuls over 2,560 of 67,584 rows),
+    fits the chip's 16 GB, and holds the Mosaic calls a step needs: 2 full
+    and 3 windowed layers' fwd, dq and dkv, and per sparse layer 9
+    ``moe_gmm`` and 3 ``moe_tgmm`` in the two chunks' loops."""
+    import collections
+    import json
+    import sys
+
+    import flax.linen as nn
+    import optax
+
+    from tony_tpu.parallel import jit_train_step
+    from tony_tpu.parallel.mesh import batch_sharding
+    from tony_tpu.parallel.sharding import DEFAULT_RULES, param_shardings
+    from tony_tpu.parallel.train import TrainState
+
+    cells = os.path.join(REPO, "benchmarks", "cells")
+    sys.path.insert(0, cells)
+    try:
+        import arch
+        program = arch.load(os.path.join(cells, "architectures", "laguna"),
+                            "program")
+    finally:
+        sys.path.remove(cells)
+    with open(os.path.join(cells, "configs", "laguna-s-2.1.json"),
+              encoding="utf-8") as f:
+        cfg = json.load(f)
+    with open(os.path.join(cells, "traffic", "seq8k-2rows.json"),
+              encoding="utf-8") as f:
+        traffic = json.load(f)
+    mesh = build_mesh(MeshSpec.from_string(traffic["mesh"]),
+                      devices=v5e_devices[:1])
+    model, loss_fn = program.build(cfg, traffic, "")
+    tx = optax.adamw(cfg["train"]["adamw"]["learning_rate"],
+                     weight_decay=0.1)
+    tokens = jnp.zeros((traffic["global_batch"], traffic["seq"]), jnp.int32)
+
+    def boxed_init(rng):
+        params = model.init(rng, tokens)["params"]
+        return TrainState(step=jnp.zeros((), jnp.int32), params=params,
+                          opt_state=tx.init(params), tx=tx)
+
+    with nn.logical_axis_rules(list(DEFAULT_RULES)):
+        abstract = jax.eval_shape(boxed_init, jax.random.key(0))
+    state_sh = param_shardings(mesh, abstract, DEFAULT_RULES)
+    a_state = jax.tree.map(
+        lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+        nn.meta.unbox(abstract), state_sh)
+    a_batch = {"tokens": jax.ShapeDtypeStruct(
+        tokens.shape, jnp.int32, sharding=batch_sharding(mesh, 1))}
+    a_rng = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                                 sharding=NamedSharding(mesh, P()))
+    step = jit_train_step(loss_fn, mesh, state_sh, {"tokens": tokens})
+    compiled = step.lower(a_state, a_batch, a_rng).compile()
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert total == LAGS_STEP_BYTES, total
+    assert total < 16e9 and total > 0.25 * 16e9
+    names = collections.Counter(re.findall(
+        r"%((?:flash|moe)_[a-z_]+)[.\d]* = ", compiled.as_text()))
+    assert names == {"flash_fwd": 2, "flash_dq": 2, "flash_dkv": 2,
+                     "flash_win_fwd": 3, "flash_win_dq": 3,
+                     "flash_win_dkv": 3, "moe_gmm": 36, "moe_tgmm": 12}

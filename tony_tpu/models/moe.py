@@ -43,9 +43,19 @@ the shares of all the chips add up to the whole layer
   partial results are summed and scattered back (``psum_scatter``). That is
   "the shares add up" as a collective; no capacity is needed because no
   shard ever receives rows, it selects its own.
+- **A shared expert** (``ExpertSpec.shared_width``) is one more gated
+  feed-forward that every token goes through, beside the routed ones and
+  unweighted. It is what every chip of a deployment computes alike, so it
+  is no shard's part: three dense projections of the experts' input
+  (``shared/gate``, ``shared/up``, ``shared/down``, laid out as the dense
+  ``MLP``'s), outside the ``shard_map`` body and added to the routed sum
+  after the ``psum_scatter``, once. ``routed_scale`` multiplies the routed
+  weights (the softmax of the chosen logits) and leaves the shared expert
+  alone.
 
 Spans: ``tony.moe.route``, ``tony.moe.dispatch``, ``tony.moe.experts``,
-``tony.moe.combine`` (``jax.named_scope``). Counters, sown into the
+``tony.moe.combine``, ``tony.moe.shared`` (``jax.named_scope``). Counters,
+sown into the
 ``intermediates`` collection and reduced by ``moe_counters``:
 ``moe_rows_routed``, ``moe_rows_unrouted_share``,
 ``moe_expert_load_max_over_mean``, ``moe_buffer_rows_live_share``.
@@ -67,7 +77,8 @@ from jax.sharding import PartitionSpec as P
 
 from tony_tpu import compat
 from tony_tpu.ops.attention import _interpret, _prec
-from tony_tpu.ops.quant import INT8, quantize_symmetric, resolve_mode
+from tony_tpu.ops.quant import (INT8, dense, quantize_symmetric,
+                                resolve_mode)
 from tony_tpu.parallel.mesh import BATCH_AXES
 
 EP_AXIS = "ep"
@@ -85,7 +96,9 @@ class ExpertSpec:
     """A layer's sparse feed-forward: ``n_experts`` router outputs,
     ``top_k`` experts a token, gated experts of hidden ``width`` with
     ``activation`` on the gate, and ``held = (first, count)``, the experts
-    that live here (None: all of them)."""
+    that live here (None: all of them). ``shared_width``: a shared expert
+    of that hidden width beside them (None: none); ``routed_scale``: the
+    factor on the routed experts' weights."""
     n_experts: int
     top_k: int
     width: int
@@ -96,6 +109,8 @@ class ExpertSpec:
     route_before_attention: bool = False
     tile_rows: int = 256        # the grouped matmul's row tile
     chunk_tokens: int = 8192    # tokens routed at a time
+    shared_width: Optional[int] = None
+    routed_scale: float = 1.0
 
     def __post_init__(self):
         first, count = self.held or (0, self.n_experts)
@@ -704,8 +719,33 @@ def moe_counters(intermediates) -> dict:
             for name, values in found.items()}
 
 
+class SharedExpert(nn.Module):
+    """The gated feed-forward every token goes through: the dense ``MLP``
+    at the shared expert's width, ``matmul_dtype`` covering its three
+    projections as it covers that one's."""
+
+    spec: ExpertSpec
+    dtype: jnp.dtype
+    param_dtype: jnp.dtype
+    matmul_dtype: str
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        proj = functools.partial(dense, dtype=self.dtype,
+                                 param_dtype=self.param_dtype,
+                                 matmul_dtype=self.matmul_dtype)
+        width = self.spec.shared_width
+        gate = proj(width, ("embed", "mlp"), "gate")(x)
+        up = proj(width, ("embed", "mlp"), "up")(x)
+        h = nn.with_logical_constraint(
+            ACTIVATIONS[self.spec.activation](gate) * up,
+            ("batch", "seq", "mlp"))
+        return proj(x.shape[-1], ("mlp", "embed"), "down")(h)
+
+
 class ExpertLayer(nn.Module):
-    """Top-k routed gated experts. ``router_in`` is what the router reads
+    """Top-k routed gated experts, and the shared expert beside them where
+    the spec has one. ``router_in`` is what the router reads
     (the block's normed pre-attention input, or the same tensor as ``x`` for
     a router after attention); ``x`` is what the experts read."""
 
@@ -772,14 +812,27 @@ class ExpertLayer(nn.Module):
                              precision=jax.lax.Precision.HIGHEST)
             top, idx = jax.lax.top_k(logits, spec.top_k)
             weights = jax.nn.softmax(top, axis=-1)
+            if spec.routed_scale != 1.0:
+                weights = weights * spec.routed_scale
             for name, value in routing_counters(
                     spec, idx, first, count, n_rows, n_ep).items():
                 self.sow("intermediates", name, value)
 
+        def with_shared(out):
+            # What every shard computes alike is added once: here, outside
+            # the shards' parts and after their sum.
+            out = out.reshape(b, s, d)
+            if spec.shared_width is None:
+                return out
+            with jax.named_scope("tony.moe.shared"):
+                return out + SharedExpert(
+                    spec, self.dtype, self.param_dtype, self.matmul_dtype,
+                    name="shared")(x)
+
         xt = x.reshape(t, d)
         if not auto:
-            return routed(spec, xt, idx, weights, w_gate, w_up, w_down,
-                          first, self.dtype).reshape(b, s, d)
+            return with_shared(routed(spec, xt, idx, weights, w_gate, w_up,
+                                      w_down, first, self.dtype))
 
         def shard(xt, idx, weights, w_gate, w_up, w_down):
             if n_ep == 1:
@@ -801,7 +854,7 @@ class ExpertLayer(nn.Module):
             in_specs=(tok, tok, tok, held_w, held_w, held_w),
             out_specs=P(rows + (EP_AXIS,)) if n_ep > 1 else tok,
             check_vma=False)(xt, idx, weights, w_gate, w_up, w_down)
-        return out.reshape(b, s, d)
+        return with_shared(out)
 
 
 def dryrun_ep_step(devices, ep: int) -> float:

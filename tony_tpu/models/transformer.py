@@ -11,37 +11,103 @@ Design choices map straight onto TPU hardware:
 - static shapes and `remat`-friendly block structure (scan over layers is
   deliberately NOT used so pipeline stages can slice layers later);
 - one loop over layers that may differ: ``TransformerConfig.layers`` says
-  of each its attention mask (full causal or a window), RoPE on or off,
+  of each its attention mask (full causal or a window), its head counts,
+  its RoPE (none, or a ``RopeSpec``: θ, the share of a head's columns
+  rotated, a YaRN table), whether attention's output is gated per head,
   and its feed-forward (the dense ``MLP`` or sparse experts,
   ``models/moe.py``). Without it every layer is the dense default.
+
+Spans (``jax.named_scope``): ``tony.attn.rope``, ``tony.attn.gate``.
+Counter, sown into ``intermediates`` and reduced by ``layer_counters``:
+``attn_gate_mean``.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+import math
+from typing import Optional, Tuple, Union
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from tony_tpu.models.moe import ExpertLayer, ExpertSpec
+from tony_tpu.models.moe import ExpertLayer, ExpertSpec, moe_counters
+from tony_tpu.ops import quant
 from tony_tpu.ops.attention import (FLASH_RESIDUAL_NAMES, flash_attention,
                                     reference_attention)
-from tony_tpu.ops.quant import QDense
 from tony_tpu.ops.ring import ring_attention
 from tony_tpu.ops.ulysses import ulysses_attention
 
 
 @dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN's blend of interpolated and original RoPE frequencies, as
+    Hugging Face's ``_compute_yarn_parameters`` has it: positions stretched
+    by ``factor`` from ``original_max_position``, the frequencies that turn
+    more than ``beta_fast`` times in the original context kept, those that
+    turn fewer than ``beta_slow`` times divided by ``factor``, a linear ramp
+    between; cos and sin are multiplied by ``attention_factor``."""
+    factor: float
+    original_max_position: int
+    attention_factor: float
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeSpec:
+    """A layer's rotary embedding: base ``theta``, the leading share
+    ``rotated`` of a head's columns it turns (the rest pass through), and an
+    optional YaRN table."""
+    theta: float
+    rotated: float = 1.0
+    yarn: Optional[Yarn] = None
+
+    def table(self, head_dim: int):
+        """(inverse frequencies of the rotated columns' pairs, the factor on
+        cos and sin). Plain RoPE's frequencies are the expression they have
+        always been; YaRN's table is worked out once, in numpy, at trace
+        time."""
+        rot = int(head_dim * self.rotated)
+        if self.yarn is None:
+            return self.theta ** (
+                -jnp.arange(0, rot, 2, dtype=jnp.float32) / rot), 1.0
+        y = self.yarn
+
+        def correction_dim(turns):
+            return rot * math.log(y.original_max_position
+                                  / (turns * 2 * math.pi)) \
+                / (2 * math.log(self.theta))
+
+        low = max(math.floor(correction_dim(y.beta_fast)), 0)
+        high = min(math.ceil(correction_dim(y.beta_slow)), rot - 1)
+        if low == high:
+            high += 0.001
+        extrapolated = 1.0 / self.theta ** (
+            np.arange(0, rot, 2, dtype=np.float64) / rot)
+        ramp = np.clip((np.arange(rot // 2) - low) / (high - low), 0, 1)
+        inv_freq = extrapolated / y.factor * ramp + extrapolated * (1 - ramp)
+        return jnp.asarray(inv_freq, jnp.float32), float(y.attention_factor)
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerSpec:
     """One layer of the stack. ``window=w``: query i attends keys
-    i − w < j ≤ i (None: the full causal triangle). ``rope=False``: no
-    position embedding on this layer's q and k. ``experts``: the sparse
-    feed-forward in place of the dense ``MLP`` (None: dense)."""
+    i − w < j ≤ i (None: the full causal triangle). ``rope``: True is RoPE
+    at ``cfg.rope_theta`` over the whole head, False no position embedding
+    on this layer's q and k, a ``RopeSpec`` the layer's own. ``n_heads``:
+    the layer's q heads over ``cfg.n_kv_heads`` (None: the config's).
+    ``gate``: attention's output is scaled, a head and token, by the
+    sigmoid of a projection ``wg`` of the layer's normed input.
+    ``experts``: the sparse feed-forward in place of the dense ``MLP``
+    (None: dense, at ``cfg.mlp_dim``)."""
     window: Optional[int] = None
-    rope: bool = True
+    rope: Union[bool, RopeSpec] = True
     experts: Optional[ExpertSpec] = None
+    n_heads: Optional[int] = None
+    gate: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -139,16 +205,9 @@ def remat_policy_of(cfg: TransformerConfig):
 
 
 def _dense(cfg: TransformerConfig, feats: int, axes, name: str) -> nn.Module:
-    init = nn.with_logical_partitioning(nn.initializers.lecun_normal(), axes)
-    if cfg.matmul_dtype:
-        # Same param name ("kernel"), path and init as nn.Dense, so the
-        # knob flips freely across checkpoints of the same model.
-        return QDense(features=feats, dtype=cfg.dtype,
-                      param_dtype=cfg.param_dtype, name=name,
-                      kernel_init=init, matmul_dtype=cfg.matmul_dtype)
-    return nn.Dense(
-        feats, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-        name=name, kernel_init=init)
+    return quant.dense(feats, axes, name, dtype=cfg.dtype,
+                       param_dtype=cfg.param_dtype,
+                       matmul_dtype=cfg.matmul_dtype)
 
 
 def _sp_offset() -> jax.Array:
@@ -163,16 +222,22 @@ def _sp_offset() -> jax.Array:
     return jax.lax.axis_index("sp")
 
 
-def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary position embedding on [B, S, H, D]; f32 trig, cast back."""
+def _rope(x: jax.Array, positions: jax.Array, rope: RopeSpec) -> jax.Array:
+    """Rotary position embedding on [B, S, H, D]; f32 trig, cast back. The
+    half-split rotation runs within the rotated leading columns; the others
+    pass through."""
     d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    freqs, factor = rope.table(d)
+    rot = 2 * freqs.shape[0]
     angles = positions[:, :, None, None].astype(jnp.float32) \
-        * freqs[None, None, None, :]                    # [B, S, 1, D/2]
+        * freqs[None, None, None, :]                    # [B, S, 1, rot/2]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
-                          axis=-1)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
+    xf = x.astype(jnp.float32)
+    x1, x2 = jnp.split(xf if rot == d else xf[..., :rot], 2, axis=-1)
+    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos]
+                          + ([] if rot == d else [xf[..., rot:]]), axis=-1)
     return out.astype(x.dtype)
 
 
@@ -200,20 +265,25 @@ class Attention(nn.Module):
     def __call__(self, x, positions):
         cfg, spec = self.cfg, self.spec
         head_dim = cfg.head_size
+        n_heads = spec.n_heads or cfg.n_heads
+        n_kv_heads = cfg.n_kv_heads
         b, s, _ = x.shape
         # Plain Dense with a fused (heads·head_dim) output: the fused dim is
         # heads-major, so sharding it over tp == sharding heads over tp.
         # (DenseGeneral flattens multi-dim kernels before calling
         # kernel_init, which breaks 3-axis logical metadata.)
-        q = _dense(cfg, cfg.n_heads * head_dim, ("embed", "heads"), "wq")(
-            x).reshape(b, s, cfg.n_heads, head_dim)
-        k = _dense(cfg, cfg.n_kv_heads * head_dim, ("embed", "kv_heads"),
-                   "wk")(x).reshape(b, s, cfg.n_kv_heads, head_dim)
-        v = _dense(cfg, cfg.n_kv_heads * head_dim, ("embed", "kv_heads"),
-                   "wv")(x).reshape(b, s, cfg.n_kv_heads, head_dim)
+        q = _dense(cfg, n_heads * head_dim, ("embed", "heads"), "wq")(
+            x).reshape(b, s, n_heads, head_dim)
+        k = _dense(cfg, n_kv_heads * head_dim, ("embed", "kv_heads"),
+                   "wk")(x).reshape(b, s, n_kv_heads, head_dim)
+        v = _dense(cfg, n_kv_heads * head_dim, ("embed", "kv_heads"),
+                   "wv")(x).reshape(b, s, n_kv_heads, head_dim)
         if spec.rope:
-            q = _rope(q, positions, cfg.rope_theta)
-            k = _rope(k, positions, cfg.rope_theta)
+            rope = RopeSpec(cfg.rope_theta) if spec.rope is True \
+                else spec.rope
+            with jax.named_scope("tony.attn.rope"):
+                q = _rope(q, positions, rope)
+                k = _rope(k, positions, rope)
         q = nn.with_logical_constraint(q, ("batch", "seq", "heads", "kv"))
         k = nn.with_logical_constraint(k, ("batch", "seq", "kv_heads", "kv"))
         v = nn.with_logical_constraint(v, ("batch", "seq", "kv_heads", "kv"))
@@ -224,7 +294,7 @@ class Attention(nn.Module):
                                 block_k=cfg.attn_block_k,
                                 window=spec.window)
         elif cfg.attn_impl == "xla":
-            g = cfg.n_heads // cfg.n_kv_heads
+            g = n_heads // n_kv_heads
             o = reference_attention(q, jnp.repeat(k, g, axis=2),
                                     jnp.repeat(v, g, axis=2), causal=True,
                                     window=spec.window)
@@ -243,7 +313,18 @@ class Attention(nn.Module):
         else:
             raise ValueError(f"unknown attn_impl {cfg.attn_impl!r}")
         o = nn.with_logical_constraint(o, ("batch", "seq", "heads", "kv"))
-        o = o.reshape(b, s, cfg.n_heads * head_dim)
+        if spec.gate:
+            # One scalar a head and token, from what q, k and v are read
+            # from. The projection is H columns wide: it stays outside the
+            # quantized path, in the activation dtype.
+            with jax.named_scope("tony.attn.gate"):
+                gate = jax.nn.sigmoid(quant.dense(
+                    n_heads, ("embed", "heads"), "wg", dtype=cfg.dtype,
+                    param_dtype=cfg.param_dtype, matmul_dtype=None)(
+                        x).astype(jnp.float32))
+                self.sow("intermediates", "attn_gate_mean", jnp.mean(gate))
+                o = (o.astype(jnp.float32) * gate[..., None]).astype(o.dtype)
+        o = o.reshape(b, s, n_heads * head_dim)
         return _dense(cfg, cfg.dim, ("heads", "embed"), "wo")(o)
 
 
@@ -359,6 +440,21 @@ class Transformer(nn.Module):
                     nn.initializers.lecun_normal(), ("embed", "vocab")))(
                         x.astype(head_dtype))
         return logits.astype(jnp.float32)
+
+
+def layer_counters(intermediates) -> dict:
+    """What the layers sowed, as one dict of scalars for a step's aux
+    metrics: the expert layers' counters (``moe_counters``) and
+    ``attn_gate_mean``, the mean of the per-head output gates over heads,
+    tokens and gated layers. {} where nothing was sown."""
+    out = moe_counters(intermediates)
+    gates = [value for path, value in
+             jax.tree_util.tree_leaves_with_path(intermediates)
+             if any(getattr(k, "key", None) == "attn_gate_mean"
+                    for k in path)]
+    if gates:
+        out["attn_gate_mean"] = jnp.mean(jnp.stack(gates))
+    return out
 
 
 def causal_lm_loss(logits: jax.Array, tokens: jax.Array,

@@ -721,6 +721,17 @@ def _check_and_transpose(q, k, v, causal, scale):
             v.transpose(0, 2, 1, 3), scale)
 
 
+def _window_blocks(window: int, block_q: int, block_k: int) -> tuple:
+    """The tiles of a windowed call follow its window: a tile wider than the
+    window computes whole tiles of pairs for a band a fraction as wide (at
+    w = 512 a 1024 × 1024 tile has at most a quarter of its pairs inside the
+    band). So the tile is the window rounded up to 128 lanes where that is
+    under the caller's block, and the caller's block otherwise: a window of
+    4,096 keeps 1,024, a window of 512 takes 512."""
+    tile = _round_up(window, 128)
+    return min(block_q, tile), min(block_k, tile)
+
+
 def flash_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array,
                              causal: bool = True,
                              scale: Optional[float] = None,
@@ -762,7 +773,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     innermost grid axis walks that band alone, and the device trace names
     them ``flash_win_fwd`` / ``flash_win_dq`` / ``flash_win_dkv``. A window
     that covers the whole sequence is the full causal call, under the full
-    kernels' names.
+    kernels' names. A window narrower than ``block_q`` / ``block_k`` takes
+    tiles of its own width (``_window_blocks``); no caller carries a second
+    pair of block sizes.
 
     Differentiable (custom flash backward); accumulation in f32 regardless
     of input dtype (bf16 in, bf16 out, f32 softmax state on-chip), matmuls
@@ -787,5 +800,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                              f"least one key a query")
         if window >= q.shape[1]:
             window = None       # every query sees its whole causal prefix
+        else:
+            block_q, block_k = _window_blocks(window, block_q, block_k)
     oh = _flash(qh, kh, vh, scale, causal, block_q, block_k, window)
     return oh.transpose(0, 2, 1, 3)

@@ -217,3 +217,18 @@ class QDense(nn.Module):
                 x, kernel, (((x.ndim - 1,), (0,)), ((), ())),
                 precision=None)
         return quantized_matmul(x, kernel, mode)
+
+
+def dense(features: int, axes, name: str, *, dtype, param_dtype,
+          matmul_dtype: Optional[str]) -> nn.Module:
+    """A bias-free projection whose ``kernel`` carries the logical ``axes``:
+    ``nn.Dense``, or ``QDense`` where ``matmul_dtype`` names a quantized
+    forward. Same param name, path and init either way, so the knob flips
+    freely across checkpoints of the same model."""
+    init = nn.with_logical_partitioning(nn.initializers.lecun_normal(), axes)
+    if matmul_dtype:
+        return QDense(features=features, dtype=dtype,
+                      param_dtype=param_dtype, name=name, kernel_init=init,
+                      matmul_dtype=matmul_dtype)
+    return nn.Dense(features, use_bias=False, dtype=dtype,
+                    param_dtype=param_dtype, name=name, kernel_init=init)
