@@ -275,12 +275,53 @@ def test_expert_layer_compiles_at_published_widths(v5e_devices,
         rf"= {leaf}\S* fusion\({leaf}\S* %[\w.\-]+, {leaf}", hlo)
 
 
+def test_the_token_side_gathers_a_slot_for_its_tokens(v5e_devices):
+    """At Laguna's widths (256 router outputs, 10 a token, 8 experts held,
+    hidden 3,072, two chunks of 8,192 tokens) a ``_gather_sum`` is, compiled:
+    one loop over the slots whose inner loop gathers a segment of rows a
+    trip, and ONE gather that yields ``[8192, 3072]`` (the sums back in the
+    tokens' order, the cast inside), where a loop over the choices had ten.
+    The layer's forward and backward hold two: combine's forward and
+    dispatch's transpose (the combine that the chunk's backward recomputes
+    is dead)."""
+    import flax.linen as nn
+
+    from tony_tpu.models import moe
+    from tony_tpu.models.moe import ExpertLayer, ExpertSpec
+
+    mesh = build_mesh(MeshSpec(), devices=v5e_devices[:1])
+    spec = ExpertSpec(n_experts=256, top_k=10, width=1024, held=(0, 8),
+                      routed_scale=2.5)
+    layer = ExpertLayer(spec, jnp.bfloat16)
+    x = _abstract((2, 8192, 3072), jnp.bfloat16, mesh,
+                  P(BATCH_AXES, None, None))
+    tiny = jnp.zeros((1, 8, 3072), jnp.bfloat16)
+    shapes = jax.eval_shape(lambda: nn.meta.unbox(
+        layer.init(jax.random.key(0), tiny, tiny))["params"])
+    params = jax.tree.map(
+        lambda a: _abstract(a.shape, a.dtype, mesh, P()), shapes)
+
+    def loss(p, r, x):       # the forward's result is read: it stays
+        return jnp.square(layer.apply({"params": p}, r, x).astype(
+            jnp.float32)).sum()
+
+    with jax.set_mesh(mesh):
+        hlo = jax.jit(jax.grad(loss, argnums=(0, 2))).lower(
+            params, x, x).compile().as_text()
+    gathers = re.findall(
+        r"= bf16\[(\d+),3072\]\S* fusion\(.*kind=kCustom.*op_name=\"[^\"]*"
+        r"/gather\"", hlo)
+    assert gathers.count("8192") == 2, gathers
+    # One loop over the slots around one over a slot's segments.
+    assert gathers.count(str(moe.TOKEN_SEGMENT_ROWS)) == 2, gathers
+
+
 # The cell ``lagS.seq8k``'s whole step as the benchmark builds it: the bytes
 # XLA:TPU gives the compiled program on one v5e (arguments + outputs −
 # aliased + temporaries), which the chip's `step_hbm_gb_per_chip.lagS` reads
 # to the digit. A change of the program's schedule moves it: say so in
 # PERF.md and put the new number here.
-LAGS_STEP_BYTES = 12_746_787_840
+LAGS_STEP_BYTES = 12_732_606_464
 
 
 @pytest.mark.timeout_s(900)

@@ -127,7 +127,8 @@ def test_loss_and_gradients_match_the_reference(bench, cfg):
     assert set(aux) == {"attn_gate_mean", "moe_rows_routed",
                         "moe_rows_unrouted_share",
                         "moe_expert_load_max_over_mean",
-                        "moe_buffer_rows_live_share"}
+                        "moe_buffer_rows_live_share",
+                        "moe_token_rows_gathered_share"}
     assert 0.3 < float(aux["attn_gate_mean"]) < 0.7
 
 

@@ -14,8 +14,10 @@ from jax.extend import core as jax_core
 
 from tony_tpu.models import moe
 from tony_tpu.models.moe import (ExpertLayer, ExpertSpec, MoEConfig,
-                                 _buffer_rows, _combine, _gmm_call, _layout,
-                                 _tgmm_call, moe_counters, routed_experts,
+                                 _buffer_rows, _combine, _dispatch,
+                                 _gather_sum, _gmm_call, _layout,
+                                 _orders_tokens, _tgmm_call, _token_order,
+                                 moe_counters, routed_experts,
                                  routing_counters)
 from tony_tpu.models.transformer import (Transformer, TransformerConfig,
                                          causal_lm_loss)
@@ -168,7 +170,8 @@ def test_combine_takes_its_weight_gradient_on_the_row_side():
     y = jax.random.normal(ks[1], (rows, d))
     weights = jax.nn.softmax(jax.random.normal(ks[2], (t, k)))
     dout = jax.random.normal(ks[3], (t, d))
-    _, vjp = jax.vjp(lambda y, w: _combine(y, w, pos, held, row_pair,
+    _, vjp = jax.vjp(lambda y, w: _combine(y, w, pos, held,
+                                           _token_order(held, k), row_pair,
                                            row_live, n_active, tile),
                      y, weights)
     dy, dweights = vjp(dout)
@@ -184,6 +187,120 @@ def test_combine_takes_its_weight_gradient_on_the_row_side():
     np.testing.assert_allclose(
         dy[:live], (np.asarray(dout)[np.asarray(row_pair) // k]
                     * row_weight[:, None])[:live], rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("n,k,count,ordered", [
+    (256, 10, 8, True), (64, 6, 16, True), (4, 2, 1, True), (8, 3, 4, True),
+    (4, 2, 4, False), (8, 6, 8, False), (8, 1, 2, False), (4, 2, 2, False),
+    (8, 2, 7, False)])
+def test_tokens_are_ordered_where_that_gathers_fewer_rows(monkeypatch, n, k,
+                                                          count, ordered):
+    """The static rule: a token's expected held pairs and the row back
+    against the rows a loop over the choices gathers. Laguna's and
+    SmallThinker's shares and an ``ep = 4`` shard of ``tiny_moe`` order their
+    tokens; a layer that holds every expert, one choice a token, and half
+    or most of two choices keep the loop."""
+    monkeypatch.setattr(moe, "TOKEN_SEGMENT_ROWS", 4)
+    spec = _spec(n_experts=n, top_k=k)
+    assert _orders_tokens(spec, count) is ordered
+    idx = jax.lax.top_k(jax.random.normal(jax.random.key(0), (64, n)), k)[1]
+    share = float(routing_counters(_spec(n_experts=n, top_k=k,
+                                         chunk_tokens=64), idx, 0, count)
+                  ["moe_token_rows_gathered_share"])
+    assert (share < 1.0) if ordered else (share == 1.0)
+
+
+# (experts, a token's choices, first held, held, tokens, segment, what the
+# router is pushed to: +1 towards the held experts, −1 away from them).
+TOKEN_SIDE = {
+    "no-pair-held": (8, 3, 2, 4, 24, 1024, -1),
+    "every-pair-held": (4, 2, 0, 4, 24, 1024, 0),
+    "a-token-with-all-it-can-hold": (8, 3, 2, 4, 24, 1024, +1),
+    "prefixes-that-do-not-divide-the-segment": (8, 3, 2, 4, 20, 8, 0),
+    "segments-that-do-not-divide-the-chunk": (8, 3, 2, 4, 20, 12, 0),
+    "more-held-than-choices": (16, 2, 4, 6, 24, 8, 0),
+    "fewer-held-than-choices": (8, 6, 6, 2, 24, 16, +1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TOKEN_SIDE))
+def test_the_token_side_gathers_the_held_pairs(monkeypatch, case):
+    """``_gather_sum`` with a token order against the plain definition
+    ``Σ_c scale · src[pos]`` and against the loop over every choice (rule:
+    ``_orders_tokens``), with NaN in every row of ``src`` past the live ones:
+    the forward bit for bit (the held pairs are added in the order the loop
+    adds them, and it adds exact zeros between), and the gradients that
+    ``_dispatch`` and ``_combine`` make of it to float32 rounding."""
+    n, k, first, count, t, segment, push = TOKEN_SIDE[case]
+    monkeypatch.setattr(moe, "TOKEN_SEGMENT_ROWS", segment)
+    spec = _spec(n_experts=n, top_k=k, held=(first, count))
+    tile, d = spec.tile_rows, 16
+    ks = jax.random.split(jax.random.key(11), 5)
+    here = (jnp.arange(n) >= first) & (jnp.arange(n) < first + count)
+    # The first tokens are pushed, the rest route as they fall.
+    logits = jax.random.normal(ks[0], (t, n)) + 50.0 * push * here * (
+        jnp.arange(t)[:, None] < (t if push < 0 else 3))
+    idx = jax.lax.top_k(logits, k)[1]
+    rows = _buffer_rows(t, spec, count)
+    held, pos, row_pair, row_live, _, n_active, _ = _layout(
+        idx, first, count, tile, rows)
+    slots = min(k, count)
+    mine = np.asarray(held).sum(axis=1)
+    assert _orders_tokens(spec, count) == (case != "every-pair-held")
+    assert mine.max() == {"no-pair-held": 0}.get(case, slots)
+    if push >= 0:
+        assert mine.min() < slots or count == n
+    order = _token_order(held, slots)
+    in_slot, listed, prefix, place = order
+    np.testing.assert_array_equal(in_slot.sum(axis=(1, 2)), mine)
+    np.testing.assert_array_equal(listed[place], np.arange(t))
+    np.testing.assert_array_equal(
+        prefix, [(mine > j).sum() for j in range(slots)])
+    assert (np.diff(mine[np.asarray(listed)]) <= 0).all()
+
+    live = int(n_active[0]) * tile
+    src = jax.random.normal(ks[1], (rows, d)).at[live:].set(jnp.nan)
+    weights = jax.nn.softmax(jax.random.normal(ks[2], (t, k)))
+    safe = jnp.minimum(pos, rows - 1)
+
+    def plain(src, scale):
+        return jnp.sum(jnp.where(held[..., None], src[safe], 0.0)
+                       * scale[..., None], axis=1)
+
+    # Bit for bit where a product is exact (weights that are powers of two):
+    # the CPU contracts a multiply and an add into one rounding, and which
+    # product of a sum it takes differs between the two programs.
+    halves = 0.5 ** jax.random.randint(ks[2], (t, k), 0, 4)
+    for scale, exact in ((jnp.where(held, weights, 0.0), False),
+                         (jnp.where(held, halves, 0.0), True),
+                         (held.astype(jnp.float32), True)):
+        got = _gather_sum(src, pos, scale, order)
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, plain(src, scale), rtol=1e-6,
+                                   atol=1e-6)
+        if exact:
+            np.testing.assert_array_equal(
+                got, _gather_sum(src, pos, scale, None))
+
+    # The two custom_vjps that call it, against autodiff of the plain form.
+    use = order if _orders_tokens(spec, count) else None
+    x = jax.random.normal(ks[3], (t, d))
+    dxs = src.at[live:].set(0.0)    # a cotangent is finite everywhere
+    _, vjp = jax.vjp(lambda x: _dispatch(x, row_pair // k, pos, held, use), x)
+    want = jnp.zeros((t, d)).at[(row_pair // k)[:live]].add(
+        jnp.where(row_live[:live, None], dxs[:live], 0.0))
+    np.testing.assert_allclose(vjp(dxs)[0], want, rtol=1e-6, atol=1e-6)
+    dout = jax.random.normal(ks[4], (t, d))
+    out, vjp = jax.vjp(lambda y, w: _combine(
+        y, w, pos, held, use, row_pair, row_live, n_active, tile), src,
+        weights)
+    want, plain_vjp = jax.vjp(
+        lambda y, w: plain(y, jnp.where(held, w, 0.0)), dxs, weights)
+    np.testing.assert_allclose(out, want, rtol=1e-6, atol=1e-6)
+    for a, b in zip(vjp(dout), plain_vjp(dout)):
+        np.testing.assert_allclose(a[:live] if a.shape[0] == rows else a,
+                                   b[:live] if b.shape[0] == rows else b,
+                                   rtol=1e-6, atol=1e-6)
 
 
 def test_an_expert_with_no_rows_gets_a_zero_gradient():

@@ -55,7 +55,7 @@ def test_train_step_hands_over_the_aux_metrics_alone(with_aux):
 
 
 def test_an_expert_model_s_counters_reach_the_step_counters():
-    """The four counters of a model with experts, the live share of its row
+    """The five counters of a model with experts, the live share of its row
     buffers among them, through ``jit_train_step`` as a program hands them
     over (``moe_counters`` of what the layers sowed)."""
     from tony_tpu.models.moe import MoEConfig, moe_counters
@@ -78,8 +78,48 @@ def test_an_expert_model_s_counters_reach_the_step_counters():
     counters = telemetry.step_counters()
     assert set(counters) == {"moe_rows_routed", "moe_rows_unrouted_share",
                              "moe_expert_load_max_over_mean",
-                             "moe_buffer_rows_live_share"}
+                             "moe_buffer_rows_live_share",
+                             "moe_token_rows_gathered_share"}
+    # Every expert is held, so the token side gathers every choice's rows.
+    assert counters["moe_token_rows_gathered_share"] == 1.0
     # All four experts held, 16 tokens a device choosing 2: 32 rows and at
     # most a tile of 8 an expert in a buffer of (4 + 4) tiles.
     assert 32 / 64 <= counters["moe_buffer_rows_live_share"] <= 1.0
     assert counters["moe_rows_routed"] == 8 * 16 * 2
+
+
+def test_the_token_side_s_rows_by_hand(monkeypatch):
+    """``moe_token_rows_gathered_share`` on a routing small enough to count:
+    experts 2 and 3 of eight held, three choices a token, chunks of eight
+    tokens, segments of four. A chunk's ``_gather_sum`` gathers each slot's
+    prefix in whole segments and all eight rows back, where a loop over the
+    choices gathers 8 · 3."""
+    from tony_tpu.models import moe
+
+    monkeypatch.setattr(moe, "TOKEN_SEGMENT_ROWS", 4)
+    spec = moe.ExpertSpec(n_experts=8, top_k=3, width=8, held=(2, 2),
+                          tile_rows=8, chunk_tokens=8)
+    # Five tokens with a pair here and one of them with two: slot 0 takes
+    # two segments, slot 1 one. 8 + 4 + 8 back = 20 of 24.
+    first = [[2, 0, 1], [0, 3, 1], [4, 5, 6], [3, 2, 7], [0, 1, 4],
+             [5, 2, 0], [7, 6, 3], [1, 0, 5]]
+    # No pair here: no slot runs a trip, the gather back alone. 8 of 24.
+    second = [[0, 1, 4], [5, 6, 7]] * 4
+    idx = jnp.array(first + second)
+    got = moe.routing_counters(spec, idx, 2, 2)
+    assert float(got["moe_token_rows_gathered_share"]) == pytest.approx(
+        (20 + 8) / 2 / 24)
+    assert float(got["moe_rows_routed"]) == 6
+    # Held whole, the same routing is a loop over the choices: 1.
+    whole = moe.routing_counters(
+        moe.ExpertSpec(n_experts=8, top_k=3, width=8, chunk_tokens=8), idx,
+        0, 8)
+    assert float(whole["moe_token_rows_gathered_share"]) == 1.0
+    # Two devices with four experts each, three slots: the first chunk's
+    # prefixes are 7, 6, 2 and 6, 2, 1 (8 + 8 + 4 and 8 + 4 + 4 rows, and 8
+    # back: 28 and 24), the second's 4, 4, 0 and 8, 4, 4 (16 and 24).
+    split = moe.routing_counters(
+        moe.ExpertSpec(n_experts=8, top_k=3, width=8, chunk_tokens=8), idx,
+        0, 8, expert_groups=2)
+    assert float(split["moe_token_rows_gathered_share"]) == pytest.approx(
+        (28 + 24 + 16 + 24) / 4 / 24)

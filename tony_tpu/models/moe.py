@@ -58,7 +58,8 @@ Spans: ``tony.moe.route``, ``tony.moe.dispatch``, ``tony.moe.experts``,
 sown into the
 ``intermediates`` collection and reduced by ``moe_counters``:
 ``moe_rows_routed``, ``moe_rows_unrouted_share``,
-``moe_expert_load_max_over_mean``, ``moe_buffer_rows_live_share``.
+``moe_expert_load_max_over_mean``, ``moe_buffer_rows_live_share``,
+``moe_token_rows_gathered_share``.
 """
 
 from __future__ import annotations
@@ -89,6 +90,9 @@ VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 # XLA's passes over a row buffer go a segment of whole tiles at a time and
 # stop where the live rows end (``_live_rows``).
 SEGMENT_ROWS = 4096
+# The token side's sums go a segment of tokens at a time and stop where a
+# slot's tokens end (``_gather_sum``).
+TOKEN_SEGMENT_ROWS = 256
 
 
 @dataclasses.dataclass(frozen=True)
@@ -367,6 +371,16 @@ def _padded(sizes, tile_rows: int):
     return jnp.maximum(-(-sizes // tile_rows), 1) * tile_rows
 
 
+def _counted(keys, n: int):
+    """A counting sort's two counts of ``keys [N]`` in ``[0, n)``: how many
+    have each value, and how many of a key's equals come before it (0 for a
+    key past ``n``)."""
+    onehot = (keys[:, None] == jnp.arange(n, dtype=keys.dtype)[None, :]
+              ).astype(jnp.int32)                           # [N, n]
+    return (jnp.sum(onehot, axis=0),
+            jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot, axis=1))
+
+
 def _layout(idx, first, count: int, tile_rows: int, rows: int):
     """Where each (token, choice) pair of ``idx [T, k]`` (expert ids) goes in
     a buffer of ``rows`` rows that holds experts ``[first, first + count)``
@@ -382,10 +396,7 @@ def _layout(idx, first, count: int, tile_rows: int, rows: int):
     local = idx - first
     held = (local >= 0) & (local < count)
     flat = jnp.where(held, local, count).reshape(-1)        # sentinel last
-    onehot = (flat[:, None] == jnp.arange(count, dtype=flat.dtype)[None, :]
-              ).astype(jnp.int32)                           # [T·k, count]
-    sizes = jnp.sum(onehot, axis=0)
-    rank = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot, axis=1)
+    sizes, rank = _counted(flat, count)
     padded = _padded(sizes, tile_rows)
     ends = jnp.cumsum(padded)
     starts = ends - padded
@@ -410,16 +421,19 @@ def _layout(idx, first, count: int, tile_rows: int, rows: int):
             tile_expert, n_active, sizes)
 
 
-def _live_rows(fn, written, read, n_active, tile_rows: int):
+def _live_rows(fn, written, read, n_active, tile_rows: int,
+               segment_rows: Optional[int] = None):
     """``written[r], … = fn(written[r], …, read[r], …)`` for the rows ``r`` of
     the live prefix ``[0, n_active · tile_rows)`` of buffers with one leading
     dim; the rows past it stay as they are, and nothing reads them. ``fn``
-    works row by row on a segment of whole tiles (``SEGMENT_ROWS``); the loop's
-    trip count follows ``n_active``, so autodiff never meets it: only the
-    hand-written halves of a ``custom_vjp`` call this. A buffer in ``written``
-    that is dead afterwards is updated in place."""
+    works row by row on a segment of whole tiles (``segment_rows``, or
+    ``SEGMENT_ROWS``); the loop's trip count follows ``n_active``, so autodiff
+    never meets it: only the hand-written halves of a ``custom_vjp`` call
+    this. A buffer in ``written`` that is dead afterwards is updated in
+    place."""
     rows = written[0].shape[0]
-    seg = min(max(SEGMENT_ROWS // tile_rows, 1) * tile_rows, rows)
+    seg = min(max((segment_rows or SEGMENT_ROWS) // tile_rows, 1) * tile_rows,
+              rows)
 
     def segment(i, bufs):
         # Where segments do not divide the buffer the last one starts early,
@@ -439,37 +453,108 @@ def _live_rows(fn, written, read, n_active, tile_rows: int):
     return jax.lax.fori_loop(0, -(-live // seg), segment, tuple(written))
 
 
-def _gather_sum(src, pos, scale):
+def _orders_tokens(spec: ExpertSpec, count: int) -> bool:
+    """Whether a layer that holds ``count`` experts orders its tokens by the
+    pairs they have here (``_token_order``): where the rows it then gathers a
+    token under an even router, the ``top_k · count / n_experts`` held pairs
+    and the one row back, are fewer than the ``top_k`` a loop over the
+    choices gathers (a gathered row costs either way about the same:
+    ``PERF.md`` 6, PR 33). A layer that holds every expert does not, nor
+    does one whose tokens choose a single expert."""
+    return spec.top_k * count / spec.n_experts + 1 < spec.top_k
+
+
+def _token_order(held, slots: int):
+    """The token side's order of work for a chunk whose pairs ``held [T, k]``
+    met an expert here; a token has at most ``slots`` such pairs. Each token's
+    held pairs are counted off in their choice order, its ``j``-th into slot
+    ``j``, and the tokens are listed by how many they have, most first (a
+    counting sort by the ``slots + 1`` counts, as ``_layout``'s by expert).
+    Slot ``j`` is then filled for a prefix of the list and for no token past
+    it.
+
+    Returns ``in_slot [T, slots, k]`` (choice ``c`` of token ``t`` is its
+    ``j``-th held pair), ``listed [T]`` (the list's ``p``-th token), ``n
+    [slots]`` (the prefix: tokens with more than ``j`` pairs here) and
+    ``place [T]`` (where token ``t`` is in the list)."""
+    held_i = held.astype(jnp.int32)
+    rank = jnp.cumsum(held_i, axis=1) - held_i      # held pairs before it
+    slot = jnp.arange(slots, dtype=jnp.int32)
+    in_slot = held[:, None, :] & (rank[:, None, :] == slot[None, :, None])
+    group = slots - jnp.sum(held_i, axis=1)         # most pairs first
+    sizes, rank = _counted(group, slots + 1)
+    ends = jnp.cumsum(sizes)
+    place = (ends - sizes)[group] + rank
+    listed = jnp.argsort(group, stable=True).astype(jnp.int32)
+    # Tokens with more than j pairs are groups 0 … slots − j − 1.
+    return in_slot, listed, ends[slots - 1 - slot], place.astype(jnp.int32)
+
+
+def _gather_sum(src, pos, scale, order):
     """``out[t] = Σ_c scale[t, c] · src[pos[t, c]]`` in float32, one choice
     at a time (a [T, k, D] gather is never held); ``scale`` is zero where the
     pair is not held, and such a ``pos`` is clamped, never read for its
-    value."""
-    out = None
-    safe = jnp.minimum(pos, src.shape[0] - 1)
-    for c in range(pos.shape[1]):
-        live = scale[:, c, None] != 0
-        part = jnp.where(live, src[safe[:, c]].astype(jnp.float32), 0.0) \
-            * scale[:, c, None]
-        out = part if out is None else out + part
-    return out
+    value. With an ``order`` (``_token_order``) only held pairs are gathered:
+    slot by slot over the slot's prefix of the listed tokens, a segment
+    (``TOKEN_SEGMENT_ROWS``) at a time, so a token's pairs are summed in its
+    own order of choices, and one gather takes the sums back to the tokens'
+    order. Only the hand-written halves of a ``custom_vjp`` call this
+    (``_live_rows``)."""
+    def part(rows, scale):
+        # Rows past the live prefix of ``src`` may hold anything.
+        return jnp.where(scale[:, None] != 0,
+                         src[rows].astype(jnp.float32), 0.0) * scale[:, None]
+
+    if order is None:
+        safe = jnp.minimum(pos, src.shape[0] - 1)
+        out = None
+        for c in range(pos.shape[1]):
+            each = part(safe[:, c], scale[:, c])
+            out = each if out is None else out + each
+        return out
+    in_slot, listed, n, place = order
+
+    def by_slot(of_pair):
+        # [T, k] of a token's pairs → [slots, T] of the listed tokens' slots;
+        # zero where a slot is empty: row 0, scaled by nothing.
+        return jnp.sum(jnp.where(in_slot, of_pair[:, None, :], 0),
+                       axis=-1)[listed].T
+
+    rows, scale = by_slot(pos), by_slot(scale)
+
+    def slot(j, out):
+        # One loop over the slots holds one loop over a slot's segments:
+        # a slot each would be traced and compiled ``slots`` times over.
+        rows_j, scale_j = (jax.lax.dynamic_index_in_dim(a, j, keepdims=False)
+                           for a in (rows, scale))
+        (out,) = _live_rows(
+            lambda acc, at, by: (acc + part(at, by),), (out,),
+            (rows_j, scale_j), jax.lax.dynamic_slice_in_dim(n, j, 1), 1,
+            TOKEN_SEGMENT_ROWS)
+        return out
+
+    out = jax.lax.fori_loop(
+        0, rows.shape[0], slot,
+        jnp.zeros((pos.shape[0], src.shape[1]), jnp.float32))
+    return out[place]
 
 
 @jax.custom_vjp
-def _dispatch(x, row_token, pos, held):
+def _dispatch(x, row_token, pos, held, order):
     """Rows for the experts: ``xs[r] = x[row_token[r]]``. Its transpose is a
     gather too: a token's gradient is the sum over its held pairs' rows."""
-    del pos, held
+    del pos, held, order
     return x[row_token]
 
 
-def _dispatch_fwd(x, row_token, pos, held):
-    return x[row_token], (pos, held)
+def _dispatch_fwd(x, row_token, pos, held, order):
+    return x[row_token], (pos, held, order)
 
 
 def _dispatch_bwd(res, dxs):
-    pos, held = res
-    dx = _gather_sum(dxs, pos, held.astype(jnp.float32)).astype(dxs.dtype)
-    return dx, None, None, None
+    pos, held, order = res
+    dx = _gather_sum(dxs, pos, held.astype(jnp.float32), order)
+    return dx.astype(dxs.dtype), None, None, None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -529,20 +614,22 @@ def _gated_bwd(tile_rows, activation, res, dhidden):
 _gated.defvjp(_gated_fwd, _gated_bwd)
 
 
-def _combined(y, weights, pos, held):
-    return _gather_sum(y, pos, jnp.where(held, weights, 0.0)).astype(y.dtype)
+def _combined(y, weights, pos, held, order):
+    return _gather_sum(y, pos, jnp.where(held, weights, 0.0),
+                       order).astype(y.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
-def _combine(y, weights, pos, held, row_pair, row_live, n_active, tile_rows):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
+def _combine(y, weights, pos, held, order, row_pair, row_live, n_active,
+             tile_rows):
     """``out[t] = Σ_c weights[t, c] · y[pos[t, c]]`` over the held pairs."""
     del row_pair, row_live, n_active
-    return _combined(y, weights, pos, held)
+    return _combined(y, weights, pos, held, order)
 
 
-def _combine_fwd(y, weights, pos, held, row_pair, row_live, n_active,
+def _combine_fwd(y, weights, pos, held, order, row_pair, row_live, n_active,
                  tile_rows):
-    return (_combined(y, weights, pos, held),
+    return (_combined(y, weights, pos, held, order),
             (y, weights, pos, held, row_pair, row_live, n_active))
 
 
@@ -566,7 +653,7 @@ def _combine_bwd(tile_rows, res, dout):
         (row_pair, row_live), n_active, tile_rows)
     dweights = jnp.where(held, dweight_rows[jnp.minimum(pos, y.shape[0] - 1)],
                          0.0).astype(weights.dtype)
-    return dy, dweights, None, None, None, None, None
+    return dy, dweights, None, None, None, None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -596,7 +683,9 @@ def _one_chunk(spec: ExpertSpec, xc, ic, wc, ws, lo, q, dws_so_far, first):
     with jax.named_scope("tony.moe.dispatch"):
         held, pos, row_pair, row_live, tile_expert, n_active, _ = \
             _layout(ic, first, count, spec.tile_rows, rows)
-        xs = _dispatch(xc, row_pair // spec.top_k, pos, held)
+        order = _token_order(held, min(spec.top_k, count)) \
+            if _orders_tokens(spec, count) else None
+        xs = _dispatch(xc, row_pair // spec.top_k, pos, held, order)
     with jax.named_scope("tony.moe.experts"):
         gate, up, down = (
             functools.partial(grouped_matmul, w=w, w_lo=w_lo, w_q=w_q,
@@ -608,8 +697,8 @@ def _one_chunk(spec: ExpertSpec, xc, ic, wc, ws, lo, q, dws_so_far, first):
                         spec.activation)
         y = down(hidden)
     with jax.named_scope("tony.moe.combine"):
-        return _combine(y, wc, pos, held, row_pair, row_live, n_active,
-                        spec.tile_rows)
+        return _combine(y, wc, pos, held, order, row_pair, row_live,
+                        n_active, spec.tile_rows)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
@@ -676,19 +765,36 @@ def routing_counters(spec: ExpertSpec, idx, first, count, token_groups=1,
                      expert_groups=1) -> dict:
     """A step's routing, for experts ``[first, first + count)``: the rows
     (token, choice) that met one of them, the share of tokens none of whose
-    choices did, the fullest expert's rows over the mean, and the share of a
+    choices did, the fullest expert's rows over the mean, the share of a
     chunk's row buffer that is live (the rows that came and their padding, by
-    the layout's own sizes: ``n_active · tile_rows`` over the buffer's rows),
-    a mean over the chunks. Where the tokens are split ``token_groups`` ways
-    and the experts ``expert_groups`` ways over devices, a chunk and a buffer
-    are one device's."""
+    the layout's own sizes: ``n_active · tile_rows`` over the buffer's rows)
+    and the rows the token side gathers a ``_gather_sum`` (the slots' prefixes
+    in whole segments and the gather back, by the order's own counts) over
+    the ``chunk · top_k`` a loop over every choice gathers, which is what a
+    layer that holds every expert does: 1. The last two are means over the
+    chunks. Where the tokens are split ``token_groups`` ways and the experts
+    ``expert_groups`` ways over devices, a chunk and a buffer are one
+    device's."""
     held = (idx >= first) & (idx < first + count)
     chunk = _chunk_tokens(spec, idx.shape[0] // token_groups)
+    here = count // expert_groups               # experts a device
     sizes = jnp.sum((idx.reshape(-1, chunk * idx.shape[1], 1) - first
                      == jnp.arange(count)).astype(jnp.int32), axis=1)
     load = jnp.sum(sizes, axis=0).astype(jnp.float32)
     live = jnp.sum(_padded(sizes, spec.tile_rows).reshape(
-        -1, expert_groups, count // expert_groups), axis=-1)
+        -1, expert_groups, here), axis=-1)
+    gathered = jnp.float32(chunk * spec.top_k)
+    if _orders_tokens(spec, here):
+        # A token's pairs with each device's experts, and from them the
+        # slots' prefixes as ``_token_order`` counts them.
+        mine = jnp.sum(((idx - first)[..., None] // here
+                        == jnp.arange(expert_groups)).astype(jnp.int32),
+                       axis=1).reshape(-1, chunk, expert_groups, 1)
+        n = jnp.sum((mine > jnp.arange(min(spec.top_k, here))
+                     ).astype(jnp.int32), axis=1)
+        seg = min(TOKEN_SEGMENT_ROWS, chunk)
+        gathered = jnp.mean((jnp.sum(-(-n // seg) * seg, axis=-1) + chunk
+                             ).astype(jnp.float32))
     return {
         "moe_rows_routed": jnp.sum(held.astype(jnp.float32)),
         "moe_rows_unrouted_share":
@@ -697,7 +803,8 @@ def routing_counters(spec: ExpertSpec, idx, first, count, token_groups=1,
             jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9),
         "moe_buffer_rows_live_share":
             jnp.mean(live.astype(jnp.float32))
-            / _buffer_rows(chunk, spec, count // expert_groups),
+            / _buffer_rows(chunk, spec, here),
+        "moe_token_rows_gathered_share": gathered / (chunk * spec.top_k),
     }
 
 
@@ -714,7 +821,8 @@ def moe_counters(intermediates) -> dict:
     reduce = {"moe_rows_routed": jnp.sum,
               "moe_rows_unrouted_share": jnp.mean,
               "moe_expert_load_max_over_mean": jnp.max,
-              "moe_buffer_rows_live_share": jnp.mean}
+              "moe_buffer_rows_live_share": jnp.mean,
+              "moe_token_rows_gathered_share": jnp.mean}
     return {name: reduce[name](jnp.stack(values))
             for name, values in found.items()}
 
