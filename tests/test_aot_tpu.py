@@ -324,15 +324,10 @@ def test_the_token_side_gathers_a_slot_for_its_tokens(v5e_devices):
 LAGS_STEP_BYTES = 12_732_606_464
 
 
-@pytest.mark.timeout_s(900)
-def test_the_laguna_cell_s_step_compiles_for_one_v5e_and_fits(v5e_devices):
-    """Laguna-S-2.1's share at published widths (672,125,952 parameters:
-    10.75 GB of state) through ``jit_train_step`` at 2 × 8,192 tokens: the
-    step passes XLA:TPU and Mosaic (heads of 24 and 36 over 4, windowed
-    calls at 512 × 512 tiles, grouped matmuls over 2,560 of 67,584 rows),
-    fits the chip's 16 GB, and holds the Mosaic calls a step needs: 2 full
-    and 3 windowed layers' fwd, dq and dkv, and per sparse layer 9
-    ``moe_gmm`` and 3 ``moe_tgmm`` in the two chunks' loops."""
+def _cell_step(v5e_devices, kind, config, traffic="seq8k-2rows"):
+    """(bytes of the compiled step on one v5e, its Mosaic calls by name) of
+    a cell's whole step as the benchmark builds it: the architecture's
+    ``program.build`` through ``jit_train_step`` at the cell's traffic."""
     import collections
     import json
     import sys
@@ -349,14 +344,14 @@ def test_the_laguna_cell_s_step_compiles_for_one_v5e_and_fits(v5e_devices):
     sys.path.insert(0, cells)
     try:
         import arch
-        program = arch.load(os.path.join(cells, "architectures", "laguna"),
+        program = arch.load(os.path.join(cells, "architectures", kind),
                             "program")
     finally:
         sys.path.remove(cells)
-    with open(os.path.join(cells, "configs", "laguna-s-2.1.json"),
+    with open(os.path.join(cells, "configs", config + ".json"),
               encoding="utf-8") as f:
         cfg = json.load(f)
-    with open(os.path.join(cells, "traffic", "seq8k-2rows.json"),
+    with open(os.path.join(cells, "traffic", traffic + ".json"),
               encoding="utf-8") as f:
         traffic = json.load(f)
     mesh = build_mesh(MeshSpec.from_string(traffic["mesh"]),
@@ -386,10 +381,81 @@ def test_the_laguna_cell_s_step_compiles_for_one_v5e_and_fits(v5e_devices):
     ma = compiled.memory_analysis()
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
              - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    return total, collections.Counter(re.findall(
+        r"%((?:flash|moe|ssd)_[a-z_]+)[.\d]* = ", compiled.as_text()))
+
+
+@pytest.mark.timeout_s(900)
+def test_the_laguna_cell_s_step_compiles_for_one_v5e_and_fits(v5e_devices):
+    """Laguna-S-2.1's share at published widths (672,125,952 parameters:
+    10.75 GB of state) through ``jit_train_step`` at 2 × 8,192 tokens: the
+    step passes XLA:TPU and Mosaic (heads of 24 and 36 over 4, windowed
+    calls at 512 × 512 tiles, grouped matmuls over 2,560 of 67,584 rows),
+    fits the chip's 16 GB, and holds the Mosaic calls a step needs: 2 full
+    and 3 windowed layers' fwd, dq and dkv, and per sparse layer 9
+    ``moe_gmm`` and 3 ``moe_tgmm`` in the two chunks' loops."""
+    total, names = _cell_step(v5e_devices, "laguna", "laguna-s-2.1")
     assert total == LAGS_STEP_BYTES, total
     assert total < 16e9 and total > 0.25 * 16e9
-    names = collections.Counter(re.findall(
-        r"%((?:flash|moe)_[a-z_]+)[.\d]* = ", compiled.as_text()))
     assert names == {"flash_fwd": 2, "flash_dq": 2, "flash_dkv": 2,
                      "flash_win_fwd": 3, "flash_win_dq": 3,
                      "flash_win_dkv": 3, "moe_gmm": 36, "moe_tgmm": 12}
+
+
+# ``nem30b.seq8k``'s step, as ``step_hbm_gb_per_chip.nem30b`` reads it.
+NEM30B_STEP_BYTES = 11_350_111_744
+
+
+@pytest.mark.timeout_s(900)
+def test_the_nemotron_cell_s_step_compiles_for_one_v5e_and_fits(v5e_devices):
+    """Nemotron-3-Nano-30B-A3B's share at published widths (666,962,944
+    parameters: 10.67 GB of state) through ``jit_train_step`` at 2 × 8,192
+    tokens: the step passes XLA:TPU and Mosaic (the scan over 64 heads of 64
+    in 8 groups with a state of 128, groups of 16 q heads a kv head, grouped
+    matmuls at 1,856 columns in tiles that hang over the edge), fits the
+    chip, and holds the Mosaic calls a step needs: a Mamba layer's
+    ``ssd_fwd`` twice (the differentiated forward runs in the forward pass
+    and in the block's recompute) and ``ssd_bwd`` once, one full flash
+    layer, and per sparse layer 6 ``moe_gmm`` and 2 ``moe_tgmm`` in the two
+    chunks' loops."""
+    total, names = _cell_step(v5e_devices, "nemotron_h",
+                              "nemotron-3-nano-30b-a3b")
+    assert total == NEM30B_STEP_BYTES, total
+    assert total < 16e9 and total > 0.25 * 16e9
+    assert names == {"ssd_fwd": 8, "ssd_bwd": 4, "flash_fwd": 1,
+                     "flash_dq": 1, "flash_dkv": 1, "moe_gmm": 24,
+                     "moe_tgmm": 8}
+
+
+def test_the_scan_kernels_compile_at_published_widths(v5e_devices,
+                                                      kernel_operands):
+    """The scan's forward and backward pass Mosaic at the cell's shape (2
+    rows of 8,192 tokens, 64 heads of 64 in 8 groups, state 128, chunks of
+    128, bf16): heads in pairs on whole lane tiles, the transposed decays,
+    the state in VMEM scratch. The forward gives y and each chunk's entering
+    state, and nothing of a ``[chunks, Q, Q]`` shape is an operand or a
+    result of either call."""
+    from tony_tpu.ops.ssd import ssd
+
+    mesh = build_mesh(MeshSpec(), devices=v5e_devices[:1])
+    rows = P(BATCH_AXES)
+    b, s, h, p, g, n = 2, 8192, 64, 64, 8, 128
+    args = (_abstract((b, s, h, p), jnp.bfloat16, mesh, rows),
+            _abstract((b, s, h), jnp.float32, mesh, rows),
+            _abstract((h,), jnp.float32, mesh, P()),
+            _abstract((b, s, g, n), jnp.bfloat16, mesh, rows),
+            _abstract((b, s, g, n), jnp.bfloat16, mesh, rows),
+            _abstract((h,), jnp.float32, mesh, P()))
+
+    def loss(*a):
+        return ssd(*a, impl="kernel").astype(jnp.float32).sum()
+
+    with jax.set_mesh(mesh):
+        hlo = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+            *args).compile().as_text()
+    calls = {c["name"].split(".")[0]: c for c in kernel_operands(hlo)}
+    assert set(calls) == {"ssd_fwd", "ssd_bwd"}, set(calls)
+    assert "f32[2,64,8,128,512]" in hlo        # the entering states
+    for call in calls.values():
+        assert not any(shape[-2:] == [128, 128] for shape in call["shapes"])
+    assert not re.findall(r"\[2,64,[\d,]*128,128\]", hlo)

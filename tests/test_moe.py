@@ -458,7 +458,7 @@ def _routed(spec, int8=False, tokens=32, seed=3):
 
     def through(spec):
         return lambda x, weights, *leaves: routed_experts(
-            spec, x, idx, weights, *leaves, first, jnp.float32, int8=int8)
+            spec, x, idx, weights, leaves, first, jnp.float32, int8=int8)
 
     def dense(x, weights, gate, up, down):
         out = 0

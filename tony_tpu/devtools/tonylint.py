@@ -112,10 +112,11 @@ _KEY_TOKEN_RE = re.compile(
 #: ``tony.*`` names that are no config keys: what the user process calls
 #: its host spans and jit scopes in a profiler trace (telemetry.step and
 #: telemetry.phase, parallel/train.py jit_train_step, models/moe.py,
-#: models/transformer.py).
+#: models/transformer.py, models/ssm.py).
 _TRACE_NAME_RE = re.compile(
     r"^tony\.(step|phase(\.[a-z0-9_\-]+)?|loss_and_grad|optimizer"
-    r"|moe\.(route|dispatch|experts|combine|shared)|attn\.(rope|gate))$")
+    r"|moe\.(route|dispatch|experts|combine|shared)|attn\.(rope|gate)"
+    r"|ssm\.(in_proj|conv|scan|gate_norm|out_proj))$")
 #: dotted tokens whose last segment is one of these are file names
 #: ("job.tony.json", "tony.xml"), not config-key references
 _FILE_EXTS = ("xml", "json", "jsonl", "yaml", "yml", "md", "py", "log",

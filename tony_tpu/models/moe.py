@@ -12,9 +12,12 @@ the shares of all the chips add up to the whole layer
 (``tests/test_moe.py``).
 
 - **Router**: a float32 matmul at ``highest`` precision on the block's
-  normed PRE-attention input (it reads what attention reads), top-k of the
-  logits, softmax over the chosen k. Routing is discrete, so the one matmul
-  whose rounding can move a choice is the one kept exact.
+  normed PRE-attention input (it reads what attention reads) or on the
+  experts' own, top-k of the logits, softmax over the chosen k
+  (``scoring="softmax"``); or sigmoid scores, the k largest chosen and
+  weighted by their own scores over the chosen's sum
+  (``scoring="sigmoid"``). Routing is discrete, so the one matmul whose
+  rounding can move a choice is the one kept exact.
 - **No capacity, no dropped token.** The (token, choice) pairs that met a
   held expert are laid out expert by expert (a counting sort: a cumulative
   sum of one-hots gives each pair its place, one ``argsort`` gives each row
@@ -43,15 +46,20 @@ the shares of all the chips add up to the whole layer
   partial results are summed and scattered back (``psum_scatter``). That is
   "the shares add up" as a collective; no capacity is needed because no
   shard ever receives rows, it selects its own.
-- **A shared expert** (``ExpertSpec.shared_width``) is one more gated
-  feed-forward that every token goes through, beside the routed ones and
-  unweighted. It is what every chip of a deployment computes alike, so it
-  is no shard's part: three dense projections of the experts' input
-  (``shared/gate``, ``shared/up``, ``shared/down``, laid out as the dense
-  ``MLP``'s), outside the ``shard_map`` body and added to the routed sum
-  after the ``psum_scatter``, once. ``routed_scale`` multiplies the routed
-  weights (the softmax of the chosen logits) and leaves the shared expert
-  alone.
+- **Experts of three matrices or of two.** Gated, an expert is
+  ``down(act(gate x) · up x)``; with ``ExpertSpec.gated`` off it is
+  ``down(act(up x))`` and the layer has no ``gate`` leaf: six ``moe_gmm``
+  and two ``moe_tgmm`` calls a chunk where the gated layer makes nine and
+  three, and the chunk loop's backward carries two sums.
+- **A shared expert** (``ExpertSpec.shared_width``, a width of its own) is
+  one more feed-forward of the experts' kind that every token goes through,
+  beside the routed ones and unweighted. It is what every chip of a
+  deployment computes alike, so it is no shard's part: dense projections of
+  the experts' input (``shared/gate`` where gated, ``shared/up``,
+  ``shared/down``, laid out as the dense ``MLP``'s), outside the
+  ``shard_map`` body and added to the routed sum after the
+  ``psum_scatter``, once. ``routed_scale`` multiplies the routed weights
+  and leaves the shared expert alone.
 
 Spans: ``tony.moe.route``, ``tony.moe.dispatch``, ``tony.moe.experts``,
 ``tony.moe.combine``, ``tony.moe.shared`` (``jax.named_scope``). Counters,
@@ -83,7 +91,9 @@ from tony_tpu.ops.quant import (INT8, dense, quantize_symmetric,
 from tony_tpu.parallel.mesh import BATCH_AXES
 
 EP_AXIS = "ep"
-ACTIVATIONS = {"silu": nn.silu, "relu": nn.relu}
+ACTIVATIONS = {"silu": nn.silu, "relu": nn.relu,
+               "relu2": lambda x: jnp.square(nn.relu(x))}
+SCORINGS = ("softmax", "sigmoid")
 # Mosaic's scoped VMEM default (16 MiB) is under a [2560, 768] expert
 # matrix double-buffered beside its row tiles; the v5e has 128 MiB.
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
@@ -98,11 +108,14 @@ TOKEN_SEGMENT_ROWS = 256
 @dataclasses.dataclass(frozen=True)
 class ExpertSpec:
     """A layer's sparse feed-forward: ``n_experts`` router outputs,
-    ``top_k`` experts a token, gated experts of hidden ``width`` with
-    ``activation`` on the gate, and ``held = (first, count)``, the experts
-    that live here (None: all of them). ``shared_width``: a shared expert
-    of that hidden width beside them (None: none); ``routed_scale``: the
-    factor on the routed experts' weights."""
+    ``top_k`` experts a token, experts of hidden ``width``, ``gated``
+    (``down(act(gate x) · up x)``, three matrices) or not (``down(act(up
+    x))``, two), and ``held = (first, count)``, the experts that live here
+    (None: all of them). ``shared_width``: a shared expert of that hidden
+    width beside them (None: none); ``routed_scale``: the factor on the
+    routed experts' weights. ``scoring``: the weights are the softmax of the
+    chosen logits, or (``"sigmoid"``) each chosen expert's sigmoid score
+    over the sum of the chosen's."""
     n_experts: int
     top_k: int
     width: int
@@ -115,6 +128,14 @@ class ExpertSpec:
     chunk_tokens: int = 8192    # tokens routed at a time
     shared_width: Optional[int] = None
     routed_scale: float = 1.0
+    gated: bool = True
+    scoring: str = "softmax"
+
+    @property
+    def into(self) -> Tuple[str, ...]:
+        """The matrices that read an expert's input, by their leaves'
+        names; ``down`` reads what they give."""
+        return ("gate", "up") if self.gated else ("up",)
 
     def __post_init__(self):
         first, count = self.held or (0, self.n_experts)
@@ -127,6 +148,9 @@ class ExpertSpec:
         if self.activation not in ACTIVATIONS:
             raise ValueError(f"activation {self.activation!r} is not one "
                              f"of {sorted(ACTIVATIONS)}")
+        if self.scoring not in SCORINGS:
+            raise ValueError(f"scoring {self.scoring!r} is not one of "
+                             f"{SCORINGS}")
 
 
 class MoEConfig:
@@ -200,12 +224,14 @@ def _tgmm_kernel(tile_expert, n_active, lhs_ref, rhs_ref, *rest):
 
 
 def _col_tile(n: int, want: int = 1024) -> int:
-    """Largest multiple of 128 that divides ``n`` and is at most ``want``;
-    ``n`` itself where it has no such divisor (tiny test widths)."""
-    for t in range(min(n, want) // 128 * 128, 0, -128):
-        if n % t == 0:
-            return t
-    return n
+    """The column tile of ``n`` columns: a multiple of 128 of at most
+    ``want`` that covers them in the fewest columns, the largest such (the
+    largest divisor where one divides ``n``; where none does, as at 1,856,
+    the last tile hangs over the edge, and since a product's columns do not
+    mix, what it computes there is never written). ``n`` itself under 128
+    (tiny test widths)."""
+    tiles = range(min(n, want) // 128 * 128, 0, -128)
+    return min(tiles, key=lambda t: (-(-n // t) * t, -t), default=n)
 
 
 def _live_row(i, n_active):
@@ -253,7 +279,7 @@ def _gmm_call(lhs, rhs, tile_expert, n_active, *, tile_rows: int,
         functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(n // tn, tiles),
+            grid=(pl.cdiv(n, tn), tiles),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((tile_rows, tn),
                                    lambda j, i, te, na: (row(i, na), j)),
@@ -297,7 +323,7 @@ def _tgmm_call(lhs, rhs, tile_expert, n_active, *, tile_rows: int,
         _tgmm_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(n // tn, tiles),
+            grid=(pl.cdiv(n, tn), tiles),
             in_specs=in_specs,
             out_specs=out_spec,
         ),
@@ -581,37 +607,37 @@ def _for_gate_and_up_bwd(tile_rows, n_active, dboth):
 _for_gate_and_up.defvjp(_for_gate_and_up_fwd, _for_gate_and_up_bwd)
 
 
-def _gate_times_up(activation: str):
+def _hidden_of(activation: str, gated: bool):
     act = ACTIVATIONS[activation]
-    return lambda gate, up: act(gate) * up
+    return (lambda gate, up: act(gate) * up) if gated else act
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _gated(gate, up, n_active, tile_rows, activation):
-    """``act(gate) · up``. Forward it is one fused pass over the whole
-    buffer (a loop would first fill a fresh buffer with zeros, which costs
-    what the dead rows do); its transpose works over the live rows, in the
-    buffers of ``gate`` and ``up``."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _hidden(pre, n_active, tile_rows, activation):
+    """``act(gate) · up`` of ``pre = (gate, up)``, or ``act(up)`` of ``pre =
+    (up,)``. Forward it is one fused pass over the whole buffer (a loop
+    would first fill a fresh buffer with zeros, which costs what the dead
+    rows do); its transpose works over the live rows, in the buffers of
+    ``pre``."""
     del n_active
-    return _gate_times_up(activation)(gate, up)
+    return _hidden_of(activation, len(pre) == 2)(*pre)
 
 
-def _gated_fwd(gate, up, n_active, tile_rows, activation):
-    return _gate_times_up(activation)(gate, up), (gate, up, n_active)
+def _hidden_fwd(pre, n_active, tile_rows, activation):
+    return _hidden_of(activation, len(pre) == 2)(*pre), (pre, n_active)
 
 
-def _gated_bwd(tile_rows, activation, res, dhidden):
-    gate, up, n_active = res
+def _hidden_bwd(tile_rows, activation, res, dhidden):
+    pre, n_active = res
 
-    def transpose(g, u, dh):    # what autodiff writes, a segment at a time
-        return jax.vjp(_gate_times_up(activation), g, u)[1](dh)
+    def transpose(*rows):       # what autodiff writes, a segment at a time
+        *pre, dh = rows
+        return jax.vjp(_hidden_of(activation, len(pre) == 2), *pre)[1](dh)
 
-    dgate, dup = _live_rows(transpose, (gate, up), (dhidden,), n_active,
-                            tile_rows)
-    return dgate, dup, None
+    return _live_rows(transpose, pre, (dhidden,), n_active, tile_rows), None
 
 
-_gated.defvjp(_gated_fwd, _gated_bwd)
+_hidden.defvjp(_hidden_fwd, _hidden_bwd)
 
 
 def _combined(y, weights, pos, held, order):
@@ -677,7 +703,8 @@ def _chunk_tokens(spec: ExpertSpec, tokens: int) -> int:
 def _one_chunk(spec: ExpertSpec, xc, ic, wc, ws, lo, q, dws_so_far, first):
     """A chunk's tokens ``xc [chunk, D]`` through the experts held here;
     ``ws``, ``lo``, ``q`` and ``dws_so_far`` are ``grouped_matmul``'s ``w``,
-    ``w_lo``, ``w_q`` and ``dw_so_far`` for gate, up and down."""
+    ``w_lo``, ``w_q`` and ``dw_so_far`` for gate, up and down, or for up and
+    down where the experts are not gated."""
     count = lo[0].shape[0]
     rows = _buffer_rows(xc.shape[0], spec, count)
     with jax.named_scope("tony.moe.dispatch"):
@@ -687,15 +714,18 @@ def _one_chunk(spec: ExpertSpec, xc, ic, wc, ws, lo, q, dws_so_far, first):
             if _orders_tokens(spec, count) else None
         xs = _dispatch(xc, row_pair // spec.top_k, pos, held, order)
     with jax.named_scope("tony.moe.experts"):
-        gate, up, down = (
+        *into, down = (
             functools.partial(grouped_matmul, w=w, w_lo=w_lo, w_q=w_q,
                               dw_so_far=dw, tile_expert=tile_expert,
                               n_active=n_active, tile_rows=spec.tile_rows)
             for w, w_lo, w_q, dw in zip(ws, lo, q, dws_so_far))
-        xs_gate, xs_up = _for_gate_and_up(xs, n_active, spec.tile_rows)
-        hidden = _gated(gate(xs_gate), up(xs_up), n_active, spec.tile_rows,
-                        spec.activation)
-        y = down(hidden)
+        if spec.gated:
+            gate, up = into
+            xs_gate, xs_up = _for_gate_and_up(xs, n_active, spec.tile_rows)
+            pre = (gate(xs_gate), up(xs_up))
+        else:
+            pre = (into[0](xs),)
+        y = down(_hidden(pre, n_active, spec.tile_rows, spec.activation))
     with jax.named_scope("tony.moe.combine"):
         return _combine(y, wc, pos, held, order, row_pair, row_live,
                         n_active, spec.tile_rows)
@@ -704,7 +734,8 @@ def _one_chunk(spec: ExpertSpec, xc, ic, wc, ws, lo, q, dws_so_far, first):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
 def _chunks(spec: ExpertSpec, int8: bool, x, idx, weights, ws, first):
     """``_one_chunk`` over the leading dim of ``x [n, chunk, D]``, ``idx``
-    and ``weights [n, chunk, k]``, the three matrices ``ws`` cast to ``x``'s
+    and ``weights [n, chunk, k]``, the experts' matrices ``ws`` (three, or
+    two where they are not gated) cast to ``x``'s
     dtype (and to int8 where ``int8`` says so) once for all chunks. The
     backward is a loop of its own and not the transpose autodiff makes of
     this one, which would add each chunk's float32 ``[count, K, N]`` weight
@@ -718,7 +749,7 @@ def _chunks_fwd(spec, int8, x, idx, weights, ws, first):
     q = tuple(quantize_symmetric(w, INT8, axis=1) if int8 else None
               for w in lo)
     out = jax.lax.map(
-        lambda c: _one_chunk(spec, *c, ws, lo, q, (None,) * 3, first),
+        lambda c: _one_chunk(spec, *c, ws, lo, q, (None,) * len(ws), first),
         (x, idx, weights))
     # A chunk keeps nothing for its backward but its inputs: what it kept
     # would be stacked over the chunks, which is the buffer chunks avoid.
@@ -746,18 +777,19 @@ def _chunks_bwd(spec, int8, res, dout):
 _chunks.defvjp(_chunks_fwd, _chunks_bwd)
 
 
-def routed_experts(spec: ExpertSpec, x, idx, weights, w_gate, w_up, w_down,
-                   first, dtype, int8: bool = False):
+def routed_experts(spec: ExpertSpec, x, idx, weights, ws, first, dtype,
+                   int8: bool = False):
     """What the experts ``[first, first + count)`` (``count`` from the
-    weights' leading dim) add for tokens ``x [T, D]`` whose choices are
-    ``idx [T, k]`` with ``weights [T, k]``: ``[T, D]`` in ``dtype``, the
-    three forward products in int8 where ``int8`` says so. The tokens go
-    ``_chunk_tokens`` at a time; one chunk is a loop of one trip."""
+    leading dim of their matrices ``ws``: gate, up and down, or up and down)
+    add for tokens ``x [T, D]`` whose choices are ``idx [T, k]`` with
+    ``weights [T, k]``: ``[T, D]`` in ``dtype``, the forward products in
+    int8 where ``int8`` says so. The tokens go ``_chunk_tokens`` at a time;
+    one chunk is a loop of one trip."""
     t, d = x.shape
     n = t // _chunk_tokens(spec, t)
     parts = _chunks(spec, int8, x.astype(dtype).reshape(n, -1, d),
                     idx.reshape(n, t // n, -1), weights.reshape(n, t // n, -1),
-                    (w_gate, w_up, w_down), first)
+                    tuple(ws), first)
     return parts.reshape(t, d)
 
 
@@ -828,9 +860,10 @@ def moe_counters(intermediates) -> dict:
 
 
 class SharedExpert(nn.Module):
-    """The gated feed-forward every token goes through: the dense ``MLP``
-    at the shared expert's width, ``matmul_dtype`` covering its three
-    projections as it covers that one's."""
+    """The feed-forward every token goes through: the dense ``MLP`` at the
+    shared expert's width (without its ``gate`` where the experts are not
+    gated), ``matmul_dtype`` covering its projections as it covers that
+    one's."""
 
     spec: ExpertSpec
     dtype: jnp.dtype
@@ -843,17 +876,17 @@ class SharedExpert(nn.Module):
                                  param_dtype=self.param_dtype,
                                  matmul_dtype=self.matmul_dtype)
         width = self.spec.shared_width
-        gate = proj(width, ("embed", "mlp"), "gate")(x)
-        up = proj(width, ("embed", "mlp"), "up")(x)
+        pre = tuple(proj(width, ("embed", "mlp"), name)(x)
+                    for name in self.spec.into)
         h = nn.with_logical_constraint(
-            ACTIVATIONS[self.spec.activation](gate) * up,
+            _hidden_of(self.spec.activation, self.spec.gated)(*pre),
             ("batch", "seq", "mlp"))
         return proj(x.shape[-1], ("mlp", "embed"), "down")(h)
 
 
 class ExpertLayer(nn.Module):
-    """Top-k routed gated experts, and the shared expert beside them where
-    the spec has one. ``router_in`` is what the router reads
+    """Top-k routed experts, and the shared expert beside them where the
+    spec has one. ``router_in`` is what the router reads
     (the block's normed pre-attention input, or the same tensor as ``x`` for
     a router after attention); ``x`` is what the experts read."""
 
@@ -890,9 +923,9 @@ class ExpertLayer(nn.Module):
                 nn.initializers.lecun_normal(batch_axis=(0,)), axes), shape,
                 self.param_dtype)
 
-        w_gate = w("gate", (count, d, spec.width), ("expert", "embed", "mlp"))
-        w_up = w("up", (count, d, spec.width), ("expert", "embed", "mlp"))
-        w_down = w("down", (count, spec.width, d), ("expert", "mlp", "embed"))
+        ws = tuple(w(name, (count, d, spec.width), ("expert", "embed", "mlp"))
+                   for name in spec.into) \
+            + (w("down", (count, spec.width, d), ("expert", "mlp", "embed")),)
 
         # Mosaic kernels cannot be partitioned automatically: under a bound
         # mesh the body runs in a shard_map manual over every axis that is
@@ -918,8 +951,12 @@ class ExpertLayer(nn.Module):
             logits = jnp.dot(router_in.reshape(t, d).astype(jnp.float32),
                              router.astype(jnp.float32),
                              precision=jax.lax.Precision.HIGHEST)
-            top, idx = jax.lax.top_k(logits, spec.top_k)
-            weights = jax.nn.softmax(top, axis=-1)
+            if spec.scoring == "softmax":
+                top, idx = jax.lax.top_k(logits, spec.top_k)
+                weights = jax.nn.softmax(top, axis=-1)
+            else:
+                top, idx = jax.lax.top_k(jax.nn.sigmoid(logits), spec.top_k)
+                weights = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
             if spec.routed_scale != 1.0:
                 weights = weights * spec.routed_scale
             for name, value in routing_counters(
@@ -939,19 +976,17 @@ class ExpertLayer(nn.Module):
 
         xt = x.reshape(t, d)
         if not auto:
-            return with_shared(routed(spec, xt, idx, weights, w_gate, w_up,
-                                      w_down, first, self.dtype))
+            return with_shared(routed(spec, xt, idx, weights, ws, first,
+                                      self.dtype))
 
-        def shard(xt, idx, weights, w_gate, w_up, w_down):
+        def shard(xt, idx, weights, *ws):
             if n_ep == 1:
-                return routed(spec, xt, idx, weights, w_gate, w_up, w_down,
-                              first, self.dtype)
+                return routed(spec, xt, idx, weights, ws, first, self.dtype)
             # Every shard has all of its group's rows (they are not split
             # over ep) and selects its own experts' pairs; the shares add
             # up, and each shard keeps a slice of the sum.
             mine = jax.lax.axis_index(EP_AXIS) * (count // n_ep)
-            part = routed(spec, xt, idx, weights, w_gate, w_up, w_down,
-                          mine, self.dtype)
+            part = routed(spec, xt, idx, weights, ws, mine, self.dtype)
             return jax.lax.psum_scatter(part, EP_AXIS, scatter_dimension=0,
                                         tiled=True)
 
@@ -959,9 +994,9 @@ class ExpertLayer(nn.Module):
         held_w = P(EP_AXIS) if n_ep > 1 else P()
         out = jax.shard_map(
             shard, axis_names=set(auto),
-            in_specs=(tok, tok, tok, held_w, held_w, held_w),
+            in_specs=(tok, tok, tok) + (held_w,) * len(ws),
             out_specs=P(rows + (EP_AXIS,)) if n_ep > 1 else tok,
-            check_vma=False)(xt, idx, weights, w_gate, w_up, w_down)
+            check_vma=False)(xt, idx, weights, *ws)
         return with_shared(out)
 
 

@@ -11,15 +11,19 @@ Design choices map straight onto TPU hardware:
 - static shapes and `remat`-friendly block structure (scan over layers is
   deliberately NOT used so pipeline stages can slice layers later);
 - one loop over layers that may differ: ``TransformerConfig.layers`` says
-  of each its attention mask (full causal or a window), its head counts,
-  its RoPE (none, or a ``RopeSpec``: θ, the share of a head's columns
-  rotated, a YaRN table), whether attention's output is gated per head,
-  and its feed-forward (the dense ``MLP`` or sparse experts,
-  ``models/moe.py``). Without it every layer is the dense default.
+  of each its mixer (attention, a state-space mixer, ``models/ssm.py``, or
+  none) and its feed-forward (the dense ``MLP``, sparse experts,
+  ``models/moe.py``, or none); of an attention mixer its mask (full causal
+  or a window), its head counts, its RoPE (none, or a ``RopeSpec``: θ, the
+  share of a head's columns rotated, a YaRN table) and whether its output
+  is gated per head. Every part that is there has one norm before it and
+  one residual around it, so a layer may be one part alone. Without
+  ``layers`` every layer is the default: attention, then the dense MLP.
 
-Spans (``jax.named_scope``): ``tony.attn.rope``, ``tony.attn.gate``.
-Counter, sown into ``intermediates`` and reduced by ``layer_counters``:
-``attn_gate_mean``.
+Spans (``jax.named_scope``): ``tony.attn.rope``, ``tony.attn.gate``; the
+state-space mixer's are in ``models/ssm.py``. Counters, sown into
+``intermediates`` and reduced by ``layer_counters``: ``attn_gate_mean``,
+``ssm_dt_mean``, ``ssm_decay_mean``.
 """
 
 from __future__ import annotations
@@ -34,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tony_tpu.models.moe import ExpertLayer, ExpertSpec, moe_counters
+from tony_tpu.models.ssm import SSMixer, SSMSpec
 from tony_tpu.ops import quant
 from tony_tpu.ops.attention import (FLASH_RESIDUAL_NAMES, flash_attention,
                                     reference_attention)
@@ -94,20 +99,37 @@ class RopeSpec:
 
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
-    """One layer of the stack. ``window=w``: query i attends keys
-    i − w < j ≤ i (None: the full causal triangle). ``rope``: True is RoPE
-    at ``cfg.rope_theta`` over the whole head, False no position embedding
-    on this layer's q and k, a ``RopeSpec`` the layer's own. ``n_heads``:
-    the layer's q heads over ``cfg.n_kv_heads`` (None: the config's).
-    ``gate``: attention's output is scaled, a head and token, by the
-    sigmoid of a projection ``wg`` of the layer's normed input.
-    ``experts``: the sparse feed-forward in place of the dense ``MLP``
-    (None: dense, at ``cfg.mlp_dim``)."""
+    """One layer of the stack: a mixer, a feed-forward, or both, each
+    ``x + part(norm(x))``. ``mixer``: ``"attention"``, an ``SSMSpec`` (the
+    state-space mixer of those sizes) or None. Of an attention mixer,
+    ``window=w``: query i attends keys i − w < j ≤ i (None: the full causal
+    triangle). ``rope``: True is RoPE at ``cfg.rope_theta`` over the whole
+    head, False no position embedding on this layer's q and k, a
+    ``RopeSpec`` the layer's own. ``n_heads``: the layer's q heads over
+    ``cfg.n_kv_heads`` (None: the config's). ``gate``: attention's output is
+    scaled, a head and token, by the sigmoid of a projection ``wg`` of the
+    layer's normed input. ``feed_forward``: False leaves the layer without
+    one; otherwise ``experts`` is the sparse feed-forward in place of the
+    dense ``MLP`` (None: dense, at ``cfg.mlp_dim``)."""
     window: Optional[int] = None
     rope: Union[bool, RopeSpec] = True
     experts: Optional[ExpertSpec] = None
     n_heads: Optional[int] = None
     gate: bool = False
+    mixer: Union[str, SSMSpec, None] = "attention"
+    feed_forward: bool = True
+
+    def __post_init__(self):
+        if not (self.mixer in (None, "attention")
+                or isinstance(self.mixer, SSMSpec)):
+            raise ValueError(f"mixer {self.mixer!r} is neither 'attention', "
+                             f"an SSMSpec nor None")
+        if self.mixer is None and not self.feed_forward:
+            raise ValueError("a layer without a mixer and without a "
+                             "feed-forward has no part")
+        if self.experts is not None and not self.feed_forward:
+            raise ValueError("experts are a feed-forward: feed_forward=False "
+                             "names none")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -348,17 +370,36 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, positions):
         cfg, spec = self.cfg, self.spec
-        n = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="attn_norm")(x)
-        h = x + Attention(cfg, spec, name="attn")(n, positions)
-        m = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="mlp_norm")(h)
-        if spec.experts is None:
-            out = h + MLP(cfg, name="mlp")(m)
-        else:
-            # The router may read what attention reads (the normed block
-            # input); the experts read the post-attention normed state.
-            out = h + ExpertLayer(spec.experts, cfg.dtype, cfg.param_dtype,
-                                  cfg.matmul_dtype or "", name="moe")(
-                n if spec.experts.route_before_attention else m, m)
+
+        def norm(name):
+            return RMSNorm(cfg.norm_eps, cfg.param_dtype, name=name)
+
+        n, h = None, x
+        if spec.mixer == "attention":
+            n = norm("attn_norm")(x)
+            h = x + Attention(cfg, spec, name="attn")(n, positions)
+        elif spec.mixer is not None:
+            if cfg.attn_impl in ("ring", "ulysses"):
+                raise ValueError(
+                    f"attn_impl {cfg.attn_impl!r} splits the sequence over "
+                    f"the sp axis, and {self.name}'s state-space mixer hands "
+                    f"its state along the whole row")
+            h = x + SSMixer(spec.mixer, cfg.dtype, cfg.param_dtype,
+                            cfg.matmul_dtype or "", cfg.norm_eps,
+                            name="ssm")(norm("ssm_norm")(x))
+        out = h
+        if spec.feed_forward:
+            m = norm("mlp_norm")(h)
+            if spec.experts is None:
+                out = h + MLP(cfg, name="mlp")(m)
+            else:
+                # The router may read what attention reads (the normed
+                # block input); the experts read the normed state after the
+                # mixer.
+                before = spec.experts.route_before_attention and n is not None
+                out = h + ExpertLayer(
+                    spec.experts, cfg.dtype, cfg.param_dtype,
+                    cfg.matmul_dtype or "", name="moe")(n if before else m, m)
         return nn.with_logical_constraint(out, ("batch", "seq", "embed"))
 
 
@@ -444,16 +485,18 @@ class Transformer(nn.Module):
 
 def layer_counters(intermediates) -> dict:
     """What the layers sowed, as one dict of scalars for a step's aux
-    metrics: the expert layers' counters (``moe_counters``) and
-    ``attn_gate_mean``, the mean of the per-head output gates over heads,
-    tokens and gated layers. {} where nothing was sown."""
+    metrics: the expert layers' counters (``moe_counters``) and the means
+    over the layers that sowed them of ``attn_gate_mean`` (the per-head
+    output gates, over heads and tokens), ``ssm_dt_mean`` and
+    ``ssm_decay_mean`` (a state-space mixer's steps Δ and decays
+    ``exp(Δ·A)``, over heads and tokens). {} where nothing was sown."""
     out = moe_counters(intermediates)
-    gates = [value for path, value in
-             jax.tree_util.tree_leaves_with_path(intermediates)
-             if any(getattr(k, "key", None) == "attn_gate_mean"
-                    for k in path)]
-    if gates:
-        out["attn_gate_mean"] = jnp.mean(jnp.stack(gates))
+    for name in ("attn_gate_mean", "ssm_dt_mean", "ssm_decay_mean"):
+        sown = [value for path, value in
+                jax.tree_util.tree_leaves_with_path(intermediates)
+                if any(getattr(k, "key", None) == name for k in path)]
+        if sown:
+            out[name] = jnp.mean(jnp.stack(sown))
     return out
 
 

@@ -1,0 +1,383 @@
+"""The chunked state-space scan (Mamba-2's SSD) as Pallas TPU kernels.
+
+A head ``h`` of width ``P`` in group ``g`` carries a state ``S ∈ R^{N×P}``
+along the sequence::
+
+    S_t = a_t · S_{t−1} + Δ_t · B_{g,t} x_tᵀ        a_t = exp(Δ_t · A_h)
+    y_t = S_tᵀ C_{g,t} + D_h · x_t                   S_0 = 0 at a row's start
+
+Token by token that is ``S`` sequential steps of rank-one updates. The
+chunked algorithm does the same sums a chunk of ``Q`` tokens at a time, as
+matmuls: with ``cum_i = Σ_{k≤i} log a_k`` inside a chunk,
+
+- within the chunk ``Y = (L ∘ C Bᵀ ∘ Δ_j) X``, ``L_ij = exp(cum_i − cum_j)``
+  for ``j ≤ i``;
+- the chunk leaves ``S_out = exp(cum_Q) · S_in + (B ∘ w)ᵀ X`` with
+  ``w_j = exp(cum_Q − cum_j) · Δ_j``;
+- what came before the chunk adds ``exp(cum_i) · (C S_in)_i``.
+
+Forward and backward are one Mosaic call each, named ``ssd_fwd`` and
+``ssd_bwd``, under one ``jax.custom_vjp``. Their grid is ``(batch, group,
+chunk)``, the chunk axis last and sequential: the state (forward) or its
+cotangent (backward, chunks in reverse) lives in VMEM scratch from chunk to
+chunk. A grid step has all the heads of one group, so ``C Bᵀ`` is one
+product a group and chunk, and nothing of shape ``[chunks, Q, Q]`` ever
+leaves VMEM. The forward also writes each chunk's entering state (float32
+``[B, chunks, G, N, heads·P]``), which the backward reads. (A training
+step's forward pass is the differentiated one, under a block's remat too,
+so a forward without that output would serve evaluation alone.)
+
+Heads narrower than the 128 lanes are worked on in packs of ``128 // P``
+side by side: every load, store and matmul operand is a whole number of
+lane tiles, and a pack's heads are told apart by lane masks (a product
+``M_h @ X_pack`` computes a whole tile either way).
+
+``A`` must be negative and ``Δ`` positive (decays in (0, 1]): the mixer's
+``−exp(A_log)`` and ``softplus``. The decays, their cumulative sums and the
+state are float32; ``x``, ``B`` and ``C`` multiply in their own dtype.
+
+``impl="jnp"`` is the same chunked algorithm in plain ``jax.numpy``, autodiff
+its backward: the path off the TPU (an interpreted kernel is slow) and the
+kernels' test oracle beside the token-by-token recurrence.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from tony_tpu.compat import per_shard
+from tony_tpu.ops.attention import _interpret, _prec
+from tony_tpu.parallel.mesh import BATCH_AXES
+
+LANES = 128
+MASKED = -1e30      # log-decay of a pair the causal mask drops
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+
+def _pack(heads: int, p: int) -> int:
+    """Heads worked on side by side: the most that divide a group's heads
+    and fit the 128 lanes together."""
+    return max(n for n in range(1, heads + 1)
+               if heads % n == 0 and (n == 1 or n * p <= LANES))
+
+
+def _f32(x):
+    return x.astype(jnp.float32)
+
+
+def _dot(a, b, dims, prec):
+    return jax.lax.dot_general(a, b, (dims, ((), ())),
+                               preferred_element_type=jnp.float32,
+                               precision=prec)
+
+
+def _chunk_masks(q: int):
+    rows = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    last = jax.lax.broadcasted_iota(jnp.int32, (1, q), 1) == q - 1
+    return rows >= cols, last
+
+
+def _head_decays(cum_ref, dt_ref, h: int, causal, last):
+    """A head's row ``h`` of the chunk's cumulative log-decays and steps, as
+    rows ``[1, Q]`` and columns ``[Q, 1]``, the chunk's total ``[1, 1]`` and
+    the masked decay matrix ``L [Q, Q]``."""
+    cum_r, dt_r = cum_ref[0, 0, 0, h:h + 1, :], dt_ref[0, 0, 0, h:h + 1, :]
+    cum_c, dt_c = jnp.transpose(cum_r), jnp.transpose(dt_r)
+    total = jnp.sum(jnp.where(last, cum_r, 0.0), axis=1, keepdims=True)
+    decay = jnp.exp(jnp.where(causal, cum_c - cum_r, MASKED))
+    return cum_r, dt_r, cum_c, dt_c, total, decay
+
+
+def _group_chunk(x_ref, b_ref, c_ref, pack: int, p: int):
+    """What a grid step's heads share: the group's B and C ``[Q, N]``, ``C
+    Bᵀ [Q, Q]``, the chunk's masks, a pack's lanes, and the dtype and
+    precision the products run in."""
+    bm, cm = b_ref[0], c_ref[0]
+    prec = _prec(x_ref)
+    causal, last = _chunk_masks(bm.shape[0])
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, pack * p), 1)
+    return (bm, cm, _dot(cm, bm, ((1,), (1,)), prec), causal, last, lane,
+            x_ref.dtype, prec)
+
+
+def _fwd_kernel(x_ref, b_ref, c_ref, cum_ref, dt_ref, d_ref, y_ref, s_in_ref,
+                state, *, heads: int, p: int, pack: int):
+    @pl.when(pl.program_id(2) == 0)
+    def _row_start():
+        state[...] = jnp.zeros_like(state)
+
+    s_in_ref[0, 0, 0] = state[...]
+    bm, cm, cb, causal, last, lane, dtype, prec = _group_chunk(
+        x_ref, b_ref, c_ref, pack, p)
+    q, width = bm.shape[0], pack * p
+    for k in range(heads // pack):
+        cols = slice(k * width, (k + 1) * width)
+        x2, s2 = x_ref[0, :, cols], state[:, cols]          # [Q, w], [N, w]
+        within = jnp.zeros((q, width), jnp.float32)
+        before = jnp.zeros((q, width), jnp.float32)   # exp(cum_i) a head
+        s_new = jnp.zeros_like(s2)
+        for j in range(pack):
+            mine = (lane >= j * p) & (lane < (j + 1) * p)
+            _, dt_r, cum_c, dt_c, total, decay = _head_decays(
+                cum_ref, dt_ref, k * pack + j, causal, last)
+            m = (cb * decay * dt_r).astype(dtype)
+            within = jnp.where(mine, _dot(m, x2, ((1,), (0,)), prec), within)
+            before = jnp.where(mine, jnp.exp(cum_c), before)
+            bw = (_f32(bm) * (jnp.exp(total - cum_c) * dt_c)).astype(dtype)
+            s_new = jnp.where(
+                mine, jnp.exp(total) * s2 + _dot(bw, x2, ((0,), (0,)), prec),
+                s_new)
+        carried = _dot(cm, s2.astype(dtype), ((1,), (0,)), prec)   # C S_in
+        y_ref[0, :, cols] = (within + before * carried
+                             + d_ref[0, :, cols] * _f32(x2)).astype(
+                                 y_ref.dtype)
+        state[:, cols] = s_new
+
+
+def _bwd_kernel(x_ref, b_ref, c_ref, cum_ref, dt_ref, d_ref, s_in_ref, dy_ref,
+                dx_ref, db_ref, dc_ref, dcum_ref, ddt_ref, dd_ref, dstate, *,
+                heads: int, p: int, pack: int):
+    @pl.when(pl.program_id(2) == 0)     # a row's last chunk: nothing follows
+    def _row_end():
+        dstate[...] = jnp.zeros_like(dstate)
+        dd_ref[...] = jnp.zeros_like(dd_ref)
+
+    bm, cm, cb, causal, last, lane, dtype, prec = _group_chunk(
+        x_ref, b_ref, c_ref, pack, p)
+    q, width = bm.shape[0], pack * p
+    b32 = _f32(bm)
+    dcb = jnp.zeros((q, q), jnp.float32)
+    db = jnp.zeros(bm.shape, jnp.float32)
+    dc = jnp.zeros(cm.shape, jnp.float32)
+    for k in range(heads // pack):
+        cols = slice(k * width, (k + 1) * width)
+        x2, dy2 = x_ref[0, :, cols], dy_ref[0, :, cols]     # [Q, w]
+        s2, ds2 = s_in_ref[0, 0, 0, :, cols], dstate[:, cols]   # [N, w] f32
+        s2_lo, ds2_lo = s2.astype(dtype), ds2.astype(dtype)
+        dy32 = _f32(dy2)
+        dy_carried = dy32 * _dot(cm, s2_lo, ((1,), (0,)), prec)  # dy ∘ C S_in
+        ds_s = ds2 * s2
+        dx = d_ref[0, :, cols] * dy32
+        before = jnp.zeros((q, width), jnp.float32)
+        kept = jnp.zeros((1, width), jnp.float32)       # exp(cum_Q) a head
+        for j in range(pack):
+            h = k * pack + j
+            mine = (lane >= j * p) & (lane < (j + 1) * p)
+            _, dt_r, cum_c, dt_c, total, decay = _head_decays(
+                cum_ref, dt_ref, h, causal, last)
+            cbl = cb * decay
+            m = (cbl * dt_r).astype(dtype)
+            x_h = jnp.where(mine, x2, jnp.zeros_like(x2))
+            dy_h = jnp.where(mine, dy2, jnp.zeros_like(dy2))
+            before_c = jnp.exp(cum_c)
+            tail_c = jnp.exp(total - cum_c)
+            w_c = tail_c * dt_c
+            bw = (b32 * w_c).astype(dtype)
+            # x: through the chunk's own pairs, and into the state it leaves
+            dx = dx + jnp.where(
+                mine, _dot(m, dy2, ((0,), (0,)), prec)
+                + _dot(bw, ds2_lo, ((1,), (0,)), prec), 0.0)
+            # the chunk's own pairs: M = C Bᵀ ∘ L ∘ Δ_j
+            dm = _dot(dy_h, x_h, ((1,), (1,)), prec)        # [Q, Q]
+            dcb = dcb + dm * decay * dt_r
+            v = dm * cbl
+            ddt_r = jnp.sum(v, axis=0, keepdims=True)
+            dcum_c = jnp.sum(v * dt_r, axis=1, keepdims=True)
+            dcum_r = -ddt_r * dt_r
+            # what came before the chunk: exp(cum_i) · (C S_in)_i
+            dcum_c = dcum_c + before_c * jnp.sum(
+                jnp.where(mine, dy_carried, 0.0), axis=1, keepdims=True)
+            dc = dc + _dot((_f32(dy_h) * before_c).astype(dtype), s2_lo,
+                           ((1,), (1,)), prec)
+            # the state the chunk leaves: exp(cum_Q) S_in + (B ∘ w)ᵀ X
+            z = _dot(x_h, ds2_lo, ((1,), (1,)), prec)       # [Q, N]
+            db = db + z * w_c
+            dw_c = jnp.sum(z * b32, axis=1, keepdims=True)
+            dcum_c = dcum_c - dw_c * w_c
+            dtotal = jnp.sum(dw_c * w_c) + jnp.exp(total) * jnp.sum(
+                jnp.where(mine, ds_s, 0.0))
+            dcum_ref[0, 0, 0, h:h + 1, :] = dcum_r + jnp.transpose(dcum_c) \
+                + jnp.where(last, dtotal, 0.0)
+            ddt_ref[0, 0, 0, h:h + 1, :] = ddt_r + jnp.transpose(
+                dw_c * tail_c)
+            before = jnp.where(mine, before_c, before)
+            kept = jnp.where(mine, jnp.exp(total), kept)
+        dx_ref[0, :, cols] = dx.astype(dx_ref.dtype)
+        dstate[:, cols] = kept * ds2 + _dot(
+            cm, (dy32 * before).astype(dtype), ((0,), (0,)), prec)
+        dd_ref[0, :, cols] += jnp.sum(dy32 * _f32(x2), axis=0, keepdims=True)
+    dcb = dcb.astype(dtype)
+    db_ref[0] = (db + _dot(dcb, cm, ((0,), (0,)), prec)).astype(db_ref.dtype)
+    dc_ref[0] = (dc + _dot(dcb, bm, ((1,), (0,)), prec)).astype(dc_ref.dtype)
+
+
+def _call(kernel, name: str, x, bm, cum, reverse: bool, operands: str,
+          results: str):
+    """The ``pallas_call`` of ``kernel`` on the grid ``(batch, group,
+    chunk)``, chunks in ``reverse`` for the backward; ``operands`` and
+    ``results`` name each one's block: ``x`` the tokens' columns of a group
+    ``[1, Q, heads·P]``, ``b`` its B or C ``[1, Q, N]``, ``h`` its heads'
+    decays ``[1, 1, 1, heads, Q]``, ``d`` D's columns, ``s`` a chunk's
+    entering state."""
+    batch = x.shape[0]
+    _, chunks, groups, heads, q = cum.shape
+    n, hp = bm.shape[2] // groups, x.shape[2] // groups
+
+    def at(c):
+        return chunks - 1 - c if reverse else c
+
+    spec = {
+        "x": pl.BlockSpec((1, q, hp), lambda b, g, c: (b, at(c), g)),
+        "b": pl.BlockSpec((1, q, n), lambda b, g, c: (b, at(c), g)),
+        "h": pl.BlockSpec((1, 1, 1, heads, q),
+                          lambda b, g, c: (b, at(c), g, 0, 0)),
+        "d": pl.BlockSpec((1, 1, hp), lambda b, g, c: (b, 0, g)),
+        "s": pl.BlockSpec((1, 1, 1, n, hp),
+                          lambda b, g, c: (b, at(c), g, 0, 0)),
+    }
+    shape = {"x": (x.shape, x.dtype), "b": (bm.shape, bm.dtype),
+             "h": (cum.shape, jnp.float32),
+             "d": ((batch, 1, x.shape[2]), jnp.float32),
+             "s": ((batch, chunks, groups, n, hp), jnp.float32)}
+    p = hp // heads
+    return pl.pallas_call(
+        functools.partial(kernel, heads=heads, p=p, pack=_pack(heads, p)),
+        grid=(batch, groups, chunks),
+        in_specs=[spec[o] for o in operands],
+        out_specs=[spec[r] for r in results],
+        out_shape=[jax.ShapeDtypeStruct(*shape[r]) for r in results],
+        scratch_shapes=[pltpu.VMEM((n, hp), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=_interpret(), name=name)
+
+
+def _fwd_call(x, bm, cm, cum, dt, d):
+    """``x [B, S, H·P]``, ``bm``, ``cm [B, S, G·N]``, ``cum``, ``dt [B,
+    chunks, G, heads, Q]`` float32, ``d [B, 1, H·P]`` float32 → ``y`` as
+    ``x``, and each chunk's entering state."""
+    return _call(_fwd_kernel, "ssd_fwd", x, bm, cum, False, "xbbhhd",
+                 "xs")(x, bm, cm, cum, dt, d)
+
+
+def _bwd_call(x, bm, cm, cum, dt, d, s_in, dy):
+    """The cotangents of ``_fwd_call``'s six operands from ``dy``."""
+    return _call(_bwd_kernel, "ssd_bwd", x, bm, cum, True, "xbbhhdsx",
+                 "xbbhhd")(x, bm, cm, cum, dt, d, s_in, dy)
+
+
+# Every operand's leading dim is the batch: under a bound mesh each device
+# runs the kernels on its own rows (compat.per_shard).
+_ROWS = (BATCH_AXES,)
+
+
+@jax.custom_vjp
+def _scan(x, bm, cm, cum, dt, d):
+    return per_shard(_fwd_call, _ROWS)(x, bm, cm, cum, dt, d)[0]
+
+
+def _scan_fwd(x, bm, cm, cum, dt, d):
+    y, s_in = per_shard(_fwd_call, _ROWS)(x, bm, cm, cum, dt, d)
+    return y, (x, bm, cm, cum, dt, d, s_in)
+
+
+def _scan_bwd(res, dy):
+    return tuple(per_shard(_bwd_call, _ROWS)(*res, dy))
+
+
+_scan.defvjp(_scan_fwd, _scan_bwd)
+
+
+def _grouped(a, groups: int):
+    """``[B, chunks, Q, H]`` of per-head scalars → ``[B, chunks, G, heads,
+    Q]``: a group's heads on sublanes, the chunk's tokens on lanes."""
+    b, chunks, q, h = a.shape
+    return a.reshape(b, chunks, q, groups, h // groups).transpose(
+        0, 1, 3, 4, 2)
+
+
+def _kernels(x, dt, a, bm, cm, d, chunk: int):
+    b, s, h, p = x.shape
+    g, n = bm.shape[2:]
+    dt = _f32(dt).reshape(b, s // chunk, chunk, h)
+    cum = jnp.cumsum(dt * _f32(a), axis=2)
+    d_cols = jnp.broadcast_to(jnp.repeat(_f32(d), p)[None, None],
+                              (b, 1, h * p))
+    y = _scan(x.reshape(b, s, h * p), bm.reshape(b, s, g * n),
+              cm.reshape(b, s, g * n), _grouped(cum, g), _grouped(dt, g),
+              d_cols)
+    return y.reshape(b, s, h, p)
+
+
+def carried_states(left, kept):
+    """The state entering each chunk, from what every chunk leaves behind
+    (``left [B, chunks, H, N, P]``, from a zero state) and the share of its
+    entering state it keeps (``kept [B, chunks, H]``): the hand-over from
+    chunk to chunk, zero at a row's start."""
+    def step(s, chunk):
+        left_c, kept_c = chunk
+        return kept_c[..., None, None] * s + left_c, s
+
+    _, s_in = jax.lax.scan(step, jnp.zeros_like(left[:, 0]),
+                           (left.swapaxes(0, 1), kept.swapaxes(0, 1)))
+    return s_in.swapaxes(0, 1)
+
+
+def _chunked(x, dt, a, bm, cm, d, chunk: int):
+    """The kernels' algorithm in plain ``jax.numpy``, a row's chunks at
+    once: float32 throughout, ``[chunks, Q, Q]`` held."""
+    b, s, h, p = x.shape
+    g, n = bm.shape[2:]
+    c, per = s // chunk, h // g
+    x32 = _f32(x).reshape(b, c, chunk, g, per, p)
+    b32, c32 = (_f32(m).reshape(b, c, chunk, g, n) for m in (bm, cm))
+    dt = _f32(dt).reshape(b, c, chunk, g, per)
+    cum = jnp.cumsum(dt * _f32(a).reshape(g, per), axis=2)
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+    decay = jnp.exp(jnp.where(
+        causal[None, None, :, :, None, None],
+        cum[:, :, :, None] - cum[:, :, None, :], MASKED))   # [b,c,i,j,g,per]
+    with jax.default_matmul_precision("highest"):
+        cb = jnp.einsum("bcign,bcjgn->bcijg", c32, b32)
+        m = cb[..., None] * decay * dt[:, :, None]
+        within = jnp.einsum("bcijgh,bcjghp->bcighp", m, x32)
+        total = cum[:, :, -1]                               # [b,c,g,per]
+        w = jnp.exp(total[:, :, None] - cum) * dt
+        left = jnp.einsum("bcjgn,bcjgh,bcjghp->bcghnp", b32, w, x32)
+        s_in = carried_states(
+            left.reshape(b, c, h, n, p),
+            jnp.exp(total).reshape(b, c, h)).reshape(b, c, g, per, n, p)
+        carried = jnp.einsum("bcign,bcghnp->bcighp", c32, s_in)
+    y = within + jnp.exp(cum)[..., None] * carried \
+        + _f32(d).reshape(g, per, 1) * x32
+    return y.reshape(b, s, h, p).astype(x.dtype)
+
+
+def ssd(x, dt, a, b, c, d, chunk: int = 128, impl: Optional[str] = None):
+    """``y [B, S, H, P]`` of the recurrence above for ``x [B, S, H, P]``,
+    steps ``dt [B, S, H]`` (positive), ``a [H]`` (negative), ``b``, ``c
+    [B, S, G, N]`` (``G`` divides ``H``: head ``h`` reads group ``h // (H /
+    G)``) and the skip ``d [H]``. Every row starts from a zero state. ``S``
+    must be a whole number of chunks. ``impl``: ``"kernel"`` (the Mosaic
+    calls; interpreted off the TPU), ``"jnp"``, or None for the kernels on a
+    TPU and ``jax.numpy`` elsewhere."""
+    if x.shape[1] % chunk:
+        raise ValueError(f"a sequence of {x.shape[1]} is no whole number of "
+                         f"chunks of {chunk}")
+    if x.shape[2] % b.shape[2] or b.shape != c.shape:
+        raise ValueError(f"{x.shape[2]} heads over B {b.shape} and C "
+                         f"{c.shape}")
+    if impl is None:
+        impl = "jnp" if _interpret() else "kernel"
+    if impl not in ("kernel", "jnp"):
+        raise ValueError(f"impl {impl!r} is neither 'kernel' nor 'jnp'")
+    return (_kernels if impl == "kernel" else _chunked)(x, dt, a, b, c, d,
+                                                        chunk)

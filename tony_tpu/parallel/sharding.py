@@ -45,6 +45,11 @@ DEFAULT_RULES: Tuple[Tuple[str, Any], ...] = (
     ("expert", "ep"),
     ("expert_logits", None),
     ("norm", None),
+    # A state-space mixer's columns, heads and conv taps stay whole: its
+    # scan is not split over chips (ROADMAP M6').
+    ("ssm_inner", None),
+    ("ssm_heads", None),
+    ("conv", None),
 )
 
 
