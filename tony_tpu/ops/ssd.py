@@ -20,17 +20,36 @@ Forward and backward are one Mosaic call each, named ``ssd_fwd`` and
 ``ssd_bwd``, under one ``jax.custom_vjp``. Their grid is ``(batch, group,
 chunk)``, the chunk axis last and sequential: the state (forward) or its
 cotangent (backward, chunks in reverse) lives in VMEM scratch from chunk to
-chunk. A grid step has all the heads of one group, so ``C Bᵀ`` is one
-product a group and chunk, and nothing of shape ``[chunks, Q, Q]`` ever
-leaves VMEM. The forward also writes each chunk's entering state (float32
-``[B, chunks, G, N, heads·P]``), which the backward reads. (A training
-step's forward pass is the differentiated one, under a block's remat too,
-so a forward without that output would serve evaluation alone.)
+chunk, and nothing of shape ``[chunks, Q, Q]`` ever leaves VMEM. The forward
+also writes each chunk's entering state (float32 ``[B, chunks, G, N,
+heads·P]``); the backward reads that result as it is, no instruction
+between the two calls. (A training step's forward pass is the differentiated
+one, under a block's remat too, so a forward without that output would serve
+evaluation alone.)
 
 Heads narrower than the 128 lanes are worked on in packs of ``128 // P``
 side by side: every load, store and matmul operand is a whole number of
-lane tiles, and a pack's heads are told apart by lane masks (a product
-``M_h @ X_pack`` computes a whole tile either way).
+lane tiles. A grid step has all the heads of one group, and a product whose
+operand the heads share is taken once, a head's scaling ``s_h`` (one number
+a token) moved to the other operand or to the float32 result, on that head's
+lanes: ``(B ∘ s_h) dS_h = s_h ∘ (B dS_h)``, ``(B ∘ s_h)ᵀ X_h = Bᵀ (s_h ∘
+X_h)``, ``Σ_h (dY_h ∘ s_h) S_hᵀ = (dY ∘ s) Sᵀ``. So, in tile products of
+``[128, 128]`` at 8 heads of 64 (four packs):
+
+- a group: ``C Bᵀ``; in the backward its cotangent into ``dB`` and ``dC``;
+- a pack: ``C S_in`` and ``Bᵀ (w ∘ X)``, the state a chunk leaves; in the
+  backward also ``B dS``, ``(dY ∘ exp(cum)) S_inᵀ`` into ``dC``, ``(X ∘ w)
+  dSᵀ`` into ``dB``, and ``Cᵀ (dY ∘ exp(cum))`` into the state's cotangent;
+  ``dw`` is the sum of ``x ∘ (B dS)`` over a head's lanes;
+- a head, because ``L`` is the head's own: ``M_h X`` in the forward, ``M_hᵀ
+  dY_h`` and ``dM_h = dY_h Xᵀ`` in the backward (``dY_h`` zero off the head's
+  lanes, the one mask), with the ``exp`` over ``[Q, Q]``.
+
+That is 17 products a grid step forward and 39 backward (21 and 51 with a
+product a head). The heads' scalars (``cum``, ``Δ``, ``exp(cum)``,
+``exp(cum_Q − cum)``, ``w`` and their cotangents) are worked on as blocks for
+the group's heads at once, ``[heads, Q]`` where a token is a lane and,
+transposed once a grid step, ``[Q, heads]`` where a token is a sublane.
 
 ``A`` must be negative and ``Δ`` positive (decays in (0, 1]): the mixer's
 ``−exp(A_log)`` and ``softplus``. The decays, their cumulative sums and the
@@ -77,34 +96,62 @@ def _dot(a, b, dims, prec):
                                precision=prec)
 
 
-def _chunk_masks(q: int):
-    rows = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    last = jax.lax.broadcasted_iota(jnp.int32, (1, q), 1) == q - 1
-    return rows >= cols, last
-
-
-def _head_decays(cum_ref, dt_ref, h: int, causal, last):
-    """A head's row ``h`` of the chunk's cumulative log-decays and steps, as
-    rows ``[1, Q]`` and columns ``[Q, 1]``, the chunk's total ``[1, 1]`` and
-    the masked decay matrix ``L [Q, Q]``."""
-    cum_r, dt_r = cum_ref[0, 0, 0, h:h + 1, :], dt_ref[0, 0, 0, h:h + 1, :]
-    cum_c, dt_c = jnp.transpose(cum_r), jnp.transpose(dt_r)
-    total = jnp.sum(jnp.where(last, cum_r, 0.0), axis=1, keepdims=True)
-    decay = jnp.exp(jnp.where(causal, cum_c - cum_r, MASKED))
-    return cum_r, dt_r, cum_c, dt_c, total, decay
-
-
-def _group_chunk(x_ref, b_ref, c_ref, pack: int, p: int):
-    """What a grid step's heads share: the group's B and C ``[Q, N]``, ``C
-    Bᵀ [Q, Q]``, the chunk's masks, a pack's lanes, and the dtype and
-    precision the products run in."""
+def _group_chunk(x_ref, b_ref, c_ref, cum_ref, dt_ref, pack: int, p: int):
+    """What a grid step's heads share. The group's B and C ``[Q, N]``, ``C
+    Bᵀ [Q, Q]``, the causal mask, a pack's lanes, the dtype and precision the
+    products run in; and the heads' scalars as blocks, for all the group's
+    heads at once: ``cum`` and ``Δ`` a head a row ``[heads, Q]`` and a head
+    a column ``[Q, heads]``, and of the columns ``exp(cum)`` (what the
+    entering state's share of token i has decayed to), ``exp(cum_Q − cum)``
+    (what token j's share of the leaving state has) and ``cum_Q [1,
+    heads]``."""
     bm, cm = b_ref[0], c_ref[0]
     prec = _prec(x_ref)
-    causal, last = _chunk_masks(bm.shape[0])
+    q = bm.shape[0]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, pack * p), 1)
-    return (bm, cm, _dot(cm, bm, ((1,), (1,)), prec), causal, last, lane,
-            x_ref.dtype, prec)
+    cum, dt = cum_ref[0, 0, 0], dt_ref[0, 0, 0]
+    cum_t, dt_t = jnp.transpose(cum), jnp.transpose(dt)
+    total = cum_t[q - 1:q]
+    return (bm, cm, _dot(cm, bm, ((1,), (1,)), prec), rows >= cols, lane,
+            x_ref.dtype, prec, cum, dt, cum_t, dt_t, jnp.exp(cum_t),
+            jnp.exp(total - cum_t), total)
+
+
+def _mine(lane, j: int, p: int):
+    """The lanes of a pack's head ``j``."""
+    return (lane >= j * p) & (lane < (j + 1) * p)
+
+
+def _on_lanes(block, k: int, pack: int, p: int, lane):
+    """Pack ``k``'s columns of ``block [rows, heads]``, each over its head's
+    ``p`` lanes: ``[rows, pack·p]``."""
+    out = jnp.broadcast_to(block[:, k * pack:k * pack + 1],
+                           (block.shape[0], pack * p))
+    for j in range(1, pack):
+        out = jnp.where(lane >= j * p,
+                        block[:, k * pack + j:k * pack + j + 1], out)
+    return out
+
+
+def _by_head(block, t, k: int, pack: int, p: int, lane):
+    """``block [rows, heads]`` with pack ``k``'s columns set to the sums of
+    ``t [rows, pack·p]`` over each head's lanes."""
+    head = jax.lax.broadcasted_iota(jnp.int32, (1, block.shape[1]), 1)
+    for j in range(pack):
+        block = jnp.where(
+            head == k * pack + j,
+            jnp.sum(jnp.where(_mine(lane, j, p), t, 0.0), axis=1,
+                    keepdims=True), block)
+    return block
+
+
+def _head_decay(cum, cum_t, h: int, causal):
+    """Head ``h``'s masked decays ``L [Q, Q]``, ``L_ij = exp(cum_i −
+    cum_j)`` for ``j ≤ i``."""
+    return jnp.exp(jnp.where(causal, cum_t[:, h:h + 1] - cum[h:h + 1],
+                             MASKED))
 
 
 def _fwd_kernel(x_ref, b_ref, c_ref, cum_ref, dt_ref, d_ref, y_ref, s_in_ref,
@@ -114,31 +161,30 @@ def _fwd_kernel(x_ref, b_ref, c_ref, cum_ref, dt_ref, d_ref, y_ref, s_in_ref,
         state[...] = jnp.zeros_like(state)
 
     s_in_ref[0, 0, 0] = state[...]
-    bm, cm, cb, causal, last, lane, dtype, prec = _group_chunk(
-        x_ref, b_ref, c_ref, pack, p)
+    (bm, cm, cb, causal, lane, dtype, prec, cum, dt, cum_t, dt_t, before_t,
+     tail_t, total) = _group_chunk(x_ref, b_ref, c_ref, cum_ref, dt_ref,
+                                    pack, p)
     q, width = bm.shape[0], pack * p
+    w_t = tail_t * dt_t
     for k in range(heads // pack):
         cols = slice(k * width, (k + 1) * width)
         x2, s2 = x_ref[0, :, cols], state[:, cols]          # [Q, w], [N, w]
+        x32 = _f32(x2)
         within = jnp.zeros((q, width), jnp.float32)
-        before = jnp.zeros((q, width), jnp.float32)   # exp(cum_i) a head
-        s_new = jnp.zeros_like(s2)
         for j in range(pack):
-            mine = (lane >= j * p) & (lane < (j + 1) * p)
-            _, dt_r, cum_c, dt_c, total, decay = _head_decays(
-                cum_ref, dt_ref, k * pack + j, causal, last)
-            m = (cb * decay * dt_r).astype(dtype)
-            within = jnp.where(mine, _dot(m, x2, ((1,), (0,)), prec), within)
-            before = jnp.where(mine, jnp.exp(cum_c), before)
-            bw = (_f32(bm) * (jnp.exp(total - cum_c) * dt_c)).astype(dtype)
-            s_new = jnp.where(
-                mine, jnp.exp(total) * s2 + _dot(bw, x2, ((0,), (0,)), prec),
-                s_new)
+            h = k * pack + j
+            m = (cb * _head_decay(cum, cum_t, h, causal)
+                 * dt[h:h + 1]).astype(dtype)
+            within = jnp.where(_mine(lane, j, p),
+                               _dot(m, x2, ((1,), (0,)), prec), within)
         carried = _dot(cm, s2.astype(dtype), ((1,), (0,)), prec)   # C S_in
-        y_ref[0, :, cols] = (within + before * carried
-                             + d_ref[0, :, cols] * _f32(x2)).astype(
-                                 y_ref.dtype)
-        state[:, cols] = s_new
+        y_ref[0, :, cols] = (
+            within + _on_lanes(before_t, k, pack, p, lane) * carried
+            + d_ref[0, :, cols] * x32).astype(y_ref.dtype)
+        # the state the chunk leaves: exp(cum_Q) S_in + Bᵀ (w ∘ X)
+        xw = (x32 * _on_lanes(w_t, k, pack, p, lane)).astype(dtype)
+        state[:, cols] = jnp.exp(_on_lanes(total, k, pack, p, lane)) * s2 \
+            + _dot(bm, xw, ((0,), (0,)), prec)
 
 
 def _bwd_kernel(x_ref, b_ref, c_ref, cum_ref, dt_ref, d_ref, s_in_ref, dy_ref,
@@ -149,73 +195,79 @@ def _bwd_kernel(x_ref, b_ref, c_ref, cum_ref, dt_ref, d_ref, s_in_ref, dy_ref,
         dstate[...] = jnp.zeros_like(dstate)
         dd_ref[...] = jnp.zeros_like(dd_ref)
 
-    bm, cm, cb, causal, last, lane, dtype, prec = _group_chunk(
-        x_ref, b_ref, c_ref, pack, p)
+    (bm, cm, cb, causal, lane, dtype, prec, cum, dt, cum_t, dt_t, before_t,
+     tail_t, total) = _group_chunk(x_ref, b_ref, c_ref, cum_ref, dt_ref,
+                                    pack, p)
     q, width = bm.shape[0], pack * p
-    b32 = _f32(bm)
+    w_t = tail_t * dt_t
+    head_row = jax.lax.broadcasted_iota(jnp.int32, (heads, 1), 0)
+    head_col = jax.lax.broadcasted_iota(jnp.int32, (1, heads), 1)
     dcb = jnp.zeros((q, q), jnp.float32)
     db = jnp.zeros(bm.shape, jnp.float32)
     dc = jnp.zeros(cm.shape, jnp.float32)
+    # Cotangents of the heads' scalars, as blocks: Σ_i of a head's pairs a
+    # row; a column each for Σ_j of the pairs, for exp(cum), for w and (one
+    # row) for exp(cum_Q).
+    pairs_j = jnp.zeros((heads, q), jnp.float32)
+    pairs_i = jnp.zeros((q, heads), jnp.float32)
+    dbefore = jnp.zeros((q, heads), jnp.float32)
+    dw = jnp.zeros((q, heads), jnp.float32)
+    dkept = jnp.zeros((1, heads), jnp.float32)
     for k in range(heads // pack):
         cols = slice(k * width, (k + 1) * width)
         x2, dy2 = x_ref[0, :, cols], dy_ref[0, :, cols]     # [Q, w]
         s2, ds2 = s_in_ref[0, 0, 0, :, cols], dstate[:, cols]   # [N, w] f32
         s2_lo, ds2_lo = s2.astype(dtype), ds2.astype(dtype)
-        dy32 = _f32(dy2)
-        dy_carried = dy32 * _dot(cm, s2_lo, ((1,), (0,)), prec)  # dy ∘ C S_in
-        ds_s = ds2 * s2
-        dx = d_ref[0, :, cols] * dy32
-        before = jnp.zeros((q, width), jnp.float32)
-        kept = jnp.zeros((1, width), jnp.float32)       # exp(cum_Q) a head
+        x32, dy32 = _f32(x2), _f32(dy2)
+        w = _on_lanes(w_t, k, pack, p, lane)
+        b_ds = _dot(bm, ds2_lo, ((1,), (0,)), prec)         # B dS  [Q, w]
+        c_s = _dot(cm, s2_lo, ((1,), (0,)), prec)           # C S_in
+        # x: the skip, into the state the chunk leaves, and (a head at a
+        # time) through the chunk's own pairs M = C Bᵀ ∘ L ∘ Δ_j
+        dx = d_ref[0, :, cols] * dy32 + w * b_ds
         for j in range(pack):
             h = k * pack + j
-            mine = (lane >= j * p) & (lane < (j + 1) * p)
-            _, dt_r, cum_c, dt_c, total, decay = _head_decays(
-                cum_ref, dt_ref, h, causal, last)
+            dt_r = dt[h:h + 1]
+            decay = _head_decay(cum, cum_t, h, causal)
             cbl = cb * decay
-            m = (cbl * dt_r).astype(dtype)
-            x_h = jnp.where(mine, x2, jnp.zeros_like(x2))
-            dy_h = jnp.where(mine, dy2, jnp.zeros_like(dy2))
-            before_c = jnp.exp(cum_c)
-            tail_c = jnp.exp(total - cum_c)
-            w_c = tail_c * dt_c
-            bw = (b32 * w_c).astype(dtype)
-            # x: through the chunk's own pairs, and into the state it leaves
-            dx = dx + jnp.where(
-                mine, _dot(m, dy2, ((0,), (0,)), prec)
-                + _dot(bw, ds2_lo, ((1,), (0,)), prec), 0.0)
-            # the chunk's own pairs: M = C Bᵀ ∘ L ∘ Δ_j
-            dm = _dot(dy_h, x_h, ((1,), (1,)), prec)        # [Q, Q]
+            dy_h = dy2 if pack == 1 else jnp.where(
+                _mine(lane, j, p), dy2, jnp.zeros_like(dy2))
+            dx = dx + _dot((cbl * dt_r).astype(dtype), dy_h, ((0,), (0,)),
+                           prec)                            # Mᵀ dY
+            dm = _dot(dy_h, x2, ((1,), (1,)), prec)         # [Q, Q]
             dcb = dcb + dm * decay * dt_r
             v = dm * cbl
-            ddt_r = jnp.sum(v, axis=0, keepdims=True)
-            dcum_c = jnp.sum(v * dt_r, axis=1, keepdims=True)
-            dcum_r = -ddt_r * dt_r
-            # what came before the chunk: exp(cum_i) · (C S_in)_i
-            dcum_c = dcum_c + before_c * jnp.sum(
-                jnp.where(mine, dy_carried, 0.0), axis=1, keepdims=True)
-            dc = dc + _dot((_f32(dy_h) * before_c).astype(dtype), s2_lo,
-                           ((1,), (1,)), prec)
-            # the state the chunk leaves: exp(cum_Q) S_in + (B ∘ w)ᵀ X
-            z = _dot(x_h, ds2_lo, ((1,), (1,)), prec)       # [Q, N]
-            db = db + z * w_c
-            dw_c = jnp.sum(z * b32, axis=1, keepdims=True)
-            dcum_c = dcum_c - dw_c * w_c
-            dtotal = jnp.sum(dw_c * w_c) + jnp.exp(total) * jnp.sum(
-                jnp.where(mine, ds_s, 0.0))
-            dcum_ref[0, 0, 0, h:h + 1, :] = dcum_r + jnp.transpose(dcum_c) \
-                + jnp.where(last, dtotal, 0.0)
-            ddt_ref[0, 0, 0, h:h + 1, :] = ddt_r + jnp.transpose(
-                dw_c * tail_c)
-            before = jnp.where(mine, before_c, before)
-            kept = jnp.where(mine, jnp.exp(total), kept)
+            pairs_j = jnp.where(head_row == h,
+                                jnp.sum(v, axis=0, keepdims=True), pairs_j)
+            pairs_i = jnp.where(head_col == h,
+                                jnp.sum(v * dt_r, axis=1, keepdims=True),
+                                pairs_i)
         dx_ref[0, :, cols] = dx.astype(dx_ref.dtype)
-        dstate[:, cols] = kept * ds2 + _dot(
-            cm, (dy32 * before).astype(dtype), ((0,), (0,)), prec)
-        dd_ref[0, :, cols] += jnp.sum(dy32 * _f32(x2), axis=0, keepdims=True)
+        # what came before the chunk, exp(cum_i) · (C S_in)_i, and the state
+        # it leaves, exp(cum_Q) S_in + Bᵀ (w ∘ X)
+        dyb = (dy32 * _on_lanes(before_t, k, pack, p, lane)).astype(dtype)
+        dc = dc + _dot(dyb, s2_lo, ((1,), (1,)), prec)
+        db = db + _dot((x32 * w).astype(dtype), ds2_lo, ((1,), (1,)), prec)
+        dstate[:, cols] = jnp.exp(_on_lanes(total, k, pack, p, lane)) * ds2 \
+            + _dot(cm, dyb, ((0,), (0,)), prec)
+        dd_ref[0, :, cols] += jnp.sum(dy32 * x32, axis=0, keepdims=True)
+        dbefore = _by_head(dbefore, dy32 * c_s, k, pack, p, lane)
+        dw = _by_head(dw, x32 * b_ds, k, pack, p, lane)
+        dkept = _by_head(dkept, jnp.sum(ds2 * s2, axis=0, keepdims=True), k,
+                         pack, p, lane)
     dcb = dcb.astype(dtype)
     db_ref[0] = (db + _dot(dcb, cm, ((0,), (0,)), prec)).astype(db_ref.dtype)
     dc_ref[0] = (dc + _dot(dcb, bm, ((1,), (0,)), prec)).astype(dc_ref.dtype)
+    # w_j = exp(cum_Q − cum_j) Δ_j: Δ's share, and cum's with cum_Q's on the
+    # chunk's last token
+    ddt_t = dw * tail_t
+    dtotal = jnp.sum(ddt_t * dt_t, axis=0, keepdims=True) \
+        + jnp.exp(total) * dkept
+    token = jax.lax.broadcasted_iota(jnp.int32, (q, 1), 0)
+    dcum_t = pairs_i + before_t * dbefore - ddt_t * dt_t + jnp.where(
+        token == q - 1, dtotal, 0.0)
+    dcum_ref[0, 0, 0] = jnp.transpose(dcum_t) - pairs_j * dt
+    ddt_ref[0, 0, 0] = jnp.transpose(ddt_t) + pairs_j
 
 
 def _call(kernel, name: str, x, bm, cum, reverse: bool, operands: str,
