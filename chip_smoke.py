@@ -555,8 +555,8 @@ def parent_main(rehearsal: bool) -> int:
               f"{metrics.get('device_count')} != devices found "
               f"{worker['device']['count']}")
         if not rehearsal:       # the CPU backend reports no memory stats
-            check(float(metrics.get("hbm_peak_bytes", 0)) > 0,
-                  "user-metrics.json hbm_peak_bytes is 0")
+            check(float(metrics.get("hbm_bytes_in_use", 0)) > 0,
+                  "user-metrics.json hbm_bytes_in_use is 0")
             check(worker["device"]["platform"] == "tpu", "not a TPU run")
 
         leftover = _stop_run(marker)
@@ -587,8 +587,7 @@ def parent_main(rehearsal: bool) -> int:
         "submit_wall_s": round(submit_wall, 1),
         "invariants": inv.stdout.strip().splitlines(),
         "user_metrics": {k: metrics.get(k) for k in (
-            "device_count", "hbm_peak_bytes", "hbm_bytes_in_use",
-            "steps_completed")},
+            "device_count", "hbm_bytes_in_use", "steps_completed")},
         "artifacts": os.path.relpath(out_dir, REPO),
     }
     with open(os.path.join(out_dir, "chip_smoke.json"), "w",

@@ -277,6 +277,58 @@ def test_span_list_stops_at_its_cap_and_counts_the_rest():
     assert telemetry.span_stats() == {}
 
 
+def test_the_reporter_ends_at_interpreter_exit(tmp_path, monkeypatch):
+    """``_stop_reporter`` (an ``atexit`` hook of ``maybe_start``): the
+    thread finishes the write it is in and makes no other, so the
+    interpreter's teardown never meets it inside jax's native code."""
+    import threading
+
+    _reset_steps()
+    writes = []
+    monkeypatch.setattr(telemetry, "write_stats_once", writes.append)
+    monkeypatch.setattr(telemetry, "_stopping", threading.Event())
+    reporter = threading.Thread(target=telemetry._loop,
+                                args=(str(tmp_path / "m.json"), 60.0),
+                                daemon=True)
+    monkeypatch.setattr(telemetry, "_thread", reporter)
+    reporter.start()
+    telemetry._stop_reporter()
+    assert not reporter.is_alive()
+    assert len(writes) <= 1
+    telemetry._span_added.clear()
+
+
+def test_every_key_of_the_metrics_file_has_a_reader_perf_md_names(tmp_path):
+    """What the reporter writes every tick is read by something: PERF.md's
+    section 3 says by what (a benchmark metric, the executor's beacon, a
+    page of docs/operations.md). A key nobody reads is not written."""
+    import jax  # noqa: F401 — the device's keys want the runtime up
+
+    _reset_steps()
+    telemetry.note_step_counters({"rows": 8})
+    with telemetry.step(flops=1e9, tokens=64.0):
+        with telemetry.phase("data_wait"):
+            pass
+    telemetry.record_span("user.compile", 1.0, 2.0, stage="backend")
+    path = str(tmp_path / "m.json")
+    assert telemetry.write_stats_once(path)
+    written = set(telemetry.read_stats(path))
+    assert {"device_count", "hbm_bytes_in_use", "steps_completed",
+            "steps_per_sec", "step_duty_cycle", "tokens_per_sec",
+            "model_flops_per_sec", "first_step_done_ts", "spans_kept",
+            "spans_dropped", "compiles", "compile_cache_hits",
+            "compile_cache_misses", "compile_seconds",
+            "compiles_after_first_step", "step_phases", "step_counters",
+            "pid"} <= written
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "PERF.md"), encoding="utf-8") as f:
+        layers = f.read().split("## 3. Layers")[1].split("## 4. Cells")[0]
+    unread = sorted(k for k in written if f"`{k}`" not in layers)
+    assert not unread, f"PERF.md 3 names no reader for {unread}"
+    telemetry.note_step_counters({})
+    _reset_steps()
+
+
 @pytest.mark.filterwarnings(
     "ignore::pytest.PytestUnhandledThreadExceptionWarning")
 def test_a_new_span_wakes_the_reporter(tmp_path, monkeypatch):

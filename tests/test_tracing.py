@@ -398,6 +398,10 @@ def test_cold_start_breakdown_splits_user_boot_into_self_times():
         "unattributed": 0.9}
     assert round(sum(bd["user_boot"].values()), 6) \
         == bd["phases"]["user_boot"] == 5.0
+    # The compiles' self time by stage: the backend compile inside
+    # init_state and the trace after it; the late lowering is out.
+    assert bd["user_boot_compile"] == {"trace": 1.2, "lower": 0.0,
+                                       "backend": 0.6}
 
 
 def test_cold_start_breakdown_is_unchanged_without_user_spans():
@@ -519,7 +523,15 @@ def test_cli_cold_start_prints_user_boot_sub_lines(tmp_path, capsys):
     at = next(i for i, line in enumerate(lines)
               if line.startswith("  user_boot"))
     assert lines[at].split()[1] == "5.00s"
-    sub = {line.split()[0]: line.split()[1] for line in lines[at + 1:at + 5]}
+    under = lines[at + 1:at + 8]
+    sub = {line.split()[0]: line.split()[1] for line in under
+           if not line.startswith("      ")}
     assert sub == {"user.pre_import": "1.90s", "user.init_state": "0.40s",
                    "user.compile": "1.80s", "unattributed": "0.90s"}
-    assert all(line.startswith("    ") for line in lines[at + 1:at + 5])
+    assert all(line.startswith("    ") for line in under)
+    # Under the compile line, its three stages (the late ``lower`` span
+    # lies outside the phase).
+    at_compile = next(i for i, line in enumerate(under)
+                      if line.split()[0] == "user.compile")
+    assert [line.split() for line in under[at_compile + 1:at_compile + 4]] \
+        == [["trace", "1.20s"], ["lower", "0.00s"], ["backend", "0.60s"]]

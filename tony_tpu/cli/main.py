@@ -625,6 +625,11 @@ def _cmd_trace(args: argparse.Namespace) -> int:
                 # The user process's own spans, as self times.
                 for name, sub in bd.get("user_boot", {}).items():
                     print(f"    {name:<20}{sub:>8.2f}s")
+                    if name == "user.compile":
+                        # jax's trace, its lowering, the backend's compile
+                        # or the persistent cache's fetch.
+                        for stage, secs in bd["user_boot_compile"].items():
+                            print(f"      {stage:<18}{secs:>8.2f}s")
         if bd["span_durations"]:
             print("  raw span durations (may overlap):")
             for name, secs in sorted(bd["span_durations"].items()):

@@ -115,7 +115,9 @@ _KEY_TOKEN_RE = re.compile(
 #: models/transformer.py, models/ssm.py).
 _TRACE_NAME_RE = re.compile(
     r"^tony\.(step|phase(\.[a-z0-9_\-]+)?|loss_and_grad|optimizer"
-    r"|moe\.(route|dispatch|experts|combine|shared)|attn\.(rope|gate)"
+    r"|embed|norm|mlp|loss_head"
+    r"|moe\.(route|dispatch|experts|combine|shared)"
+    r"|attn\.(proj|rope|core|gate)"
     r"|ssm\.(in_proj|conv|scan|gate_norm|out_proj))$")
 #: dotted tokens whose last segment is one of these are file names
 #: ("job.tony.json", "tony.xml"), not config-key references

@@ -745,12 +745,15 @@ def _chunks(spec: ExpertSpec, int8: bool, x, idx, weights, ws, first):
 
 
 def _chunks_fwd(spec, int8, x, idx, weights, ws, first):
-    lo = tuple(w.astype(x.dtype) for w in ws)
-    q = tuple(quantize_symmetric(w, INT8, axis=1) if int8 else None
-              for w in lo)
-    out = jax.lax.map(
-        lambda c: _one_chunk(spec, *c, ws, lo, q, (None,) * len(ws), first),
-        (x, idx, weights))
+    with jax.named_scope("tony.moe.experts"):
+        lo = tuple(w.astype(x.dtype) for w in ws)
+        q = tuple(quantize_symmetric(w, INT8, axis=1) if int8 else None
+                  for w in lo)
+    # The loop's own slices and stacks of the chunks count with dispatch.
+    with jax.named_scope("tony.moe.dispatch"):
+        out = jax.lax.map(
+            lambda c: _one_chunk(spec, *c, ws, lo, q, (None,) * len(ws),
+                                 first), (x, idx, weights))
     # A chunk keeps nothing for its backward but its inputs: what it kept
     # would be stacked over the chunks, which is the buffer chunks avoid.
     return out, (x, idx, weights, ws, lo, q, first)
@@ -769,8 +772,11 @@ def _chunks_bwd(spec, int8, res, dout):
         dxc, dwc, dws = vjp(dc)
         return dws, (dxc, dwc)
 
-    zeros = tuple(jnp.zeros(w.shape, jnp.float32) for w in ws)
-    dws, (dx, dweights) = jax.lax.scan(step, zeros, (x, idx, weights, dout))
+    with jax.named_scope("tony.moe.experts"):
+        zeros = tuple(jnp.zeros(w.shape, jnp.float32) for w in ws)
+    with jax.named_scope("tony.moe.dispatch"):     # as the forward's loop
+        dws, (dx, dweights) = jax.lax.scan(step, zeros,
+                                           (x, idx, weights, dout))
     return dx, None, dweights, dws, None
 
 

@@ -20,8 +20,13 @@ Design choices map straight onto TPU hardware:
   one residual around it, so a layer may be one part alone. Without
   ``layers`` every layer is the default: attention, then the dense MLP.
 
-Spans (``jax.named_scope``): ``tony.attn.rope``, ``tony.attn.gate``; the
-state-space mixer's are in ``models/ssm.py``. Counters, sown into
+Spans (``jax.named_scope``, one where each layer's work happens, so that a
+device trace reads by layer: ``profiling/scopes.py``): ``tony.embed``,
+``tony.norm``, ``tony.attn.proj`` (q, k, v and the output projection),
+``tony.attn.rope``, ``tony.attn.core`` (the kernel call and the layouts
+around it), ``tony.attn.gate``, ``tony.mlp``, ``tony.loss_head`` (both
+losses, and the head of the full-logits path); the state-space mixer's are
+in ``models/ssm.py``, the experts' in ``models/moe.py``. Counters, sown into
 ``intermediates`` and reduced by ``layer_counters``: ``attn_gate_mean``,
 ``ssm_dt_mean``, ``ssm_decay_mean``.
 """
@@ -273,10 +278,11 @@ class RMSNorm(nn.Module):
             "scale", nn.with_logical_partitioning(nn.initializers.ones,
                                                   ("norm",)),
             (x.shape[-1],), self.param_dtype)
-        var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
-                       keepdims=True)
-        y = x.astype(jnp.float32) * jax.lax.rsqrt(var + self.eps)
-        return (y * scale).astype(x.dtype)
+        with jax.named_scope("tony.norm"):
+            var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
+                           keepdims=True)
+            y = x.astype(jnp.float32) * jax.lax.rsqrt(var + self.eps)
+            return (y * scale).astype(x.dtype)
 
 
 class Attention(nn.Module):
@@ -294,47 +300,51 @@ class Attention(nn.Module):
         # heads-major, so sharding it over tp == sharding heads over tp.
         # (DenseGeneral flattens multi-dim kernels before calling
         # kernel_init, which breaks 3-axis logical metadata.)
-        q = _dense(cfg, n_heads * head_dim, ("embed", "heads"), "wq")(
-            x).reshape(b, s, n_heads, head_dim)
-        k = _dense(cfg, n_kv_heads * head_dim, ("embed", "kv_heads"),
-                   "wk")(x).reshape(b, s, n_kv_heads, head_dim)
-        v = _dense(cfg, n_kv_heads * head_dim, ("embed", "kv_heads"),
-                   "wv")(x).reshape(b, s, n_kv_heads, head_dim)
+        with jax.named_scope("tony.attn.proj"):
+            q = _dense(cfg, n_heads * head_dim, ("embed", "heads"), "wq")(
+                x).reshape(b, s, n_heads, head_dim)
+            k = _dense(cfg, n_kv_heads * head_dim, ("embed", "kv_heads"),
+                       "wk")(x).reshape(b, s, n_kv_heads, head_dim)
+            v = _dense(cfg, n_kv_heads * head_dim, ("embed", "kv_heads"),
+                       "wv")(x).reshape(b, s, n_kv_heads, head_dim)
         if spec.rope:
             rope = RopeSpec(cfg.rope_theta) if spec.rope is True \
                 else spec.rope
             with jax.named_scope("tony.attn.rope"):
                 q = _rope(q, positions, rope)
                 k = _rope(k, positions, rope)
-        q = nn.with_logical_constraint(q, ("batch", "seq", "heads", "kv"))
-        k = nn.with_logical_constraint(k, ("batch", "seq", "kv_heads", "kv"))
-        v = nn.with_logical_constraint(v, ("batch", "seq", "kv_heads", "kv"))
+        # What is left around the kernel: the head layouts, the
+        # transposes in and out, and the kernel call itself.
+        with jax.named_scope("tony.attn.core"):
+            q = nn.with_logical_constraint(q, ("batch", "seq", "heads", "kv"))
+            k, v = (nn.with_logical_constraint(
+                t, ("batch", "seq", "kv_heads", "kv")) for t in (k, v))
 
-        if cfg.attn_impl == "flash":
-            o = flash_attention(q, k, v, causal=True,
-                                block_q=cfg.attn_block_q,
-                                block_k=cfg.attn_block_k,
-                                window=spec.window)
-        elif cfg.attn_impl == "xla":
-            g = n_heads // n_kv_heads
-            o = reference_attention(q, jnp.repeat(k, g, axis=2),
-                                    jnp.repeat(v, g, axis=2), causal=True,
+            if cfg.attn_impl == "flash":
+                o = flash_attention(q, k, v, causal=True,
+                                    block_q=cfg.attn_block_q,
+                                    block_k=cfg.attn_block_k,
                                     window=spec.window)
-        elif cfg.attn_impl == "ring":
-            # GQA-native: K/V ride the ring at kv-head width (no repeat).
-            # Ring and Ulysses refuse a window.
-            o = ring_attention(q, k, v, axis_name="sp", causal=True,
-                               block_q=cfg.attn_block_q,
-                               block_k=cfg.attn_block_k,
-                               window=spec.window)
-        elif cfg.attn_impl == "ulysses":
-            o = ulysses_attention(q, k, v, axis_name="sp", causal=True,
-                                  block_q=cfg.attn_block_q,
-                                  block_k=cfg.attn_block_k,
-                                  window=spec.window)
-        else:
-            raise ValueError(f"unknown attn_impl {cfg.attn_impl!r}")
-        o = nn.with_logical_constraint(o, ("batch", "seq", "heads", "kv"))
+            elif cfg.attn_impl == "xla":
+                g = n_heads // n_kv_heads
+                o = reference_attention(q, jnp.repeat(k, g, axis=2),
+                                        jnp.repeat(v, g, axis=2),
+                                        causal=True, window=spec.window)
+            elif cfg.attn_impl == "ring":
+                # GQA-native: K/V ride the ring at kv-head width (no repeat).
+                # Ring and Ulysses refuse a window.
+                o = ring_attention(q, k, v, axis_name="sp", causal=True,
+                                   block_q=cfg.attn_block_q,
+                                   block_k=cfg.attn_block_k,
+                                   window=spec.window)
+            elif cfg.attn_impl == "ulysses":
+                o = ulysses_attention(q, k, v, axis_name="sp", causal=True,
+                                      block_q=cfg.attn_block_q,
+                                      block_k=cfg.attn_block_k,
+                                      window=spec.window)
+            else:
+                raise ValueError(f"unknown attn_impl {cfg.attn_impl!r}")
+            o = nn.with_logical_constraint(o, ("batch", "seq", "heads", "kv"))
         if spec.gate:
             # One scalar a head and token, from what q, k and v are read
             # from. The projection is H columns wide: it stays outside the
@@ -346,8 +356,9 @@ class Attention(nn.Module):
                         x).astype(jnp.float32))
                 self.sow("intermediates", "attn_gate_mean", jnp.mean(gate))
                 o = (o.astype(jnp.float32) * gate[..., None]).astype(o.dtype)
-        o = o.reshape(b, s, n_heads * head_dim)
-        return _dense(cfg, cfg.dim, ("heads", "embed"), "wo")(o)
+        with jax.named_scope("tony.attn.proj"):
+            o = o.reshape(b, s, n_heads * head_dim)
+            return _dense(cfg, cfg.dim, ("heads", "embed"), "wo")(o)
 
 
 class MLP(nn.Module):
@@ -356,11 +367,12 @@ class MLP(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        gate = _dense(cfg, cfg.mlp_dim, ("embed", "mlp"), "gate")(x)
-        up = _dense(cfg, cfg.mlp_dim, ("embed", "mlp"), "up")(x)
-        h = nn.silu(gate) * up
-        h = nn.with_logical_constraint(h, ("batch", "seq", "mlp"))
-        return _dense(cfg, cfg.dim, ("mlp", "embed"), "down")(h)
+        with jax.named_scope("tony.mlp"):
+            gate = _dense(cfg, cfg.mlp_dim, ("embed", "mlp"), "gate")(x)
+            up = _dense(cfg, cfg.mlp_dim, ("embed", "mlp"), "up")(x)
+            h = nn.silu(gate) * up
+            h = nn.with_logical_constraint(h, ("batch", "seq", "mlp"))
+            return _dense(cfg, cfg.dim, ("mlp", "embed"), "down")(h)
 
 
 class Block(nn.Module):
@@ -448,8 +460,9 @@ class Transformer(nn.Module):
             "embedding", nn.with_logical_partitioning(
                 nn.initializers.normal(0.02), ("vocab_table", "embed_table")),
             (cfg.vocab_size, cfg.dim), cfg.param_dtype)
-        x = emb[tokens].astype(cfg.dtype)
-        x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
+        with jax.named_scope("tony.embed"):
+            x = emb[tokens].astype(cfg.dtype)
+            x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         block = Block
         if cfg.remat:
             # prevent_cse MUST stay True here: layers are a Python loop
@@ -469,18 +482,19 @@ class Transformer(nn.Module):
         if return_hidden:
             return x
         head_dtype = cfg.lm_head_dtype or cfg.dtype
-        if cfg.tie_embeddings:
-            logits = jnp.einsum("bsd,vd->bsv", x.astype(head_dtype),
-                                emb.astype(head_dtype),
-                                preferred_element_type=jnp.float32)
-        else:
-            logits = nn.Dense(
-                cfg.vocab_size, use_bias=False, dtype=head_dtype,
-                param_dtype=cfg.param_dtype, name="lm_head",
-                kernel_init=nn.with_logical_partitioning(
-                    nn.initializers.lecun_normal(), ("embed", "vocab")))(
-                        x.astype(head_dtype))
-        return logits.astype(jnp.float32)
+        with jax.named_scope("tony.loss_head"):
+            if cfg.tie_embeddings:
+                logits = jnp.einsum("bsd,vd->bsv", x.astype(head_dtype),
+                                    emb.astype(head_dtype),
+                                    preferred_element_type=jnp.float32)
+            else:
+                logits = nn.Dense(
+                    cfg.vocab_size, use_bias=False, dtype=head_dtype,
+                    param_dtype=cfg.param_dtype, name="lm_head",
+                    kernel_init=nn.with_logical_partitioning(
+                        nn.initializers.lecun_normal(), ("embed", "vocab")))(
+                            x.astype(head_dtype))
+            return logits.astype(jnp.float32)
 
 
 def layer_counters(intermediates) -> dict:
@@ -500,6 +514,7 @@ def layer_counters(intermediates) -> dict:
     return out
 
 
+@jax.named_scope("tony.loss_head")
 def causal_lm_loss(logits: jax.Array, tokens: jax.Array,
                    mask: Optional[jax.Array] = None) -> jax.Array:
     """Next-token cross entropy; logits [B,S,V] predict tokens shifted.
@@ -519,6 +534,7 @@ def causal_lm_loss(logits: jax.Array, tokens: jax.Array,
     return jnp.mean(nll)
 
 
+@jax.named_scope("tony.loss_head")
 def chunked_causal_lm_loss(hidden: jax.Array, head_kernel: jax.Array,
                            tokens: jax.Array, chunk_size: int = 4096,
                            mask: Optional[jax.Array] = None,

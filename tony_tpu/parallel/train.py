@@ -9,6 +9,9 @@ plane is someone else's problem" stance (SURVEY.md §2.4).
 
 from __future__ import annotations
 
+import contextlib
+import logging
+import time
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import flax.linen as nn
@@ -21,6 +24,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from tony_tpu import telemetry
 from tony_tpu.parallel.mesh import tree_batch_shardings
 from tony_tpu.parallel.sharding import DEFAULT_RULES, param_shardings
+from tony_tpu.profiling import scopes
+
+log = logging.getLogger(__name__)
 
 
 @struct.dataclass
@@ -78,6 +84,66 @@ def init_sharded_state(
     return state, state_sh
 
 
+@contextlib.contextmanager
+def _metadata_in_the_cache_key():
+    """jax keys its persistent compile cache by a module WITHOUT its
+    metadata, so the executable it serves may carry the ``op_name``s of
+    whichever version of the program compiled it first: good enough to run,
+    wrong to read scopes from. Inside, the metadata is part of the key."""
+    name = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, name)
+    jax.config.update(name, True)
+    try:
+        yield
+    finally:
+        jax.config.update(name, before)
+
+
+class LoweredStep:
+    """jax's ``Lowered`` of a step (every attribute is that object's), whose
+    ``compile()`` also leaves the compiled module's map from instruction to
+    scope (``profiling/scopes.py step_scopes``) as one closed span of the
+    job's span log, ``user.step_scopes``: what joins a device trace's
+    instruction names to the model's layers and the step's passes. A step
+    that is only called records nothing: the map costs a pass over the
+    compiled text, and whoever wants it compiles the step by this door.
+
+    The executable the call path holds may have come from the persistent
+    cache with another version's metadata (``_metadata_in_the_cache_key``),
+    and jax hands a ``Lowered`` that executable back without asking the
+    compiler. So this compile names a compiler option, at its default, which
+    sends it to the compiler's cache under a key with the metadata in: the
+    first such compile of a program's version is a whole one, every later
+    one a fetch; the instructions are the call path's, name for name."""
+
+    #: at its default: changes no compile, only jax's choice to make one
+    KEEP_METADATA = {"xla_dump_disable_metadata": False}
+
+    def __init__(self, lowered, fun_name: str):
+        self._lowered, self._fun_name = lowered, fun_name
+
+    def __getattr__(self, name: str):
+        return getattr(self._lowered, name)
+
+    def compile(self, compiler_options: Optional[dict] = None, **kwargs):
+        with _metadata_in_the_cache_key():
+            compiled = self._lowered.compile(
+                {**self.KEEP_METADATA, **(compiler_options or {})}, **kwargs)
+        start = time.time()
+        try:
+            record = scopes.step_scopes(compiled.as_text())
+        except Exception:  # noqa: BLE001 — the map is diagnostics only
+            log.exception("no step_scopes record for %s", self._fun_name)
+            return compiled
+        # Name lists as one string each: a span's args are a line of JSON.
+        record["scopes"] = {key: " ".join(names)
+                            for key, names in record["scopes"].items()}
+        record["with_update"] = " ".join(record["with_update"])
+        telemetry.record_span("user.step_scopes", start, time.time(),
+                              keep=True, fun_name=self._fun_name, **record)
+        return compiled
+
+
 def jit_train_step(
     loss_fn: Callable[[Any, Any, jax.Array], Tuple[jax.Array, dict]],
     mesh: Mesh,
@@ -94,7 +160,8 @@ def jit_train_step(
     per ``state_shardings`` — XLA derives every collective from there. The
     mesh is bound (``jax.set_mesh``) around every call, which is what lets
     the Pallas kernels in the model run per shard; ``step.lower(state,
-    batch, rng)`` lowers under the same binding, for ahead-of-time compiles.
+    batch, rng)`` lowers under the same binding, for ahead-of-time compiles
+    (a ``LoweredStep``: its ``compile()`` records the step's scopes).
     """
     def step(state: TrainState, batch: Any, rng: jax.Array):
         # Two scopes, so that every fusion's op_name metadata says which
@@ -136,7 +203,8 @@ def jit_train_step(
         ``ShapeDtypeStruct``s, so the step can be compiled ahead of time
         for devices this host does not have (a TPU topology)."""
         with jax.set_mesh(mesh):
-            return jitted.lower(state, batch, rng)
+            return LoweredStep(jitted.lower(state, batch, rng),
+                               step.__name__)
 
     wrapped.lower = lower
     return wrapped

@@ -23,6 +23,7 @@ and the compile listeners exist only where ``jax`` is already loaded.
 
 from __future__ import annotations
 
+import atexit
 import collections
 import contextlib
 import json
@@ -36,6 +37,7 @@ from tony_tpu import constants
 
 _started = threading.Lock()
 _thread: Optional[threading.Thread] = None
+_stopping = threading.Event()       # set at interpreter exit
 
 # ---------------------------------------------------------------------------
 # Step-time utilization (the reference samples GPU duty cycle via
@@ -133,7 +135,10 @@ _phase_ring: Deque[dict] = collections.deque(maxlen=PHASE_RING_STEPS)
 #   task's run span (executor._forward_user_spans). Only what is rare
 #   goes this way — boot (``user.pre_import``, ``user.backend_init``,
 #   ``user.init_state``) and every compile (``user.compile``: a recompile
-#   at step 4,000 shows with its step) — never a per-step span. The list
+#   at step 4,000 shows with its step) and, where the step was compiled
+#   ahead of time, its map from instruction to scope
+#   (``user.step_scopes``, ``parallel/train.py``: 12–65 KB, once) — never
+#   a per-step span. The list
 #   has a file of its own beside the metrics file (``spans_file``),
 #   rewritten only when it grew; the metrics file, rewritten every tick,
 #   carries the counters and ``spans_kept``, which tells the executor
@@ -172,13 +177,16 @@ _jax_hooks_installed = False
 _spans_written: tuple = ("", 0)     # (metrics path, spans in its spans file)
 
 
-def record_span(name: str, start: float, end: float, **attrs: Any) -> None:
+def record_span(name: str, start: float, end: float, keep: bool = False,
+                **attrs: Any) -> None:
     """Keep one closed span of this process (wall-clock seconds) for the
-    job's span log. Past ``SPAN_CAP`` the span is counted and dropped."""
+    job's span log. Past ``SPAN_CAP`` the span is counted and dropped,
+    unless ``keep`` says it is one a caller asked for by hand (a compiled
+    step's map, ``user.step_scopes``) and no burst can repeat."""
     global _spans_closed
     with _span_lock:
         _spans_closed += 1
-        if len(_spans) < SPAN_CAP:
+        if keep or len(_spans) < SPAN_CAP:
             _spans.append({"seq": _spans_closed, "name": name,
                            "start": start, "end": max(end, start),
                            "args": attrs})
@@ -670,19 +678,15 @@ def collect_device_stats() -> Dict[str, float]:
             jax, devices = None, []
         if jax is not None:
             out["device_count"] = float(len(devices))
-            in_use = peak = 0.0
+            in_use = 0.0
             for d in devices:
                 try:
                     stats = d.memory_stats() or {}
                 except Exception:  # noqa: BLE001
                     stats = {}
-                b = float(stats.get("bytes_in_use", 0) or 0)
-                p = float(stats.get("peak_bytes_in_use", b) or b)
-                in_use += b
-                peak += p
+                in_use += float(stats.get("bytes_in_use", 0) or 0)
                 kinds.append(str(getattr(d, "device_kind", "?")))
             out["hbm_bytes_in_use"] = in_use
-            out["hbm_peak_bytes"] = peak
     util = step_stats()
     if util:
         out.update(util)
@@ -751,7 +755,7 @@ def write_stats_once(path: str) -> bool:
 
 
 def _loop(path: str, interval_s: float) -> None:
-    while True:
+    while not _stopping.is_set():
         # On-demand profiling directive intake first, so a request
         # written just before this tick arms at the very next boundary.
         try:
@@ -767,6 +771,18 @@ def _loop(path: str, interval_s: float) -> None:
         if _span_added.wait(interval_s):
             time.sleep(SPAN_FLUSH_DELAY_S)
             _span_added.clear()
+
+
+def _stop_reporter() -> None:
+    """At interpreter exit, before the threads' teardown: let the reporter
+    finish the write it is in and end. A daemon thread that the teardown
+    catches inside jax's native code (``memory_stats``) takes the process
+    down with an abort after its work is done; a span closed just before
+    the exit (a compile, a step's map) wakes the reporter exactly then."""
+    _stopping.set()
+    _span_added.set()
+    if _thread is not None:
+        _thread.join(timeout=2.0)
 
 
 def maybe_start(interval_s: float = 3.0) -> bool:
@@ -791,6 +807,7 @@ def maybe_start(interval_s: float = 3.0) -> bool:
         _thread = threading.Thread(target=_loop, args=(path, interval_s),
                                    name="tony-telemetry", daemon=True)
         _thread.start()
+        atexit.register(_stop_reporter)
         return True
 
 

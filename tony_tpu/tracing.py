@@ -57,6 +57,8 @@ import time
 import uuid
 from typing import Any, Dict, List, Optional, Tuple, Union
 
+from tony_tpu import telemetry
+
 log = logging.getLogger(__name__)
 
 
@@ -456,13 +458,21 @@ def cold_start_breakdown(records: List[Dict[str, Any]]) -> Dict[str, Any]:
          "task": "worker:0",
          "phases": {phase: seconds, ...},     # ordered, consecutive
          "span_durations": {name: seconds},   # raw (possibly overlapping)
-         "user_boot": {name: seconds}}        # only with ``user.*`` spans
+         "user_boot": {name: seconds},        # only with ``user.*`` spans
+         "user_boot_compile": {stage: seconds},   # ... with ``user.compile``
+         "step_scopes": {...}}                # ... with ``user.step_scopes``
 
     ``user_boot`` splits the ``user_boot`` phase by the anchor task's
     ``user.*`` spans (the user process's own: telemetry.record_span) into
     SELF times per span name — a span's time less what the spans opened
     inside it cover — plus ``unattributed``; the values sum to
-    ``phases["user_boot"]``.
+    ``phases["user_boot"]``. ``user_boot_compile`` splits
+    ``user_boot["user.compile"]`` by the spans' ``stage`` (``trace``,
+    ``lower``, ``backend``: jax's tracing, its lowering to MLIR, and the
+    backend's compile or the fetch from the persistent cache).
+    ``step_scopes`` is the newest ``user.step_scopes`` span's record (a
+    compiled step's map from instruction to pass and scope,
+    ``profiling/scopes.py``), whenever in the job it was made.
     """
     payload = to_trace_events(records)
     events = [e for e in payload["traceEvents"] if e.get("ph") == "X"]
@@ -522,21 +532,41 @@ def cold_start_breakdown(records: List[Dict[str, Any]]) -> Dict[str, Any]:
             durations[name] = round(e.get("dur", 0) / 1e6, 4)
     out = {"total_s": round((end - t0) / 1e6, 4), "task": task,
            "phases": phases, "span_durations": durations}
-    user = [(int(e["ts"]), int(e["ts"] + e.get("dur", 0)), e["name"])
-            for e in events
+    mine = [e for e in events
             if e["name"].startswith("user.") and _task(e) == task]
-    boot = _self_times(user, boot_start, end)
-    if boot:
+    # Owned by (name, stage): a compile's stages part its self time.
+    owned = _self_times(
+        [(int(e["ts"]), int(e["ts"] + e.get("dur", 0)),
+          (e["name"], str(e["args"].get("stage", ""))
+           if e["name"] == "user.compile" else "")) for e in mine],
+        boot_start, end)
+    if owned:
+        boot: Dict[str, float] = {}
+        stages = dict.fromkeys(telemetry.COMPILE_STAGES.values(), 0)
+        for (name, stage), us in owned.items():
+            boot[name] = boot.get(name, 0) + us
+            if name == "user.compile":
+                stages[stage] = stages.get(stage, 0) + us
         boot = {name: round(us / 1e6, 4) for name, us in boot.items()}
         boot["unattributed"] = round(
             phases.get("user_boot", 0.0) - sum(boot.values()), 4)
         out["user_boot"] = boot
+        if "user.compile" in boot:
+            out["user_boot_compile"] = {
+                stage: round(us / 1e6, 4) for stage, us in stages.items()}
+    maps = [e for e in mine if e["name"] == "user.step_scopes"]
+    if maps:
+        newest = max(maps, key=lambda e: e["ts"])
+        out["step_scopes"] = {k: v for k, v in newest["args"].items()
+                              if k not in ("trace", "span", "parent",
+                                           "task")}
     return out
 
 
-def _self_times(spans: List[Tuple[int, int, str]], lo: int,
-                hi: int) -> Dict[str, int]:
-    """Microseconds of ``[lo, hi]`` owned by each span name: every instant
+def _self_times(spans: List[Tuple[int, int, Any]], lo: int,
+                hi: int) -> Dict[Any, int]:
+    """Microseconds of ``[lo, hi]`` owned by each span name (any key that
+    hashes: the third of a span's triple): every instant
     belongs to the innermost span open at it (the one opened last), so a
     parent keeps its duration less what its children cover, and the
     instants no span covers belong to no name."""
