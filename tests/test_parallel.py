@@ -100,6 +100,39 @@ def test_train_step_loss_decreases_sharded():
     assert int(state.step) == 20
 
 
+def test_apply_gradients_is_optax_adamw_bit_for_bit():
+    """The barrier ``apply_gradients`` puts on each gradient changes no
+    arithmetic: over three steps, params and the whole AdamW state (both
+    moments, the count) equal, bit for bit,
+    ``optax.apply_updates(params, tx.update(grads, ...))``."""
+    tx = optax.adamw(1e-2, weight_decay=0.1)
+    keys = jax.random.split(jax.random.key(0), 6)
+    params = {"w": jax.random.normal(keys[0], (64, 128)),
+              "v": jax.random.normal(keys[1], (128,)),
+              "k": jax.random.normal(keys[2], (4, 16, 8))}
+
+    @jax.jit
+    def ours(state, grads):
+        return state.apply_gradients(grads)
+
+    @jax.jit
+    def theirs(params, opt_state, grads):
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state
+
+    state = TrainState(step=jnp.zeros((), jnp.int32), params=params,
+                       opt_state=tx.init(params), tx=tx)
+    want = (params, tx.init(params))
+    for i in range(3):
+        grads = jax.tree.map(
+            lambda p, k=keys[3 + i]: jax.random.normal(k, p.shape), params)
+        state = ours(state, grads)
+        want = theirs(*want, grads)
+    jax.tree.map(np.testing.assert_array_equal,
+                 (state.params, state.opt_state), want)
+    assert int(state.step) == 3
+
+
 def test_batch_sharding_splits_batch_dim():
     mesh = build_mesh(MeshSpec(dp=4, fsdp=2))
     sh = batch_sharding(mesh, extra_dims=2)

@@ -39,6 +39,13 @@ class TrainState:
     tx: optax.GradientTransformation = struct.field(pytree_node=False)
 
     def apply_gradients(self, grads: Any) -> "TrainState":
+        # A barrier on each leaf's gradient keeps its update out of the
+        # product that makes the gradient. Fused in as the product's
+        # epilogue, the update's three f32 states in and out shrank the
+        # product's tiles: on m7b.seq2k fifteen such fusions took 195.5 ms
+        # a step, the same products and updates apart 145 ms (PERF.md 6,
+        # PR 37). The barrier leaves no instruction in the compiled step.
+        grads = jax.tree.map(jax.lax.optimization_barrier, grads)
         updates, new_opt = self.tx.update(grads, self.opt_state, self.params)
         return self.replace(step=self.step + 1,
                             params=optax.apply_updates(self.params, updates),
