@@ -1,0 +1,10 @@
+"""``step_s_p95`` in the cell ``g4hm.seq8k``: that metric's reader under a name
+this cell's entry can list (``same_reader``)."""
+import same_reader
+
+NAME, UNIT, SOURCE = "step_s_p95.g4hm", "s", "host_clock"
+LAYER, MOVES = "train step", "tokens_per_s_per_chip"
+
+read = same_reader.of("step_s_p95").read
+
+note = same_reader.of("step_s_p95").note

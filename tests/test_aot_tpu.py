@@ -429,8 +429,9 @@ def test_the_laguna_cell_s_step_compiles_for_one_v5e_and_fits(v5e_devices):
                      "flash_win_dkv": 3, "moe_gmm": 36, "moe_tgmm": 12}
 
 
-# ``nem30b.seq8k``'s step, as ``step_hbm_gb_per_chip.nem30b`` reads it.
-NEM30B_STEP_BYTES = 11_321_815_040
+# ``nem30b.seq8k``'s step, as ``step_hbm_gb_per_chip.nem30b`` reads it (the
+# head RMS counter's reduction is the 194,048 B over 11,321,815,040).
+NEM30B_STEP_BYTES = 11_322_009_088
 
 
 @pytest.mark.timeout_s(900)
@@ -486,3 +487,24 @@ def test_the_scan_kernels_compile_at_published_widths(v5e_devices,
     for call in calls.values():
         assert not any(shape[-2:] == [128, 128] for shape in call["shapes"])
     assert not re.findall(r"\[2,64,[\d,]*128,128\]", hlo)
+
+
+# ``g4hm.seq8k``'s step, as ``step_hbm_gb_per_chip.g4hm`` reads it.
+G4HM_STEP_BYTES = 12_103_374_336
+
+
+@pytest.mark.timeout_s(900)
+def test_the_granite_cell_s_step_compiles_for_one_v5e_and_fits(v5e_devices):
+    """Granite 4.0-H Micro's share at published widths (772,160,448
+    parameters: 12.35 GB of state at 16 B) through ``jit_train_step`` at 2 ×
+    8,192 tokens: the step passes XLA:TPU and Mosaic (the scan over one
+    group of 64 heads of 64 in chunks of 256, eight head blocks a grid;
+    flash at heads of 64 and the scale 1/64), fits the chip, and holds a
+    Mamba layer's ``ssd_fwd`` twice and ``ssd_bwd`` once, nine times, and
+    one full flash layer."""
+    total, names = _cell_step(v5e_devices, "granitemoehybrid",
+                              "granite-4.0-h-micro")
+    assert total == G4HM_STEP_BYTES, total
+    assert total < 16e9 and total > 0.25 * 16e9
+    assert names == {"ssd_fwd": 18, "ssd_bwd": 9, "flash_fwd": 1,
+                     "flash_dq": 1, "flash_dkv": 1}

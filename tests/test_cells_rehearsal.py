@@ -3,8 +3,8 @@
 ``benchmarks/cells/run.py`` is the one yardstick (``BENCHMARK.json``); its
 numbers come only from a TPU. What tier-1 can hold is the plumbing a
 ``tony_tpu/`` change could break before the chip is asked: that each cell of
-the rehearsal table, and the tiny cells of the two newest architectures' own
-tables, goes through submit → coordinator → executor → the train
+the rehearsal table, and the tiny cells of the three newest architectures'
+own tables, goes through submit → coordinator → executor → the train
 script and comes back ``correct`` against the plain reference, without a
 metric, and that the runner refuses to give a result where there is no TPU.
 The runner is invoked as the driver invokes it: a subprocess, from the root
@@ -25,6 +25,7 @@ TABLE = os.path.join(FIXTURES, "rehearsal", "table.json")
 # An architecture brings a rehearsal table of its own.
 LAGUNA_TABLE = os.path.join(FIXTURES, "rehearsal_laguna", "table.json")
 NEMOTRON_TABLE = os.path.join(FIXTURES, "rehearsal_nemotron_h", "table.json")
+GRANITE_TABLE = os.path.join(FIXTURES, "rehearsal_granite", "table.json")
 SEED = 3000000391       # no other caller's: the output directory is its own
 
 
@@ -42,7 +43,8 @@ def _run(workload, *args):
 @pytest.mark.timeout_s(150)
 @pytest.mark.parametrize("workload, table", [
     ("tiny.b4", TABLE), ("tiny.b4-4dev", TABLE), ("tiny_tied.b4", TABLE),
-    ("tiny_lag.b4", LAGUNA_TABLE), ("tiny_nem.b4", NEMOTRON_TABLE)])
+    ("tiny_lag.b4", LAGUNA_TABLE), ("tiny_nem.b4", NEMOTRON_TABLE),
+    ("tiny_g4h.b4", GRANITE_TABLE)])
 def test_rehearsal_cell_is_correct_and_reports_no_metric(workload, table):
     r, out_dir = _run(workload, "--seconds", "2", "--rehearsal",
                       "--table", table)

@@ -122,8 +122,9 @@ def test_loss_and_gradients_match_the_reference(bench, cfg):
         np.testing.assert_allclose(np.asarray(g) / scale,
                                    np.asarray(w) / scale, atol=2e-4,
                                    err_msg="/".join(path))
-    # the step's counters: the expert layers' and the mixers' two
-    assert set(aux) == {"ssm_dt_mean", "ssm_decay_mean", "moe_rows_routed",
+    # the step's counters: the expert layers' and the mixers' three
+    assert set(aux) == {"ssm_dt_mean", "ssm_decay_mean",
+                        "ssm_head_rms_max_over_median", "moe_rows_routed",
                         "moe_rows_unrouted_share",
                         "moe_expert_load_max_over_mean",
                         "moe_buffer_rows_live_share",

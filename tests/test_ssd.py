@@ -2,9 +2,11 @@
 stands for, token by token: the Mosaic kernels in interpret mode and the
 ``jax.numpy`` chunked path, ``y`` and the gradient of every input; a state
 carried over a chunk's boundary; rows that do not leak into each other; heads
-in packs and alone; bf16 inputs' gradients as close as they were before the
-shared products were taken once; the products a grid step runs, counted; a
-length that is no whole number of chunks refused."""
+in packs and alone, a group's heads in blocks of eight, and one group of 64
+heads at chunks of 256 against the ``jax.numpy`` path; bf16 inputs'
+gradients as close as they were before the shared products were taken once;
+the products a grid step runs, counted; a length that is no whole number of
+chunks refused."""
 
 import math
 
@@ -14,7 +16,7 @@ import numpy as np
 import pytest
 
 from tony_tpu.ops import ssd as ssd_module
-from tony_tpu.ops.ssd import _pack, carried_states, ssd
+from tony_tpu.ops.ssd import _block_heads, _pack, carried_states, ssd
 
 IMPLS = ("kernel", "jnp")
 
@@ -65,8 +67,10 @@ def close(got, want, tol=2e-5):
     dict(h=2, p=128, g=1),      # heads as wide as the lanes: one at a time
     dict(h=4, p=8, g=4),        # a group a head
     dict(h=4, p=64, g=1),       # the cell's kind: two packs of two in a group
-    dict(h=8, p=64, g=2)],      # and several such groups
-    ids=["pairs", "threes", "wide", "alone", "packs", "groups-of-packs"])
+    dict(h=8, p=64, g=2),       # and several such groups
+    dict(h=16, p=64, g=1)],     # a group of two head blocks
+    ids=["pairs", "threes", "wide", "alone", "packs", "groups-of-packs",
+         "blocks"])
 def test_y_and_every_gradient_match_the_recurrence(impl, shape):
     """Four chunks of 16: the output and the gradients of x, Δ, A, B, C and
     D are those of the token-by-token recurrence."""
@@ -89,6 +93,35 @@ def test_y_and_every_gradient_match_the_recurrence(impl, shape):
 def test_packs_fill_the_lanes():
     assert [_pack(8, 64), _pack(4, 8), _pack(6, 8), _pack(2, 128),
             _pack(1, 8), _pack(8, 48)] == [2, 4, 6, 1, 1, 2]
+
+
+def test_a_grid_step_holds_at_most_eight_heads():
+    """A group of 8 is one block (the grid of 8 groups keeps its steps); one
+    group of 64 is eight blocks of 8."""
+    assert [_block_heads(8), _block_heads(64), _block_heads(16),
+            _block_heads(6), _block_heads(12), _block_heads(1)] == [
+        8, 8, 8, 6, 6, 1]
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_one_group_of_64_heads_at_chunks_of_256_is_the_jnp_path(seed):
+    """The published Granite 4.0-H mixer's scan: 64 heads of 64 reading one
+    group's B and C (state 128), two chunks of 256: the kernels, eight head
+    blocks a group, give the ``jax.numpy`` path's output and gradients."""
+    args = inputs(batch=1, s=512, h=64, p=64, g=1, n=128, seed=seed)
+    weight = jax.random.normal(jax.random.key(9), args[0].shape)
+
+    def scalar(impl):
+        return lambda *a: jnp.sum(ssd(*a, chunk=256, impl=impl) * weight)
+
+    with jax.default_matmul_precision("highest"):
+        close(ssd(*args, chunk=256, impl="kernel"),
+              ssd(*args, chunk=256, impl="jnp"))
+        got, want = (jax.grad(scalar(impl), argnums=range(6))(*args)
+                     for impl in IMPLS)
+    for g, w in zip(got, want):
+        assert np.asarray(w).any()
+        close(g, w)
 
 
 @pytest.mark.parametrize("impl", IMPLS)

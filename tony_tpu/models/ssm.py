@@ -27,8 +27,11 @@ refuse a stack that has one (``models/transformer.py``).
 Spans (``jax.named_scope``): ``tony.ssm.in_proj``, ``tony.ssm.conv``,
 ``tony.ssm.scan``, ``tony.ssm.gate_norm``, ``tony.ssm.out_proj``. Counters,
 sown into ``intermediates`` and reduced by ``transformer.layer_counters``:
-``ssm_dt_mean`` (mean Δ) and ``ssm_decay_mean`` (mean ``a_t = exp(Δ·A)``: 0
-is a state that forgets at once, 1 one that never does).
+``ssm_dt_mean`` (mean Δ), ``ssm_decay_mean`` (mean ``a_t = exp(Δ·A)``: 0 is
+a state that forgets at once, 1 one that never does) and
+``ssm_head_rms_max_over_median`` (each head's RMS of ``y`` over its tokens
+and columns, before the gate; the largest over the median: far above 1, one
+head sets the norm's statistics of its group).
 """
 
 from __future__ import annotations
@@ -136,6 +139,10 @@ class SSMixer(nn.Module):
                     leaf("D", nn.initializers.ones, (h,), ("ssm_heads",)),
                     chunk=spec.chunk)
         with jax.named_scope("tony.ssm.gate_norm"):
+            rms = jnp.sqrt(jnp.mean(jnp.square(y.astype(jnp.float32)),
+                                    axis=(0, 1, 3)))
+            self.sow("intermediates", "ssm_head_rms_max_over_median",
+                     jnp.max(rms) / jnp.median(rms))
             gated = (y.reshape(b, s, inner).astype(jnp.float32)
                      * nn.silu(z.astype(jnp.float32))).reshape(b, s, g, -1)
             var = jnp.mean(jnp.square(gated), axis=-1, keepdims=True)

@@ -18,7 +18,11 @@ Design choices map straight onto TPU hardware:
   share of a head's columns rotated, a YaRN table) and whether its output
   is gated per head. Every part that is there has one norm before it and
   one residual around it, so a layer may be one part alone. Without
-  ``layers`` every layer is the default: attention, then the dense MLP.
+  ``layers`` every layer is the default: attention, then the dense MLP;
+- four multipliers (Granite 4.0's): on the embedding's rows, on every part's
+  output before its residual add, the softmax's scale in place of
+  ``head_dim^-½``, and a divisor of the logits. Each is the identity when
+  unset, and then adds no operation to the step.
 
 Spans (``jax.named_scope``, one where each layer's work happens, so that a
 device trace reads by layer: ``profiling/scopes.py``): ``tony.embed``,
@@ -26,9 +30,14 @@ device trace reads by layer: ``profiling/scopes.py``): ``tony.embed``,
 ``tony.attn.rope``, ``tony.attn.core`` (the kernel call and the layouts
 around it), ``tony.attn.gate``, ``tony.mlp``, ``tony.loss_head`` (both
 losses, and the head of the full-logits path); the state-space mixer's are
-in ``models/ssm.py``, the experts' in ``models/moe.py``. Counters, sown into
-``intermediates`` and reduced by ``layer_counters``: ``attn_gate_mean``,
-``ssm_dt_mean``, ``ssm_decay_mean``.
+in ``models/ssm.py``, the experts' in ``models/moe.py``. A multiplier lives
+in the scope of what it scales: the embedding's under ``tony.embed``, a
+residual branch's under its part's (``tony.attn.proj``, ``tony.ssm.out_proj``,
+``tony.mlp``, ``tony.moe.combine``), the logits' under ``tony.loss_head``,
+the softmax's scale inside the kernel under ``tony.attn.core``. Counters,
+sown into ``intermediates`` and reduced by ``layer_counters``:
+``attn_gate_mean``, ``ssm_dt_mean``, ``ssm_decay_mean``,
+``ssm_head_rms_max_over_median``.
 """
 
 from __future__ import annotations
@@ -186,6 +195,17 @@ class TransformerConfig:
     # One LayerSpec a layer, read by Transformer's one loop; None makes
     # every layer the default (full causal, RoPE, dense MLP).
     layers: Optional[Tuple[LayerSpec, ...]] = None
+    # Multipliers, each the identity when unset (no operation is added): the
+    # embedding's rows times ``embedding_multiplier`` (under tony.embed);
+    # every part's output times ``residual_multiplier`` before its residual
+    # add (in float32, under the part's own scope); ``attention_multiplier``
+    # the softmax's scale (None: head_dim^-½); the logits divided by
+    # ``logits_scaling`` (under tony.loss_head; ``chunked_causal_lm_loss``
+    # takes it as an argument).
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
 
     def __post_init__(self):
         if self.layers is not None and len(self.layers) != self.n_layers:
@@ -322,6 +342,7 @@ class Attention(nn.Module):
 
             if cfg.attn_impl == "flash":
                 o = flash_attention(q, k, v, causal=True,
+                                    scale=cfg.attention_multiplier,
                                     block_q=cfg.attn_block_q,
                                     block_k=cfg.attn_block_k,
                                     window=spec.window)
@@ -329,16 +350,20 @@ class Attention(nn.Module):
                 g = n_heads // n_kv_heads
                 o = reference_attention(q, jnp.repeat(k, g, axis=2),
                                         jnp.repeat(v, g, axis=2),
-                                        causal=True, window=spec.window)
+                                        causal=True,
+                                        scale=cfg.attention_multiplier,
+                                        window=spec.window)
             elif cfg.attn_impl == "ring":
                 # GQA-native: K/V ride the ring at kv-head width (no repeat).
                 # Ring and Ulysses refuse a window.
                 o = ring_attention(q, k, v, axis_name="sp", causal=True,
+                                   scale=cfg.attention_multiplier,
                                    block_q=cfg.attn_block_q,
                                    block_k=cfg.attn_block_k,
                                    window=spec.window)
             elif cfg.attn_impl == "ulysses":
                 o = ulysses_attention(q, k, v, axis_name="sp", causal=True,
+                                      scale=cfg.attention_multiplier,
                                       block_q=cfg.attn_block_q,
                                       block_k=cfg.attn_block_k,
                                       window=spec.window)
@@ -386,32 +411,43 @@ class Block(nn.Module):
         def norm(name):
             return RMSNorm(cfg.norm_eps, cfg.param_dtype, name=name)
 
+        def branch(scope, y):
+            """A part's output as its residual add takes it."""
+            if cfg.residual_multiplier == 1.0:
+                return y
+            with jax.named_scope(scope):
+                return (y.astype(jnp.float32)
+                        * cfg.residual_multiplier).astype(y.dtype)
+
         n, h = None, x
         if spec.mixer == "attention":
             n = norm("attn_norm")(x)
-            h = x + Attention(cfg, spec, name="attn")(n, positions)
+            h = x + branch("tony.attn.proj",
+                           Attention(cfg, spec, name="attn")(n, positions))
         elif spec.mixer is not None:
             if cfg.attn_impl in ("ring", "ulysses"):
                 raise ValueError(
                     f"attn_impl {cfg.attn_impl!r} splits the sequence over "
                     f"the sp axis, and {self.name}'s state-space mixer hands "
                     f"its state along the whole row")
-            h = x + SSMixer(spec.mixer, cfg.dtype, cfg.param_dtype,
-                            cfg.matmul_dtype or "", cfg.norm_eps,
-                            name="ssm")(norm("ssm_norm")(x))
+            h = x + branch("tony.ssm.out_proj", SSMixer(
+                spec.mixer, cfg.dtype, cfg.param_dtype,
+                cfg.matmul_dtype or "", cfg.norm_eps,
+                name="ssm")(norm("ssm_norm")(x)))
         out = h
         if spec.feed_forward:
             m = norm("mlp_norm")(h)
             if spec.experts is None:
-                out = h + MLP(cfg, name="mlp")(m)
+                out = h + branch("tony.mlp", MLP(cfg, name="mlp")(m))
             else:
                 # The router may read what attention reads (the normed
                 # block input); the experts read the normed state after the
                 # mixer.
                 before = spec.experts.route_before_attention and n is not None
-                out = h + ExpertLayer(
+                out = h + branch("tony.moe.combine", ExpertLayer(
                     spec.experts, cfg.dtype, cfg.param_dtype,
-                    cfg.matmul_dtype or "", name="moe")(n if before else m, m)
+                    cfg.matmul_dtype or "", name="moe")(n if before else m,
+                                                        m))
         return nn.with_logical_constraint(out, ("batch", "seq", "embed"))
 
 
@@ -461,7 +497,10 @@ class Transformer(nn.Module):
                 nn.initializers.normal(0.02), ("vocab_table", "embed_table")),
             (cfg.vocab_size, cfg.dim), cfg.param_dtype)
         with jax.named_scope("tony.embed"):
-            x = emb[tokens].astype(cfg.dtype)
+            x = emb[tokens]
+            if cfg.embedding_multiplier != 1.0:
+                x = x * cfg.embedding_multiplier
+            x = x.astype(cfg.dtype)
             x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         block = Block
         if cfg.remat:
@@ -494,7 +533,10 @@ class Transformer(nn.Module):
                     kernel_init=nn.with_logical_partitioning(
                         nn.initializers.lecun_normal(), ("embed", "vocab")))(
                             x.astype(head_dtype))
-            return logits.astype(jnp.float32)
+            logits = logits.astype(jnp.float32)
+            if cfg.logits_scaling != 1.0:
+                logits = logits / cfg.logits_scaling
+            return logits
 
 
 def layer_counters(intermediates) -> dict:
@@ -503,9 +545,12 @@ def layer_counters(intermediates) -> dict:
     over the layers that sowed them of ``attn_gate_mean`` (the per-head
     output gates, over heads and tokens), ``ssm_dt_mean`` and
     ``ssm_decay_mean`` (a state-space mixer's steps Δ and decays
-    ``exp(Δ·A)``, over heads and tokens). {} where nothing was sown."""
+    ``exp(Δ·A)``, over heads and tokens) and
+    ``ssm_head_rms_max_over_median`` (of a mixer's heads' scan outputs, the
+    largest RMS over the median). {} where nothing was sown."""
     out = moe_counters(intermediates)
-    for name in ("attn_gate_mean", "ssm_dt_mean", "ssm_decay_mean"):
+    for name in ("attn_gate_mean", "ssm_dt_mean", "ssm_decay_mean",
+                 "ssm_head_rms_max_over_median"):
         sown = [value for path, value in
                 jax.tree_util.tree_leaves_with_path(intermediates)
                 if any(getattr(k, "key", None) == name for k in path)]
@@ -539,7 +584,8 @@ def chunked_causal_lm_loss(hidden: jax.Array, head_kernel: jax.Array,
                            tokens: jax.Array, chunk_size: int = 4096,
                            mask: Optional[jax.Array] = None,
                            head_dtype: Optional[jnp.dtype] = None,
-                           seq_axis_name: str = "sp") -> jax.Array:
+                           seq_axis_name: str = "sp",
+                           logits_scaling: float = 1.0) -> jax.Array:
     """Next-token cross entropy without ever materializing [B, S, vocab].
 
     The long-context memory wall is not attention (flash streams it) but
@@ -558,7 +604,8 @@ def chunked_causal_lm_loss(hidden: jax.Array, head_kernel: jax.Array,
     ``tie_embeddings=True`` pass ``emb.T`` as the kernel; note the tied
     full path additionally accumulates in f32
     (``preferred_element_type``), so equality there is to bf16-matmul
-    tolerance, not bitwise.
+    tolerance, not bitwise. ``logits_scaling`` divides each chunk's float32
+    logits, as ``TransformerConfig.logits_scaling`` does on the full path.
 
     Not sequence-parallel: under a sequence shard_map the per-shard
     sequence shift would misalign targets at shard boundaries, so this
@@ -610,6 +657,8 @@ def chunked_causal_lm_loss(hidden: jax.Array, head_kernel: jax.Array,
     def chunk_stats(xc, tc, mc):
         logits = (xc.astype(hd)
                   @ head_kernel.astype(hd)).astype(jnp.float32)
+        if logits_scaling != 1.0:
+            logits = logits / logits_scaling
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
         picked = jnp.take_along_axis(
             logits, tc[..., None], axis=-1)[..., 0]

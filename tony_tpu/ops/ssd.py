@@ -18,25 +18,31 @@ matmuls: with ``cum_i = Σ_{k≤i} log a_k`` inside a chunk,
 
 Forward and backward are one Mosaic call each, named ``ssd_fwd`` and
 ``ssd_bwd``, under one ``jax.custom_vjp``. Their grid is ``(batch, group,
-chunk)``, the chunk axis last and sequential: the state (forward) or its
-cotangent (backward, chunks in reverse) lives in VMEM scratch from chunk to
-chunk, and nothing of shape ``[chunks, Q, Q]`` ever leaves VMEM. The forward
-also writes each chunk's entering state (float32 ``[B, chunks, G, N,
-heads·P]``); the backward reads that result as it is, no instruction
+head block, chunk)``, the chunk axis last and sequential: the state (forward)
+or its cotangent (backward, chunks in reverse) lives in VMEM scratch from
+chunk to chunk, and nothing of shape ``[chunks, Q, Q]`` ever leaves VMEM. A
+head block is at most ``BLOCK_HEADS`` of a group's heads, so a group of 64
+heads is eight blocks whose grid steps are each the size of a group of 8; a
+group's own products are then taken once a block, and the backward writes
+each block's share of ``dB`` and ``dC`` (float32) for one sum after the
+call. Where a group is one block the backward writes them as they are. The
+forward also writes each chunk's entering state (float32 ``[B, chunks, G,
+N, heads·P]``); the backward reads that result as it is, no instruction
 between the two calls. (A training step's forward pass is the differentiated
 one, under a block's remat too, so a forward without that output would serve
 evaluation alone.)
 
 Heads narrower than the 128 lanes are worked on in packs of ``128 // P``
 side by side: every load, store and matmul operand is a whole number of
-lane tiles. A grid step has all the heads of one group, and a product whose
-operand the heads share is taken once, a head's scaling ``s_h`` (one number
-a token) moved to the other operand or to the float32 result, on that head's
-lanes: ``(B ∘ s_h) dS_h = s_h ∘ (B dS_h)``, ``(B ∘ s_h)ᵀ X_h = Bᵀ (s_h ∘
+lane tiles. A grid step has the heads of one head block, and a product
+whose operand the heads share is taken once, a head's scaling ``s_h`` (one
+number a token) moved to the other operand or to the float32 result, on that
+head's lanes: ``(B ∘ s_h) dS_h = s_h ∘ (B dS_h)``, ``(B ∘ s_h)ᵀ X_h = Bᵀ (s_h ∘
 X_h)``, ``Σ_h (dY_h ∘ s_h) S_hᵀ = (dY ∘ s) Sᵀ``. So, in tile products of
 ``[128, 128]`` at 8 heads of 64 (four packs):
 
-- a group: ``C Bᵀ``; in the backward its cotangent into ``dB`` and ``dC``;
+- a head block: ``C Bᵀ``; in the backward its cotangent into ``dB`` and
+  ``dC``;
 - a pack: ``C S_in`` and ``Bᵀ (w ∘ X)``, the state a chunk leaves; in the
   backward also ``B dS``, ``(dY ∘ exp(cum)) S_inᵀ`` into ``dC``, ``(X ∘ w)
   dSᵀ`` into ``dB``, and ``Cᵀ (dY ∘ exp(cum))`` into the state's cotangent;
@@ -48,7 +54,7 @@ X_h)``, ``Σ_h (dY_h ∘ s_h) S_hᵀ = (dY ∘ s) Sᵀ``. So, in tile products o
 That is 17 products a grid step forward and 39 backward (21 and 51 with a
 product a head). The heads' scalars (``cum``, ``Δ``, ``exp(cum)``,
 ``exp(cum_Q − cum)``, ``w`` and their cotangents) are worked on as blocks for
-the group's heads at once, ``[heads, Q]`` where a token is a lane and,
+the block's heads at once, ``[heads, Q]`` where a token is a lane and,
 transposed once a grid step, ``[Q, heads]`` where a token is a sublane.
 
 ``A`` must be negative and ``Δ`` positive (decays in (0, 1]): the mixer's
@@ -77,6 +83,7 @@ from tony_tpu.parallel.mesh import BATCH_AXES
 LANES = 128
 MASKED = -1e30      # log-decay of a pair the causal mask drops
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+BLOCK_HEADS = 8     # the most heads a grid step holds
 
 
 def _pack(heads: int, p: int) -> int:
@@ -84,6 +91,12 @@ def _pack(heads: int, p: int) -> int:
     and fit the 128 lanes together."""
     return max(n for n in range(1, heads + 1)
                if heads % n == 0 and (n == 1 or n * p <= LANES))
+
+
+def _block_heads(per: int) -> int:
+    """Heads of a grid step: the most, up to ``BLOCK_HEADS``, that divide a
+    group's ``per`` heads."""
+    return max(n for n in range(1, min(per, BLOCK_HEADS) + 1) if per % n == 0)
 
 
 def _f32(x):
@@ -99,7 +112,7 @@ def _dot(a, b, dims, prec):
 def _group_chunk(x_ref, b_ref, c_ref, cum_ref, dt_ref, pack: int, p: int):
     """What a grid step's heads share. The group's B and C ``[Q, N]``, ``C
     Bᵀ [Q, Q]``, the causal mask, a pack's lanes, the dtype and precision the
-    products run in; and the heads' scalars as blocks, for all the group's
+    products run in; and the heads' scalars as blocks, for all the block's
     heads at once: ``cum`` and ``Δ`` a head a row ``[heads, Q]`` and a head
     a column ``[Q, heads]``, and of the columns ``exp(cum)`` (what the
     entering state's share of token i has decayed to), ``exp(cum_Q − cum)``
@@ -156,7 +169,7 @@ def _head_decay(cum, cum_t, h: int, causal):
 
 def _fwd_kernel(x_ref, b_ref, c_ref, cum_ref, dt_ref, d_ref, y_ref, s_in_ref,
                 state, *, heads: int, p: int, pack: int):
-    @pl.when(pl.program_id(2) == 0)
+    @pl.when(pl.program_id(3) == 0)
     def _row_start():
         state[...] = jnp.zeros_like(state)
 
@@ -190,7 +203,7 @@ def _fwd_kernel(x_ref, b_ref, c_ref, cum_ref, dt_ref, d_ref, y_ref, s_in_ref,
 def _bwd_kernel(x_ref, b_ref, c_ref, cum_ref, dt_ref, d_ref, s_in_ref, dy_ref,
                 dx_ref, db_ref, dc_ref, dcum_ref, ddt_ref, dd_ref, dstate, *,
                 heads: int, p: int, pack: int):
-    @pl.when(pl.program_id(2) == 0)     # a row's last chunk: nothing follows
+    @pl.when(pl.program_id(3) == 0)     # a row's last chunk: nothing follows
     def _row_end():
         dstate[...] = jnp.zeros_like(dstate)
         dd_ref[...] = jnp.zeros_like(dd_ref)
@@ -272,42 +285,50 @@ def _bwd_kernel(x_ref, b_ref, c_ref, cum_ref, dt_ref, d_ref, s_in_ref, dy_ref,
 
 def _call(kernel, name: str, x, bm, cum, reverse: bool, operands: str,
           results: str):
-    """The ``pallas_call`` of ``kernel`` on the grid ``(batch, group,
-    chunk)``, chunks in ``reverse`` for the backward; ``operands`` and
-    ``results`` name each one's block: ``x`` the tokens' columns of a group
-    ``[1, Q, heads·P]``, ``b`` its B or C ``[1, Q, N]``, ``h`` its heads'
+    """The ``pallas_call`` of ``kernel`` on the grid ``(batch, group, head
+    block, chunk)``, chunks in ``reverse`` for the backward; ``operands`` and
+    ``results`` name each one's block: ``x`` the tokens' columns of a head
+    block ``[1, Q, heads·P]``, ``b`` its group's B or C ``[1, Q, N]``, ``p``
+    the head block's share of B's or C's cotangent (float32), ``h`` its heads'
     decays ``[1, 1, 1, heads, Q]``, ``d`` D's columns, ``s`` a chunk's
     entering state."""
     batch = x.shape[0]
-    _, chunks, groups, heads, q = cum.shape
-    n, hp = bm.shape[2] // groups, x.shape[2] // groups
+    _, chunks, groups, per, q = cum.shape
+    n, p = bm.shape[2] // groups, x.shape[2] // (groups * per)
+    heads = _block_heads(per)
+    blocks, width = per // heads, heads * p
 
     def at(c):
         return chunks - 1 - c if reverse else c
 
     spec = {
-        "x": pl.BlockSpec((1, q, hp), lambda b, g, c: (b, at(c), g)),
-        "b": pl.BlockSpec((1, q, n), lambda b, g, c: (b, at(c), g)),
+        "x": pl.BlockSpec((1, q, width),
+                          lambda b, g, j, c: (b, at(c), g * blocks + j)),
+        "b": pl.BlockSpec((1, q, n), lambda b, g, j, c: (b, at(c), g)),
+        "p": pl.BlockSpec((1, q, n),
+                          lambda b, g, j, c: (b, at(c), g * blocks + j)),
         "h": pl.BlockSpec((1, 1, 1, heads, q),
-                          lambda b, g, c: (b, at(c), g, 0, 0)),
-        "d": pl.BlockSpec((1, 1, hp), lambda b, g, c: (b, 0, g)),
-        "s": pl.BlockSpec((1, 1, 1, n, hp),
-                          lambda b, g, c: (b, at(c), g, 0, 0)),
+                          lambda b, g, j, c: (b, at(c), g, j, 0)),
+        "d": pl.BlockSpec((1, 1, width),
+                          lambda b, g, j, c: (b, 0, g * blocks + j)),
+        "s": pl.BlockSpec((1, 1, 1, n, width),
+                          lambda b, g, j, c: (b, at(c), g, 0, j)),
     }
     shape = {"x": (x.shape, x.dtype), "b": (bm.shape, bm.dtype),
+             "p": ((batch, x.shape[1], groups * blocks * n), jnp.float32),
              "h": (cum.shape, jnp.float32),
              "d": ((batch, 1, x.shape[2]), jnp.float32),
-             "s": ((batch, chunks, groups, n, hp), jnp.float32)}
-    p = hp // heads
+             "s": ((batch, chunks, groups, n, per * p), jnp.float32)}
     return pl.pallas_call(
         functools.partial(kernel, heads=heads, p=p, pack=_pack(heads, p)),
-        grid=(batch, groups, chunks),
+        grid=(batch, groups, blocks, chunks),
         in_specs=[spec[o] for o in operands],
         out_specs=[spec[r] for r in results],
         out_shape=[jax.ShapeDtypeStruct(*shape[r]) for r in results],
-        scratch_shapes=[pltpu.VMEM((n, hp), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n, width), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=_interpret(), name=name)
 
@@ -321,9 +342,19 @@ def _fwd_call(x, bm, cm, cum, dt, d):
 
 
 def _bwd_call(x, bm, cm, cum, dt, d, s_in, dy):
-    """The cotangents of ``_fwd_call``'s six operands from ``dy``."""
-    return _call(_bwd_kernel, "ssd_bwd", x, bm, cum, True, "xbbhhdsx",
-                 "xbbhhd")(x, bm, cm, cum, dt, d, s_in, dy)
+    """The cotangents of ``_fwd_call``'s six operands from ``dy``: a group of
+    several head blocks has its blocks' shares of ``dB`` and ``dC`` summed."""
+    b, s, gn = bm.shape
+    groups, per = cum.shape[2:4]
+    blocks = per // _block_heads(per)
+    shared = "b" if blocks == 1 else "p"
+    dx, db, dc, dcum, ddt, dd = _call(
+        _bwd_kernel, "ssd_bwd", x, bm, cum, True, "xbbhhdsx",
+        "x" + 2 * shared + "hhd")(x, bm, cm, cum, dt, d, s_in, dy)
+    if blocks > 1:
+        db, dc = (t.reshape(b, s, groups, blocks, gn // groups).sum(
+            axis=3).reshape(b, s, gn).astype(bm.dtype) for t in (db, dc))
+    return dx, db, dc, dcum, ddt, dd
 
 
 # Every operand's leading dim is the batch: under a bound mesh each device
