@@ -343,12 +343,97 @@ def test_the_token_side_gathers_a_slot_for_its_tokens(v5e_devices):
     assert gathers.count(str(moe.TOKEN_SEGMENT_ROWS)) == 2, gathers
 
 
+def _results(line):
+    """[(dtype, dims, minor-to-major layout)] of an instruction line's
+    result, every element of a tuple."""
+    from tony_tpu.profiling import scopes
+
+    at = scopes._DEFINITION.match(line).end()
+    end = scopes._closing(line, at) + 1 if line[at] == "(" \
+        else line.index(" ", at)
+    return [(dtype, [int(n) for n in dims.split(",") if n],
+             [int(n) for n in layout.split(",") if n])
+            for dtype, dims, layout in re.findall(
+                r"(\w+)\[([\d,]*)\]\{([\d,]*)", line[at:end])]
+
+
+def test_rope_turns_whole_rows_of_q_and_k(v5e_devices):
+    """One attention block at Mistral-7B's head widths (4,096 wide, 32 q and
+    8 kv heads of 128, RoPE over the whole head) over 2 × 1,024 tokens,
+    forward, the remat's recompute and backward as a cell's step runs them,
+    compiled for one v5e: no ``tony.attn.rope`` instruction over the tokens
+    has a minor dimension of half a head, none is an f32 copy or a
+    concatenate, and no fusion outside every layer's scope is left around q
+    or k. The half-split rotation gave q and k sequence-minor f32 copies,
+    two halves of 64 columns, a concatenate, and ``subtract_convert``
+    fusions cloned without an ``op_name``."""
+    import flax.linen as nn
+
+    from tony_tpu.models.transformer import (Attention, LayerSpec,
+                                             TransformerConfig,
+                                             remat_policy_of)
+    from tony_tpu.parallel.sharding import DEFAULT_RULES
+    from tony_tpu.profiling import scopes
+
+    b, s, dim, h, hk, d = 2, 1024, 4096, 32, 8, 128
+    cfg = TransformerConfig(dim=dim, n_heads=h, n_kv_heads=hk, head_dim=d,
+                            n_layers=1, rope_theta=1e6, max_seq_len=s,
+                            layers=(LayerSpec(),))
+    block = nn.remat(Attention, prevent_cse=True,
+                     policy=remat_policy_of(cfg))(cfg, cfg.layer(0))
+    mesh = build_mesh(MeshSpec(), devices=v5e_devices[:1])
+    with nn.logical_axis_rules(list(DEFAULT_RULES)):
+        shapes = jax.eval_shape(lambda: nn.meta.unbox(block.init(
+            jax.random.key(0), jnp.zeros((1, 8, dim), jnp.bfloat16),
+            jnp.zeros((1, 8), jnp.int32)))["params"])
+    params = jax.tree.map(lambda a: _abstract(a.shape, a.dtype, mesh, P()),
+                          shapes)
+
+    def loss(p, x, positions):
+        y = block.apply({"params": p}, x, positions)
+        return jnp.square(y.astype(jnp.float32)).mean()
+
+    def grads(p, x, positions):
+        with jax.named_scope("tony.loss_and_grad"):
+            return jax.grad(loss, argnums=(0, 1))(p, x, positions)
+
+    with jax.set_mesh(mesh), nn.logical_axis_rules(list(DEFAULT_RULES)):
+        hlo = jax.jit(grads).lower(
+            params, _abstract((b, s, dim), jnp.bfloat16, mesh, P()),
+            _abstract((b, s), jnp.int32, mesh, P())).compile().as_text()
+    record = scopes.step_scopes(hlo)
+    rope = {name for key, names in record["scopes"].items()
+            if key.endswith("/tony.attn.rope") for name in names}
+    unscoped = set(record["scopes"].get("other/-", ()))
+    assert {key.split("/")[0] for key in record["scopes"]
+            if key.endswith("/tony.attn.rope")} == {
+                "forward", "recompute", "backward"}, record["scopes"].keys()
+    seen = 0
+    for line in hlo.splitlines():
+        found = scopes._instruction(line)
+        if not found or found[0] not in rope | unscoped:
+            continue
+        name, opcode = found[:2]
+        over_tokens = [(dtype, dims[layout[0]]) for dtype, dims, layout
+                       in _results(line) if s in dims and layout]
+        if name in unscoped:
+            assert not (opcode == "fusion" and over_tokens), line
+            continue
+        seen += bool(over_tokens)
+        assert all(minor != d // 2 for _, minor in over_tokens), line
+        assert not (opcode == "copy" and any(
+            dtype == "f32" for dtype, _ in over_tokens)), line
+        assert opcode != "concatenate" and not (
+            over_tokens and re.search(r"pad|concatenate", name)), line
+    assert seen, "no RoPE instruction over the tokens"
+
+
 # The cell ``lagS.seq8k``'s whole step as the benchmark builds it: the bytes
 # XLA:TPU gives the compiled program on one v5e (arguments + outputs −
 # aliased + temporaries), which the chip's `step_hbm_gb_per_chip.lagS` reads
 # to the digit. A change of the program's schedule moves it: say so in
 # PERF.md and put the new number here.
-LAGS_STEP_BYTES = 12_752_092_160
+LAGS_STEP_BYTES = 13_021_602_816
 
 
 def _cell_step(v5e_devices, kind, config, traffic="seq8k-2rows"):

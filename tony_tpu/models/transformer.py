@@ -43,6 +43,7 @@ sown into ``intermediates`` and reduced by ``layer_counters``:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Optional, Tuple, Union
 
@@ -269,23 +270,59 @@ def _sp_offset() -> jax.Array:
     return jax.lax.axis_index("sp")
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _turn(x: jax.Array, cos: jax.Array, sin: jax.Array,
+          half: int) -> jax.Array:
+    """``x·cos + (x R)·sin`` in f32, rounded once to x's dtype: ``R`` the
+    constant 0/1 matrix that swaps the two halves of x's leading ``2·half``
+    columns and zeroes the others, so ``x R`` is exact (each output is one
+    input times 1)."""
+    d = x.shape[-1]
+    swap = np.zeros((d, d), np.float32)
+    pairs = np.arange(half)
+    swap[pairs + half, pairs] = swap[pairs, pairs + half] = 1.0
+    swapped = jnp.einsum("...d,de->...e", x, jnp.asarray(swap, x.dtype),
+                         preferred_element_type=jnp.float32,
+                         precision=jax.lax.Precision.HIGHEST)
+    return (x.astype(jnp.float32) * cos + swapped * sin).astype(x.dtype)
+
+
+def _turn_fwd(x, cos, sin, half):
+    return _turn(x, cos, sin, half), (cos, sin)
+
+
+def _turn_bwd(half, tables, dy):
+    # The transpose turns back by the same angles: (dy·sin) Rᵀ = (dy R)·(−sin)
+    # for R = Rᵀ and sin's halves of opposite signs. So the backward is the
+    # forward's one pass, rounded once, where autodiff would round dy·cos
+    # and (dy·sin) Rᵀ to x's dtype apart and then add them.
+    cos, sin = tables
+    return _turn(dy, cos, -sin, half), None, None
+
+
+_turn.defvjp(_turn_fwd, _turn_bwd)
+
+
 def _rope(x: jax.Array, positions: jax.Array, rope: RopeSpec) -> jax.Array:
-    """Rotary position embedding on [B, S, H, D]; f32 trig, cast back. The
-    half-split rotation runs within the rotated leading columns; the others
-    pass through."""
+    """Rotary position embedding on [B, S, H, D]; f32 trig and arithmetic,
+    cast back. Each head turns as one row of D columns, never as two
+    halves: a tensor half a head wide fills half a lane tile, and the
+    compiler then lays q and k out sequence-minor, with f32 relayout copies
+    on both sides of the rotation. So ``out = x·cos' + (x R)·sin'``
+    (``_turn``), with full-width tables over the columns, ``cos' = [cos,
+    cos, 1 …]`` and ``sin' = [−sin, sin, 0 …]`` (YaRN's factor on the
+    rotated columns alone; [B, S, 1, D], broadcast over heads): the
+    columns past the rotated ones pass through."""
     d = x.shape[-1]
     freqs, factor = rope.table(d)
-    rot = 2 * freqs.shape[0]
-    angles = positions[:, :, None, None].astype(jnp.float32) \
-        * freqs[None, None, None, :]                    # [B, S, 1, rot/2]
-    cos, sin = jnp.cos(angles), jnp.sin(angles)
-    if factor != 1.0:
-        cos, sin = cos * factor, sin * factor
-    xf = x.astype(jnp.float32)
-    x1, x2 = jnp.split(xf if rot == d else xf[..., :rot], 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos]
-                          + ([] if rot == d else [xf[..., rot:]]), axis=-1)
-    return out.astype(x.dtype)
+    half = freqs.shape[0]
+    kept = d - 2 * half
+    freqs = jnp.concatenate([freqs, freqs, jnp.zeros(kept, jnp.float32)])
+    on_cos = np.array([factor] * 2 * half + [1.0] * kept, np.float32)
+    on_sin = np.array([-factor] * half + [factor] * half + [0.0] * kept,
+                      np.float32)
+    angles = positions[:, :, None, None].astype(jnp.float32) * freqs
+    return _turn(x, jnp.cos(angles) * on_cos, jnp.sin(angles) * on_sin, half)
 
 
 class RMSNorm(nn.Module):
