@@ -118,6 +118,67 @@ def test_flash_kernels_carry_their_names(flash_grad_hlo, kernel_operands):
         assert name.startswith(kernel), names
 
 
+def _mosaic_kernels(lowered_text):
+    """Each Mosaic call's kernel in a lowered module, as MLIR text without
+    source locations (a kernel carries its source's file and lines, which
+    any edit above it moves), in the module's order."""
+    import base64
+    import json
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    kernels = []
+    for config in re.findall(r'tpu_custom_call.*?backend_config = "([^"]*)"',
+                             lowered_text):
+        body = json.loads(config.replace("\\22", '"'))[
+            "custom_call_config"]["body"]
+        with mlir.make_ir_context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            kernels.append(ir.Module.parse(base64.b64decode(body)).operation
+                           .get_asm(enable_debug_info=False))
+    return kernels
+
+
+# sha256 (first 16 hex digits) of the flash fwd, dq and dkv kernels where q,
+# k and v share one width, lowered for one v5e, before v took a width of its
+# own: the cells' three kinds (heads of 128 at 2 q heads a kv head, heads of
+# 64 at 4, a window of 512).
+EQUAL_WIDTH_KERNELS = {
+    (2, 128, None): ("c21fa71365848af5", "9c51f52ca05e66bb",
+                     "5f0923ac4514c10e"),
+    (1, 64, None): ("1b17efc79c3f677e", "772bc073480343c0",
+                    "5cfeb4a00b5bc5d2"),
+    (2, 128, 512): ("326333d136e50b13", "af9e3d9952d4b90e",
+                    "09981f96b0db628f"),
+}
+
+
+@pytest.mark.parametrize("hk, d, window", sorted(
+    EQUAL_WIDTH_KERNELS, key=str))
+def test_flash_at_equal_widths_lowers_to_the_same_kernels(v5e_devices, hk,
+                                                          d, window):
+    """A v of its own width leaves every kernel at q, k and v of one width
+    as it was, text for text."""
+    import hashlib
+
+    mesh = build_mesh(MeshSpec(), devices=v5e_devices[:1])
+    rows = P(BATCH_AXES)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window).astype(
+            jnp.float32).sum()
+
+    args = [_abstract((1, 2048, n, d), jnp.bfloat16, mesh, rows)
+            for n in (4, hk, hk)]
+    with jax.set_mesh(mesh):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            *args).as_text()
+    got = tuple(hashlib.sha256(k.encode()).hexdigest()[:16]
+                for k in _mosaic_kernels(text))
+    assert got == EQUAL_WIDTH_KERNELS[(hk, d, window)], got
+
+
 GN_SHAPE = (8, 56, 64)       # ResNet-50 stage-1 activation, batch > 1
 
 
@@ -494,7 +555,7 @@ def _cell_step(v5e_devices, kind, config, traffic="seq8k-2rows"):
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
              - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
     return total, collections.Counter(re.findall(
-        r"%((?:flash|moe|ssd)_[a-z_]+)[.\d]* = ", compiled.as_text()))
+        r"%((?:flash|moe|ssd|kda)_[a-z_]+)[.\d]* = ", compiled.as_text()))
 
 
 @pytest.mark.timeout_s(900)
@@ -593,3 +654,45 @@ def test_the_granite_cell_s_step_compiles_for_one_v5e_and_fits(v5e_devices):
     assert total < 16e9 and total > 0.25 * 16e9
     assert names == {"ssd_fwd": 18, "ssd_bwd": 9, "flash_fwd": 1,
                      "flash_dq": 1, "flash_dkv": 1}
+
+
+# ``m7b.seq2k``'s step, as ``step_hbm_gb_per_chip`` reads it: the same before
+# and after v took a width of its own in the flash kernels.
+M7B_STEP_BYTES = 12_528_216_576
+
+
+@pytest.mark.timeout_s(900)
+def test_the_mistral_cell_s_step_compiles_for_one_v5e_and_fits(v5e_devices):
+    """Mistral-7B-v0.3's two layers at published widths through
+    ``jit_train_step`` at 8 × 2,048 tokens: two flash layers at heads of 128
+    (32 q over 8 kv heads), the step's bytes unchanged by latent attention's
+    widths."""
+    total, names = _cell_step(v5e_devices, "mistral", "mistral-7b-v0.3",
+                              "seq2k")
+    assert total == M7B_STEP_BYTES, total
+    assert names == {"flash_fwd": 2, "flash_dq": 2, "flash_dkv": 2}
+
+
+# ``kimiL.seq32k``'s step, as ``step_hbm_gb_per_chip.kimiL`` reads it: its
+# peak in a KDA layer's backward.
+KIMIL_STEP_BYTES = 15_308_228_608
+
+
+@pytest.mark.timeout_s(900)
+def test_the_kimi_linear_cell_s_step_compiles_for_one_v5e_and_fits(
+        v5e_devices):
+    """Kimi-Linear-48B-A3B's share at published widths (602,433,408
+    parameters: 9.64 GB of state) through ``jit_train_step`` at 1 × 32,768
+    tokens: the step passes XLA:TPU and Mosaic (the KDA kernels over 32
+    heads of 128 in chunks of 64; flash at q and k 192 and v 128; grouped
+    matmuls over 8 of 256 experts), fits the chip under 15.5 GB, and holds a
+    KDA layer's ``kda_fwd`` twice (the forward and the block's recompute)
+    and ``kda_bwd`` once, four times; one flash layer; per sparse layer 9
+    ``moe_gmm`` and 3 ``moe_tgmm`` in the eight chunks' loops."""
+    total, names = _cell_step(v5e_devices, "kimi_linear",
+                              "kimi-linear-48b-a3b", "seq32k")
+    assert total == KIMIL_STEP_BYTES, total
+    assert total < 15.5e9 and total > 0.25 * 16e9
+    assert names == {"kda_fwd": 8, "kda_bwd": 4, "flash_fwd": 1,
+                     "flash_dq": 1, "flash_dkv": 1, "moe_gmm": 36,
+                     "moe_tgmm": 12}

@@ -3,7 +3,7 @@
 ``benchmarks/cells/run.py`` is the one yardstick (``BENCHMARK.json``); its
 numbers come only from a TPU. What tier-1 can hold is the plumbing a
 ``tony_tpu/`` change could break before the chip is asked: that each cell of
-the rehearsal table, and the tiny cells of the three newest architectures'
+the rehearsal table, and the tiny cells of the four newest architectures'
 own tables, goes through submit → coordinator → executor → the train
 script and comes back ``correct`` against the plain reference, without a
 metric, and that the runner refuses to give a result where there is no TPU.
@@ -26,6 +26,7 @@ TABLE = os.path.join(FIXTURES, "rehearsal", "table.json")
 LAGUNA_TABLE = os.path.join(FIXTURES, "rehearsal_laguna", "table.json")
 NEMOTRON_TABLE = os.path.join(FIXTURES, "rehearsal_nemotron_h", "table.json")
 GRANITE_TABLE = os.path.join(FIXTURES, "rehearsal_granite", "table.json")
+KIMI_TABLE = os.path.join(FIXTURES, "rehearsal_kimi_linear", "table.json")
 SEED = 3000000391       # no other caller's: the output directory is its own
 
 
@@ -44,7 +45,7 @@ def _run(workload, *args):
 @pytest.mark.parametrize("workload, table", [
     ("tiny.b4", TABLE), ("tiny.b4-4dev", TABLE), ("tiny_tied.b4", TABLE),
     ("tiny_lag.b4", LAGUNA_TABLE), ("tiny_nem.b4", NEMOTRON_TABLE),
-    ("tiny_g4h.b4", GRANITE_TABLE)])
+    ("tiny_g4h.b4", GRANITE_TABLE), ("tiny_kimi.b4", KIMI_TABLE)])
 def test_rehearsal_cell_is_correct_and_reports_no_metric(workload, table):
     r, out_dir = _run(workload, "--seconds", "2", "--rehearsal",
                       "--table", table)

@@ -432,7 +432,7 @@ def test_every_scope_reader_answers_to_its_table_entry(readers):
     with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as f:
         entries = {e["name"]: e for e in json.load(f)["per_layer"]}
     every = ["m7b.seq2k", "m7b.seq32k", "st21b.seq16k", "lagS.seq8k",
-             "nem30b.seq8k"]
+             "nem30b.seq8k", "kimiL.seq32k"]
     for name in HAND:
         entry, reader = entries[name], readers[name]
         assert (reader.UNIT, reader.SOURCE, reader.LAYER, reader.MOVES) == (

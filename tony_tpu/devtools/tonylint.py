@@ -118,7 +118,8 @@ _TRACE_NAME_RE = re.compile(
     r"|embed|norm|mlp|loss_head"
     r"|moe\.(route|dispatch|experts|combine|shared)"
     r"|attn\.(proj|rope|core|gate)"
-    r"|ssm\.(in_proj|conv|scan|gate_norm|out_proj))$")
+    r"|ssm\.(in_proj|conv|scan|gate_norm|out_proj)"
+    r"|kda\.(in_proj|conv|scan|out_norm|out_proj))$")
 #: dotted tokens whose last segment is one of these are file names
 #: ("job.tony.json", "tony.xml"), not config-key references
 _FILE_EXTS = ("xml", "json", "jsonl", "yaml", "yml", "md", "py", "log",

@@ -11,8 +11,9 @@ Design choices map straight onto TPU hardware:
 - static shapes and `remat`-friendly block structure (scan over layers is
   deliberately NOT used so pipeline stages can slice layers later);
 - one loop over layers that may differ: ``TransformerConfig.layers`` says
-  of each its mixer (attention, a state-space mixer, ``models/ssm.py``, or
-  none) and its feed-forward (the dense ``MLP``, sparse experts,
+  of each its mixer (attention, latent attention without RoPE (``MLA``), a
+  state-space mixer, ``models/ssm.py``, a linear-attention mixer,
+  ``models/kda.py``, or none) and its feed-forward (the dense ``MLP``, sparse experts,
   ``models/moe.py``, or none); of an attention mixer its mask (full causal
   or a window), its head counts, its RoPE (none, or a ``RopeSpec``: θ, the
   share of a head's columns rotated, a YaRN table) and whether its output
@@ -26,18 +27,21 @@ Design choices map straight onto TPU hardware:
 
 Spans (``jax.named_scope``, one where each layer's work happens, so that a
 device trace reads by layer: ``profiling/scopes.py``): ``tony.embed``,
-``tony.norm``, ``tony.attn.proj`` (q, k, v and the output projection),
-``tony.attn.rope``, ``tony.attn.core`` (the kernel call and the layouts
-around it), ``tony.attn.gate``, ``tony.mlp``, ``tony.loss_head`` (both
-losses, and the head of the full-logits path); the state-space mixer's are
-in ``models/ssm.py``, the experts' in ``models/moe.py``. A multiplier lives
+``tony.norm``, ``tony.attn.proj`` (q, k, v and the output projection;
+latent attention's ``W_q``, ``W_kv_a``, the latent's norm, ``W_kv_b``, the
+key's layout and ``W_o``), ``tony.attn.rope``, ``tony.attn.core`` (the kernel call and the layouts
+around it), ``tony.attn.gate``, ``tony.mlp``, ``tony.loss_head`` (both losses,
+and the head of the full-logits path); the state-space mixer's are in
+``models/ssm.py``, the KDA mixer's in ``models/kda.py``, the experts' in
+``models/moe.py``. A multiplier lives
 in the scope of what it scales: the embedding's under ``tony.embed``, a
 residual branch's under its part's (``tony.attn.proj``, ``tony.ssm.out_proj``,
 ``tony.mlp``, ``tony.moe.combine``), the logits' under ``tony.loss_head``,
 the softmax's scale inside the kernel under ``tony.attn.core``. Counters,
 sown into ``intermediates`` and reduced by ``layer_counters``:
 ``attn_gate_mean``, ``ssm_dt_mean``, ``ssm_decay_mean``,
-``ssm_head_rms_max_over_median``.
+``ssm_head_rms_max_over_median``, ``kda_decay_mean``, ``kda_log_decay_min``,
+``kda_beta_mean``.
 """
 
 from __future__ import annotations
@@ -52,6 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tony_tpu.models.kda import KDAMixer, KDASpec
 from tony_tpu.models.moe import ExpertLayer, ExpertSpec, moe_counters
 from tony_tpu.models.ssm import SSMixer, SSMSpec
 from tony_tpu.ops import quant
@@ -113,6 +118,20 @@ class RopeSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class MLASpec:
+    """Latent attention without RoPE (``mla_use_nope``): ``n_heads`` heads
+    whose q and k are ``qk_nope + qk_rope`` wide and whose v is ``v_dim``;
+    keys and values come from one latent of ``kv_rank`` columns a token
+    (RMS-normed), the last ``qk_rope`` columns of each key shared by every
+    head and turned by no rotary embedding."""
+    n_heads: int
+    qk_nope: int
+    qk_rope: int
+    v_dim: int
+    kv_rank: int
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerSpec:
     """One layer of the stack: a mixer, a feed-forward, or both, each
     ``x + part(norm(x))``. ``mixer``: ``"attention"``, an ``SSMSpec`` (the
@@ -123,7 +142,11 @@ class LayerSpec:
     ``RopeSpec`` the layer's own. ``n_heads``: the layer's q heads over
     ``cfg.n_kv_heads`` (None: the config's). ``gate``: attention's output is
     scaled, a head and token, by the sigmoid of a projection ``wg`` of the
-    layer's normed input. ``feed_forward``: False leaves the layer without
+    layer's normed input. ``mixer`` may also be a ``KDASpec`` (the
+    linear-attention mixer of ``models/kda.py``) or an ``MLASpec`` (latent
+    attention, ``MLA``: full causal, its own head count and widths, no
+    position embedding; ``window``, ``rope``, ``n_heads`` and ``gate`` are
+    the plain attention's). ``feed_forward``: False leaves the layer without
     one; otherwise ``experts`` is the sparse feed-forward in place of the
     dense ``MLP`` (None: dense, at ``cfg.mlp_dim``)."""
     window: Optional[int] = None
@@ -131,14 +154,14 @@ class LayerSpec:
     experts: Optional[ExpertSpec] = None
     n_heads: Optional[int] = None
     gate: bool = False
-    mixer: Union[str, SSMSpec, None] = "attention"
+    mixer: Union[str, SSMSpec, KDASpec, MLASpec, None] = "attention"
     feed_forward: bool = True
 
     def __post_init__(self):
         if not (self.mixer in (None, "attention")
-                or isinstance(self.mixer, SSMSpec)):
+                or isinstance(self.mixer, (SSMSpec, KDASpec, MLASpec))):
             raise ValueError(f"mixer {self.mixer!r} is neither 'attention', "
-                             f"an SSMSpec nor None")
+                             f"an SSMSpec, a KDASpec, an MLASpec nor None")
         if self.mixer is None and not self.feed_forward:
             raise ValueError("a layer without a mixer and without a "
                              "feed-forward has no part")
@@ -423,6 +446,61 @@ class Attention(nn.Module):
             return _dense(cfg, cfg.dim, ("heads", "embed"), "wo")(o)
 
 
+class MLA(nn.Module):
+    """Latent attention without RoPE, expanded: ``q = n W_q`` ``[S, H,
+    qk_nope + qk_rope]``; ``[c | k_r] = n W_kv_a``, ``c`` RMS-normed
+    (``kv_norm``); ``[k_n | v] = c W_kv_b`` a head; ``k = [k_n | k_r]``, k_r
+    the same for every head; causal softmax at ``(qk_nope + qk_rope)^−½``
+    (or ``cfg.attention_multiplier``) over values ``v_dim`` wide; ``out =
+    concat_h(a_h) W_o``. The projections, the latent's norm and the layouts
+    of k are under ``tony.attn.proj``, the kernel call under
+    ``tony.attn.core``."""
+    cfg: TransformerConfig
+    spec: MLASpec
+
+    @nn.compact
+    def __call__(self, x):
+        cfg, spec = self.cfg, self.spec
+        b, s, _ = x.shape
+        h, qk = spec.n_heads, spec.qk_nope + spec.qk_rope
+        with jax.named_scope("tony.attn.proj"):
+            q = _dense(cfg, h * qk, ("embed", "heads"), "wq")(x).reshape(
+                b, s, h, qk)
+            latent = _dense(cfg, spec.kv_rank + spec.qk_rope,
+                            ("embed", "rank"), "wkv_a")(x)
+            c, k_r = jnp.split(latent, (spec.kv_rank,), axis=-1)
+            c32 = c.astype(jnp.float32)
+            c = (c32 * jax.lax.rsqrt(jnp.mean(jnp.square(c32), axis=-1,
+                                              keepdims=True) + cfg.norm_eps)
+                 * self.param("kv_norm", nn.with_logical_partitioning(
+                     nn.initializers.ones, ("norm",)), (spec.kv_rank,),
+                     cfg.param_dtype)).astype(cfg.dtype)
+            kv = _dense(cfg, h * (spec.qk_nope + spec.v_dim),
+                        ("rank", "heads"), "wkv_b")(c).reshape(
+                            b, s, h, spec.qk_nope + spec.v_dim)
+            k_n, v = jnp.split(kv, (spec.qk_nope,), axis=-1)
+            k = jnp.concatenate([k_n, jnp.broadcast_to(
+                k_r[:, :, None, :], (b, s, h, spec.qk_rope))], axis=-1)
+        with jax.named_scope("tony.attn.core"):
+            q, k, v = (nn.with_logical_constraint(
+                t, ("batch", "seq", "heads", "kv")) for t in (q, k, v))
+            if cfg.attn_impl == "flash":
+                o = flash_attention(q, k, v, causal=True,
+                                    scale=cfg.attention_multiplier,
+                                    block_q=cfg.attn_block_q,
+                                    block_k=cfg.attn_block_k)
+            elif cfg.attn_impl == "xla":
+                o = reference_attention(q, k, v, causal=True,
+                                        scale=cfg.attention_multiplier)
+            else:
+                raise ValueError(f"attn_impl {cfg.attn_impl!r}: latent "
+                                 f"attention runs as flash or xla")
+            o = nn.with_logical_constraint(o, ("batch", "seq", "heads", "kv"))
+        with jax.named_scope("tony.attn.proj"):
+            return _dense(cfg, cfg.dim, ("heads", "embed"), "wo")(
+                o.reshape(b, s, h * spec.v_dim))
+
+
 class MLP(nn.Module):
     cfg: TransformerConfig
 
@@ -461,16 +539,29 @@ class Block(nn.Module):
             n = norm("attn_norm")(x)
             h = x + branch("tony.attn.proj",
                            Attention(cfg, spec, name="attn")(n, positions))
+        elif isinstance(spec.mixer, MLASpec):
+            n = norm("attn_norm")(x)
+            h = x + branch("tony.attn.proj",
+                           MLA(cfg, spec.mixer, name="mla")(n))
         elif spec.mixer is not None:
             if cfg.attn_impl in ("ring", "ulysses"):
+                kind = "state-space" if isinstance(spec.mixer, SSMSpec) \
+                    else "KDA"
                 raise ValueError(
                     f"attn_impl {cfg.attn_impl!r} splits the sequence over "
-                    f"the sp axis, and {self.name}'s state-space mixer hands "
-                    f"its state along the whole row")
-            h = x + branch("tony.ssm.out_proj", SSMixer(
-                spec.mixer, cfg.dtype, cfg.param_dtype,
-                cfg.matmul_dtype or "", cfg.norm_eps,
-                name="ssm")(norm("ssm_norm")(x)))
+                    f"the sp axis, and {self.name}'s {kind} mixer hands its "
+                    f"state along the whole row (a state-space or a KDA "
+                    f"mixer: neither is split over the sequence)")
+            if isinstance(spec.mixer, SSMSpec):
+                h = x + branch("tony.ssm.out_proj", SSMixer(
+                    spec.mixer, cfg.dtype, cfg.param_dtype,
+                    cfg.matmul_dtype or "", cfg.norm_eps,
+                    name="ssm")(norm("ssm_norm")(x)))
+            else:
+                h = x + branch("tony.kda.out_proj", KDAMixer(
+                    spec.mixer, cfg.dtype, cfg.param_dtype,
+                    cfg.matmul_dtype or "", cfg.norm_eps,
+                    name="kda")(norm("kda_norm")(x)))
         out = h
         if spec.feed_forward:
             m = norm("mlp_norm")(h)
@@ -582,17 +673,21 @@ def layer_counters(intermediates) -> dict:
     over the layers that sowed them of ``attn_gate_mean`` (the per-head
     output gates, over heads and tokens), ``ssm_dt_mean`` and
     ``ssm_decay_mean`` (a state-space mixer's steps Δ and decays
-    ``exp(Δ·A)``, over heads and tokens) and
+    ``exp(Δ·A)``, over heads and tokens),
     ``ssm_head_rms_max_over_median`` (of a mixer's heads' scan outputs, the
-    largest RMS over the median). {} where nothing was sown."""
+    largest RMS over the median), ``kda_decay_mean`` and ``kda_beta_mean``
+    (a KDA mixer's α over channels and tokens, its β); and the least over
+    the layers of ``kda_log_decay_min``. {} where nothing was sown."""
     out = moe_counters(intermediates)
     for name in ("attn_gate_mean", "ssm_dt_mean", "ssm_decay_mean",
-                 "ssm_head_rms_max_over_median"):
+                 "ssm_head_rms_max_over_median", "kda_decay_mean",
+                 "kda_log_decay_min", "kda_beta_mean"):
         sown = [value for path, value in
                 jax.tree_util.tree_leaves_with_path(intermediates)
                 if any(getattr(k, "key", None) == name for k in path)]
         if sown:
-            out[name] = jnp.mean(jnp.stack(sown))
+            reduce = jnp.min if name == "kda_log_decay_min" else jnp.mean
+            out[name] = reduce(jnp.stack(sown))
     return out
 
 

@@ -25,6 +25,9 @@ TPU-first design points (round-3 rework):
 - **Causal tiles are skipped in the DMA, not just the ALU.** Index maps
   clamp fully-masked tiles to the previous fetch, so Pallas's pipeline
   skips the copy (revisited blocks are not re-fetched).
+- **v has a width of its own.** q and k share ``D``; v, the output, ``dO``
+  and ``dv`` have ``Dv`` (latent attention's 192 and 128). The scale comes
+  from q's width. Where the two are equal every block is what it was.
 
 Public layout is ``[batch, seq, heads, head_dim]`` (the layout the models
 use); kernels run on ``[B, H, S, D]`` views. On non-TPU backends the kernels
@@ -235,7 +238,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
     def _compute():
         q = _load2d(q_ref, qi, block_q, seq_q)    # [block_q, d]
         k = _load2d(k_ref, kj, block_k, seq_k)    # [block_k, d]
-        v = _load2d(v_ref, kj, block_k, seq_k)    # [block_k, d]
+        v = _load2d(v_ref, kj, block_k, seq_k)    # [block_k, dv]
         # Scale folded into the [·, d] q block — 8–16× fewer elements than
         # a post-hoc pass over the [bq, bk] score tile.
         qs = q * jnp.asarray(scale, q.dtype)
@@ -256,8 +259,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         if fused_rowsum:
             # The row-sum rides the MXU: a ones column appended to v makes
             # the pv dot produce [o_partial | l_partial] in one accumulator
-            # — free while d+1 fits the 128-wide MXU/lane tile, deleting
-            # the VPU sum-reduce pass over the score tile. (At d >= 128
+            # — free while dv+1 fits the 128-wide MXU/lane tile, deleting
+            # the VPU sum-reduce pass over the score tile. (At dv >= 128
             # the extra column would pad to a second lane tile, doubling
             # accumulator VMEM — the plain reduce is used instead.)
             v1 = jnp.concatenate(
@@ -456,6 +459,7 @@ def _kernel_name(kind: str, window) -> str:
 def _fwd_call(q, k, v, *, scale, causal, block_q, block_k, out_dtype,
               window=None):
     b, h, sq, d = q.shape
+    dv = v.shape[3]             # o is v's width; q and k share d
     hk = k.shape[1]
     g = h // hk
     sk = k.shape[2]
@@ -472,7 +476,7 @@ def _fwd_call(q, k, v, *, scale, causal, block_q, block_k, out_dtype,
     kv_j = functools.partial(_kv_index, block_q=block_q, block_k=block_k,
                              causal=causal, window=window)
 
-    fused_rowsum = d < 128
+    fused_rowsum = dv < 128
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, num_k_blocks=nkb, seq_q=sq, seq_k=sk,
@@ -484,11 +488,12 @@ def _fwd_call(q, k, v, *, scale, causal, block_q, block_k, out_dtype,
             pl.BlockSpec((1, 1, block_q, d), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_k, d),
                          lambda b, h, i, j: (b, h // g, kv_j(i, j), 0)),
-            pl.BlockSpec((1, 1, block_k, d),
+            pl.BlockSpec((1, 1, block_k, dv),
                          lambda b, h, i, j: (b, h // g, kv_j(i, j), 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_q, dv),
+                         lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, STAT_SUB, block_q),
                          lambda b, h, i, j: (b, h, 0, i)),
         ],
@@ -496,16 +501,16 @@ def _fwd_call(q, k, v, *, scale, causal, block_q, block_k, out_dtype,
             # out_dtype=f32 hands the caller the kernel's own f32
             # accumulator unrounded — ring attention threads it through
             # hops so error stays flat in sp degree (ops/ring.py).
-            jax.ShapeDtypeStruct((b, h, sq, d), out_dtype or q.dtype),
+            jax.ShapeDtypeStruct((b, h, sq, dv), out_dtype or q.dtype),
             jax.ShapeDtypeStruct((b, h, STAT_SUB, sq), jnp.float32),
         ],
         scratch_shapes=(
             [pltpu.VMEM((block_q, 1), jnp.float32),
-             pltpu.VMEM((block_q, d + 1), jnp.float32)]
+             pltpu.VMEM((block_q, dv + 1), jnp.float32)]
             if fused_rowsum else
             [pltpu.VMEM((block_q, 1), jnp.float32),
              pltpu.VMEM((block_q, 1), jnp.float32),
-             pltpu.VMEM((block_q, d), jnp.float32)]),
+             pltpu.VMEM((block_q, dv), jnp.float32)]),
         interpret=_interpret(),
         name=_kernel_name("fwd", window),
     )(q, k, v)
@@ -545,6 +550,7 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, block_q, block_k,
 def _bwd_call(q, k, v, do, lse, delta, *, scale, causal, block_q, block_k,
               window=None):
     b, h, sq, d = q.shape
+    dv = v.shape[3]             # v, do and dv are v's width
     hk = k.shape[1]
     g = h // hk
     sk = k.shape[2]
@@ -566,9 +572,10 @@ def _bwd_call(q, k, v, do, lse, delta, *, scale, causal, block_q, block_k,
             pl.BlockSpec((1, 1, block_q, d), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_k, d),
                          lambda b, h, i, j: (b, h // g, kv_j(i, j), 0)),
-            pl.BlockSpec((1, 1, block_k, d),
+            pl.BlockSpec((1, 1, block_k, dv),
                          lambda b, h, i, j: (b, h // g, kv_j(i, j), 0)),
-            pl.BlockSpec((1, 1, block_q, d), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_q, dv),
+                         lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, STAT_SUB, block_q),
                          lambda b, h, i, j: (b, h, 0, i)),
             pl.BlockSpec((1, 1, STAT_SUB, block_q),
@@ -615,9 +622,9 @@ def _bwd_call(q, k, v, do, lse, delta, *, scale, causal, block_q, block_k,
                          lambda b, hk_, j, t: (b, qh(hk_, t), q_i(j, t), 0)),
             pl.BlockSpec((1, 1, block_k, d),
                          lambda b, hk_, j, t: (b, hk_, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
+            pl.BlockSpec((1, 1, block_k, dv),
                          lambda b, hk_, j, t: (b, hk_, j, 0)),
-            pl.BlockSpec((1, 1, block_q, d),
+            pl.BlockSpec((1, 1, block_q, dv),
                          lambda b, hk_, j, t: (b, qh(hk_, t), q_i(j, t), 0)),
             pl.BlockSpec((1, 1, STAT_SUB, block_q),
                          lambda b, hk_, j, t: (b, qh(hk_, t), 0, q_i(j, t))),
@@ -627,16 +634,16 @@ def _bwd_call(q, k, v, do, lse, delta, *, scale, causal, block_q, block_k,
         out_specs=[
             pl.BlockSpec((1, 1, block_k, d),
                          lambda b, hk_, j, t: (b, hk_, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
+            pl.BlockSpec((1, 1, block_k, dv),
                          lambda b, hk_, j, t: (b, hk_, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, hk, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((b, hk, sk, d), v.dtype),
+            jax.ShapeDtypeStruct((b, hk, sk, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         interpret=_interpret(),
         name=_kernel_name("dkv", window),
@@ -714,6 +721,9 @@ def _check_and_transpose(q, k, v, causal, scale):
     if k.shape[2] != v.shape[2]:
         raise ValueError(f"k heads ({k.shape[2]}) != v heads "
                          f"({v.shape[2]})")
+    if q.shape[3] != k.shape[3]:
+        raise ValueError(f"q width ({q.shape[3]}) != k width "
+                         f"({k.shape[3]}); v may differ")
     if h % hk:
         raise ValueError(f"q heads {h} not a multiple of kv heads {hk}")
     scale = scale if scale is not None else q.shape[-1] ** -0.5

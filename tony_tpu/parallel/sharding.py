@@ -50,6 +50,12 @@ DEFAULT_RULES: Tuple[Tuple[str, Any], ...] = (
     ("ssm_inner", None),
     ("ssm_heads", None),
     ("conv", None),
+    # Likewise a linear-attention mixer's (models/kda.py), and a low-rank
+    # product's narrow side (its gates' down-projections, latent attention's
+    # shared latent).
+    ("kda_inner", None),
+    ("kda_heads", None),
+    ("rank", None),
 )
 
 
