@@ -3,13 +3,16 @@ stands for, token by token: the Mosaic kernels (interpreted on the CPU) and
 the ``jax.numpy`` path, forward and every gradient, over several chunks of
 64 and two rows, with and without channels whose decay is e^−5 a token (a
 chunk then spans e^−320, past float32's range for a factor split from the
-chunk's start)."""
+chunk's start), and the kernels' pairs inside a sub-chunk against the
+``jax.numpy`` path's."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
+import tony_tpu.ops.kda as ops
 from tony_tpu.ops.kda import SUB, kda, log_decays, normed
 
 CHUNK = 64
@@ -45,15 +48,17 @@ def recurrence(q, k, v, x, a, beta):
     return jnp.moveaxis(o, 0, 1)
 
 
-def _inputs(strong: float = 0.0, seed: int = 1):
+def _inputs(strong: float = 0.0, seed: int = 1, every: int = 1):
     ks = jax.random.split(jax.random.key(seed), 7)
     q = jax.nn.silu(jax.random.normal(ks[0], (B, S, H, K)))
     k = jax.nn.silu(jax.random.normal(ks[1], (B, S, H, K)))
     v = jax.random.normal(ks[2], (B, S, H, V))
     x = jax.random.normal(ks[3], (B, S, H, K)) * 2 - 2
     a = -jnp.exp(jax.random.normal(ks[4], (H,)) * 0.5)
-    if strong:      # a quarter of the channels: g = −strong every token
-        x = x.at[..., :K // 4].set(jnp.log(jnp.expm1(strong / -a[:, None])))
+    if strong:      # a quarter of the channels: g = −strong on every
+        # ``every``-th token, the mild decays of the others elsewhere
+        x = x.at[:, ::every, :, :K // 4].set(
+            jnp.log(jnp.expm1(strong / -a[:, None])))
     beta = jax.nn.sigmoid(jax.random.normal(ks[5], (B, S, H)))
     w = jax.random.normal(ks[6], (B, S, H, V))
     return (q, k, v, x, a, beta), w
@@ -80,12 +85,26 @@ def _gaps(impl, args, w, dtype=jnp.float32):
 
 
 @pytest.mark.parametrize("impl", ["kernel", "jnp"])
-@pytest.mark.parametrize("strong", [0.0, 5.0, 30.0])
-def test_chunks_match_the_recurrence(impl, strong):
+@pytest.mark.parametrize("strong, every", [
+    pytest.param(0.0, 1, id="0.0"), pytest.param(5.0, 1, id="5.0"),
+    pytest.param(30.0, 1, id="30.0"),
+    pytest.param(35.0, 11, id="35.0-every11")])
+def test_chunks_match_the_recurrence(impl, strong, every):
     """Without strong channels, with channels that decay by e^−5 a token,
     and by e^−30 (a sub-chunk of 16 then spans e^−450: a factor counted from
-    any one token of it would not exist in float32)."""
-    gaps = _gaps(impl, *_inputs(strong))
+    any one token of it would not exist in float32). The last case decays
+    them by e^−35 (the seeded model's deepest is −34.6) on every 11th token
+    and mildly on the others: 11 is prime to 16, so across the four chunks
+    the reference rows of every halving level are strong on some blocks and
+    mild on others.
+
+    On every third token the same channels sum to Γ ≈ −770 a chunk, where
+    float32 holds Γ to 6e-5: a mild stretch's e^{Γ_t − Γ_j} is then off by
+    as much, and both paths read 2e-5 to 4e-5 off the recurrence, as the
+    key-at-a-time kernel did. With the decays on a grid that float32 sums
+    exactly the kernel reads 5e-6 there: the limit is Γ's, not the
+    pairs'."""
+    gaps = _gaps(impl, *_inputs(strong, every=every))
     assert max(gaps.values()) < TOL, gaps
 
 
@@ -105,6 +124,52 @@ def test_bf16_inputs_fail_the_tolerance():
     them, read well over it."""
     gaps = _gaps("kernel", *_inputs(), dtype=jnp.bfloat16)
     assert min(gaps[n] for n in ("o", "q", "k", "v")) > 10 * TOL, gaps
+
+
+def _pairs_of(q32, k32, gam, kernel):
+    """``_pairs``' ``M_qk`` (j ≤ t) and ``M_kk`` (j < t) of one chunk, run
+    as the kernels run them (in an interpreted Mosaic body)."""
+    n = gam.shape[0]
+
+    def body(q_ref, k_ref, g_ref, qk_ref, kk_ref):
+        qk_ref[...], kk_ref[...], _ = ops._pairs(
+            q_ref[...], k_ref[...], g_ref[...], jnp.bfloat16,
+            jax.lax.Precision.DEFAULT, kernel)
+
+    with jax.default_matmul_precision("highest"):
+        mqk, mkk = pl.pallas_call(
+            body, interpret=True,
+            out_shape=[jax.ShapeDtypeStruct((n, n), jnp.float32)] * 2)(
+                q32, k32, gam)
+    rows, cols = np.tril_indices(n), np.tril_indices(n, -1)
+    return np.concatenate([np.asarray(mqk)[rows], np.asarray(mkk)[cols]])
+
+
+@pytest.mark.parametrize("strong, every", [(0.0, 1), (30.0, 1), (35.0, 3)])
+def test_kernel_pairs_are_the_float32_sums(monkeypatch, strong, every):
+    """The kernels' pairs (the levels inside each sub-chunk) against the
+    ``jax.numpy`` path's (``_within``'s one ``[16, 16, K]`` factor a
+    sub-chunk), both in float32 from bf16-valued q and k: the same sums in
+    another order, within 1e-6 of the largest entry. The in-sub-chunk
+    operands rounded to bf16 read over 1e-4, so the tolerance tells the
+    float32 sums from a lower precision."""
+    (q, k, _, x, a, _), _ = _inputs(strong, every=every)
+    qn, kn = normed(q.astype(jnp.bfloat16), k.astype(jnp.bfloat16))
+    q32, k32 = (t[0, :CHUNK, 0].astype(jnp.float32) for t in (qn, kn))
+    gam = jnp.cumsum(log_decays(x, a)[0, :CHUNK, 0], axis=0)
+    want = _pairs_of(q32, k32, gam, kernel=False)
+    scale = np.max(np.abs(want))
+
+    def gap():
+        return np.max(np.abs(_pairs_of(q32, k32, gam, True) - want)) / scale
+
+    assert gap() < 1e-6
+    # every product of ``_pairs`` on bf16 operands: the pairs between
+    # sub-chunks already are, those inside lose float32's bits
+    dot = ops._dot
+    monkeypatch.setattr(ops, "_dot", lambda a, b, dims, prec: dot(
+        a.astype(jnp.bfloat16), b.astype(jnp.bfloat16), dims, prec))
+    assert gap() > 1e-4
 
 
 def test_a_row_starts_from_a_zero_state():

@@ -32,10 +32,17 @@ sub-chunks of ``SUB`` tokens (as FLA's KDA does). A key in an earlier
 sub-chunk than its query is two factors from the last token before the
 query's sub-chunk, ``e^{Γ_t − ref} · e^{ref − Γ_j}``, each at most 1 (one of
 them may round to 0 only where the pair itself is below float32's range):
-one product in q's dtype a sub-chunk of queries. A key in the query's own
-sub-chunk is summed a key at a time in float32 with its own ``e^{Γ_t −
-Γ_j}``. No factor above 1 is ever formed, however fast a channel decays;
-the mixer's counter ``kda_log_decay_min`` reads how fast they did.
+one product in q's dtype a sub-chunk of queries. Inside a sub-chunk the
+kernels halve: at level ``m = SUB/2, ..., 1`` each query in the second half
+of a block of ``2m`` tokens meets each key of its first half through the
+block's reference ``R``, Γ at the first half's last token, as ``(q_t ∘
+e^{Γ_t − R}) · (k_j ∘ e^{R − Γ_j})``. Both exponents are at most 0, and
+every row takes one factor a level, so a level is a masked product of
+float32 operands at ``HIGHEST`` precision for ``M_kk`` and one for
+``M_qk``: the float32 sums of a key at a time, in another order. The pair
+t = j is ``q_t · k_t``. No factor above 1 is ever formed, however fast a
+channel decays; the mixer's counter ``kda_log_decay_min`` reads how fast
+they did.
 
 Forward and backward are one Mosaic call each, named ``kda_fwd`` and
 ``kda_bwd``, under one ``jax.custom_vjp``; the backward is written by hand.
@@ -46,9 +53,10 @@ into Γ itself, and the backward hands back g's cotangent, so no
 ``[chunks, C]`` layout of the decays is made outside. The forward also
 writes each chunk's
 entering state (float32 ``[B, H, chunks, K, V]``), which the backward reads
-to recompute its chunk. Decays, their cumulative sums, the state and
-``(I + A)^{-1}`` are float32; q, k, v and the other products' operands
-multiply in q's dtype with float32 accumulation.
+to recompute its chunk. Decays, their cumulative sums, the state,
+``(I + A)^{-1}`` and the pairs inside a sub-chunk are float32; q, k, v and
+the other products' operands multiply in q's dtype with float32
+accumulation.
 
 ``impl="jnp"`` is the same chunked algorithm in plain ``jax.numpy`` (the
 same per-chunk function, a scan over chunks), autodiff its backward: the
@@ -74,6 +82,7 @@ MASKED = -1e30      # an exponent whose factor is 0
 L2_EPS = 1e-6       # inside the root of q's and k's L2 norms
 SUB = 16            # tokens of a sub-chunk: the span of an in-block pair
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _f32(x):
@@ -110,7 +119,7 @@ def _within(q32, k32, gam):
     """The pairs inside each sub-chunk, ``Σ_c x_tc k_jc e^{Γ_tc − Γ_jc}``
     for j ≤ t (x = q, k), as ``[C, C]`` blocks on the diagonal: the
     ``jax.numpy`` path's form, one ``[SUB, SUB, K]`` factor a sub-chunk (a
-    kernel sums them a key at a time)."""
+    kernel takes them by halving levels, ``_pairs``)."""
     n, kd = gam.shape
     subs = n // SUB
     qs, ks, gs = (t.reshape(subs, SUB, kd) for t in (q32, k32, gam))
@@ -125,14 +134,57 @@ def _within(q32, k32, gam):
     return blocks(qs), blocks(ks)
 
 
+def _at_ref(gam, m):
+    """Γ at each row's level-``m`` reference, the last row of the first half
+    of its block of ``2m`` rows. A block of whole (8, 128) tiles is a
+    sublane broadcast; a smaller one takes the row from 1 − m to m rows
+    away by rolls."""
+    n, kd = gam.shape
+    if 2 * m >= 8:
+        blocks = gam.reshape(n // (2 * m), 2 * m, kd)
+        return jnp.broadcast_to(blocks[:, m - 1:m], blocks.shape).reshape(
+            n, kd)
+    at = _rows(n) & (2 * m - 1)
+    ref = gam
+    for s in range(1 - m, m + 1):                 # row r takes row r − s
+        if s:
+            ref = jnp.where(at == m - 1 + s, pltpu.roll(gam, s % n, 0), ref)
+    return ref
+
+
+def _to_ref(c, m):
+    """``_at_ref``'s transpose: each block's sum of ``c`` on its reference
+    row, zero on the others."""
+    n, kd = c.shape
+    at = _rows(n) & (2 * m - 1)
+    if 2 * m >= 8:
+        blocks = c.reshape(n // (2 * m), 2 * m, kd)
+        total = jnp.broadcast_to(jnp.sum(blocks, axis=1, keepdims=True),
+                                 blocks.shape).reshape(n, kd)
+        return jnp.where(at == m - 1, total, 0.0)
+    out = jnp.where(at == m - 1, c, 0.0)
+    for s in range(1 - m, m + 1):
+        if s:
+            out = out + pltpu.roll(jnp.where(at == m - 1 + s, c, 0.0),
+                                   -s % n, 0)
+    return out
+
+
 def _pairs(q32, k32, gam, dtype, prec, kernel: bool = True):
     """``M_qk`` (j ≤ t) and ``M_kk`` (j < t) of a chunk, and what their
-    backward reuses. A pair whose key lies in an earlier sub-chunk is two
-    factors, each at most 1, from the last token before the query's
-    sub-chunk (``ref_i``): ``(q ∘ e^{Γ − ref_i}) (k ∘ e^{ref_i − Γ})ᵀ``, a
-    product in ``dtype``. A pair inside one sub-chunk is summed in float32
-    with its own ``e^{Γ_t − Γ_j}``, never split: a key at a time in a
-    ``kernel``, else by ``_within``."""
+    backward reuses; entries above those are the caller's to mask. A pair
+    whose key lies in an earlier sub-chunk is two factors, each at most 1,
+    from the last token before the query's sub-chunk (``ref_i``): ``(q ∘
+    e^{Γ − ref_i}) (k ∘ e^{ref_i − Γ})ᵀ``, a product in ``dtype``. A pair
+    inside one sub-chunk is float32 throughout: in a ``kernel`` by halving
+    levels, else by ``_within``. At level ``m`` (``SUB/2``, ..., 1) a query
+    in the second half of a block of ``2m`` rows meets each key of the
+    first half through the block's reference ``R``, Γ at the first half's
+    last row: ``(q_t ∘ e^{Γ_t − R}) · (k_j ∘ e^{R − Γ_j})``, both exponents
+    at most 0. Each row takes one of the two a level as its factor ``f``,
+    so a level is two masked products of float32 operands at ``HIGHEST``,
+    ``(k ∘ f)(k ∘ f)ᵀ`` and ``(q ∘ f)(k ∘ f)ᵀ``. The pair t = j is ``q_t ·
+    k_t``."""
     n, kd = gam.shape
     row = _rows(n)
     subs = [(row >= i * SUB) & (row < (i + 1) * SUB)
@@ -144,41 +196,45 @@ def _pairs(q32, k32, gam, dtype, prec, kernel: bool = True):
         gref = jnp.where(mine, ref, gref)
     a = jnp.exp(gam - gref)                        # e^{Γ_t − ref(t)} ≤ 1
     qa, ka = (q32 * a).astype(dtype), (k32 * a).astype(dtype)
-    mqk = jnp.zeros((n, n), jnp.float32)
-    mkk = jnp.zeros((n, n), jnp.float32)
     kbs, ebs = [None], [None]
     for i in range(1, n // SUB):
         # the keys of the sub-chunks before i, each e^{ref_i − Γ_j} ≤ 1
         eb = jnp.exp(jnp.where(row < i * SUB, refs[i] - gam, MASKED))
-        kb = (k32 * eb).astype(dtype)
-        mqk = jnp.where(subs[i], _dot(qa, kb, ((1,), (1,)), prec), mqk)
-        mkk = jnp.where(subs[i], _dot(ka, kb, ((1,), (1,)), prec), mkk)
-        kbs.append(kb)
+        kbs.append((k32 * eb).astype(dtype))
         ebs.append(eb)
     p = dict(a=a, qa=qa, ka=ka, kbs=kbs, ebs=ebs, subs=subs)
+
+    def between(x):
+        out = jnp.zeros((n, n), jnp.float32)
+        for i in range(1, n // SUB):
+            out = jnp.where(subs[i], _dot(x, kbs[i], ((1,), (1,)), prec), out)
+        return out
+
     if not kernel:
         wqk, wkk = _within(q32, k32, gam)
-        return mqk + wqk, mkk + wkk, p
-    pos = _rows(SUB)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (SUB, n), 1)
-    blocks_qk, blocks_kk = [], []
-    for i in range(n // SUB):
-        rows = slice(i * SUB, (i + 1) * SUB)
-        qi, ki, gi = q32[rows], k32[rows], gam[rows]
-        bqk = jnp.zeros((SUB, n), jnp.float32)
-        bkk = jnp.zeros((SUB, n), jnp.float32)
-        for o in range(SUB):
-            kje = ki[o:o + 1] * jnp.exp(
-                jnp.where(pos >= o, gi - gi[o:o + 1], MASKED))
-            here = cols == i * SUB + o
-            bqk = jnp.where(here, jnp.sum(qi * kje, axis=1, keepdims=True),
-                            bqk)
-            bkk = jnp.where(here, jnp.sum(ki * kje, axis=1, keepdims=True),
-                            bkk)
-        blocks_qk.append(bqk)
-        blocks_kk.append(bkk)
-    mqk = mqk + jnp.concatenate(blocks_qk, axis=0)
-    mkk = mkk + jnp.concatenate(blocks_kk, axis=0)
+        return between(qa) + wqk, between(ka) + wkk, p
+    # M_kk's products are written first: (I + A)^{-1} waits on M_kk alone,
+    # M_qk only the output, and a kernel's products reach the MXUs in the
+    # order they are written. t XOR j is below SUB inside a sub-chunk, below
+    # 2m inside a level-m block
+    apart = _rows(n, n) ^ jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    mkk = between(ka)
+    p["levels"] = []
+    m = SUB // 2
+    while m:
+        ref = _at_ref(gam, m)
+        f = jnp.exp(jnp.where((row & m) != 0, gam - ref, ref - gam))
+        x = jnp.concatenate([q32 * f, k32 * f])
+        mkk = jnp.where(apart < 2 * m, _dot(x[n:], x[n:], ((1,), (1,)),
+                                            HIGHEST), mkk)
+        p["levels"].append((m, f, x))
+        m //= 2
+    mqk = between(qa)
+    for m, _, x in p["levels"]:
+        mqk = jnp.where(apart < 2 * m, _dot(x[:n], x[n:], ((1,), (1,)),
+                                            HIGHEST), mqk)
+    mqk = jnp.where(apart == 0, jnp.sum(q32 * k32, axis=1, keepdims=True),
+                    mqk)
     return mqk, mkk, p
 
 
@@ -217,37 +273,36 @@ def _pairs_bwd(q32, k32, gam, dmqk, dmkk, p, prec):
         dref = drefs[i] - jnp.sum(jnp.where(p["subs"][i], da_t, 0.0),
                                   axis=0, keepdims=True)
         dgam = dgam + jnp.where(row == i * SUB - 1, dref, 0.0)
-    # the pairs inside each sub-chunk, a key at a time
-    pos = _rows(SUB)
-    dq_d, dk_d, dg_d = [], [], []
-    for i in range(n // SUB):
-        rows = slice(i * SUB, (i + 1) * SUB)
-        qi, ki, gi = q32[rows], k32[rows], gam[rows]
-        wq_blk, wk_blk = dmqk[rows], dmkk[rows]
-        dqi = jnp.zeros((SUB, kd), jnp.float32)
-        dki = jnp.zeros((SUB, kd), jnp.float32)
-        dgi = jnp.zeros((SUB, kd), jnp.float32)
-        for o in range(SUB):
-            j = i * SUB + o
-            kj = ki[o:o + 1]
-            e = jnp.exp(jnp.where(pos >= o, gi - gi[o:o + 1], MASKED))
-            wq, wk = wq_blk[:, j:j + 1], wk_blk[:, j:j + 1]
-            dqi = dqi + wq * kj * e
-            dki = dki + wk * kj * e
-            s = (wq * qi + wk * ki) * e            # into k_j ∘ e_{tj}
-            dki = jnp.where(pos == o, dki + jnp.sum(s, axis=0, keepdims=True),
-                            dki)
-            # e_{jj} = 1 whatever Γ_j is: the pair t = j moves no Γ
-            s = jnp.where(pos > o, s, 0.0)
-            dgi = dgi + s * kj
-            dgi = jnp.where(pos == o, dgi - kj * jnp.sum(s, axis=0,
-                                                         keepdims=True), dgi)
-        dq_d.append(dqi)
-        dk_d.append(dki)
-        dg_d.append(dgi)
-    return (dq + jnp.concatenate(dq_d, axis=0),
-            dk + jnp.concatenate(dk_d, axis=0),
-            dgam + jnp.concatenate(dg_d, axis=0))
+    # the pairs inside each sub-chunk, level by level, one product a level:
+    # [[0, dM_qk], [dM_qkᵀ, dM_kkᵀ + dM_kk]] against [qf; kf] gives qf's
+    # cotangent (dM_qk against kf) over kf's (dM_qk against qf as a key,
+    # dM_kk against kf as a key and as a query). The mask of t XOR j is
+    # symmetric, so it masks the transposes as it masks dM
+    apart = _rows(n, n) ^ jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    apart = jnp.concatenate([apart, apart], axis=1)
+    apart = jnp.concatenate([apart, apart])
+    zero = jnp.zeros_like(dmqk)
+    lhs = jnp.concatenate([
+        jnp.concatenate([zero, dmqk], axis=1),
+        jnp.transpose(jnp.concatenate([dmqk, dmkk]))
+        + jnp.concatenate([zero, dmkk], axis=1)])
+    for m, f, x in p["levels"]:
+        d = _dot(jnp.where((apart >= m) & (apart < 2 * m), lhs, 0.0), x,
+                 ((1,), (0,)), HIGHEST)
+        dqf, dkf = d[:n], d[n:]
+        dq = dq + dqf * f
+        dk = dk + dkf * f
+        # into the exponent ±(Γ − R), and R's share: at R's own row the
+        # exponent is 0 whatever Γ is, so that row's two shares are left
+        # out rather than cancelled in float32
+        ce = dqf * x[:n] + dkf * x[n:]
+        c = jnp.where((row & (2 * m - 1)) == m - 1, 0.0,
+                      jnp.where((row & m) != 0, -ce, ce))
+        dgam = dgam - c + _to_ref(c, m)
+    # the pairs t = j: e^0, so they move no Γ
+    dii = jnp.sum(jnp.where(apart[:n, :n] == 0, dmqk, 0.0), axis=1,
+                  keepdims=True)
+    return dq + dii * k32, dk + dii * q32, dgam
 
 
 def _inverse(a):
