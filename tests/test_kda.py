@@ -172,6 +172,61 @@ def test_kernel_pairs_are_the_float32_sums(monkeypatch, strong, every):
     assert gap() > 1e-4
 
 
+def _a_and_inverse(q32, k32, gam, beta):
+    """A = diag(β) M_kk (strictly lower) of one chunk as the kernels take it
+    (``_pairs``' levels), and ``_inverse(A)``, both in an interpreted Mosaic
+    body."""
+    n = gam.shape[0]
+
+    def body(q_ref, k_ref, g_ref, b_ref, a_ref, t_ref):
+        _, mkk, _ = ops._pairs(q_ref[...], k_ref[...], g_ref[...],
+                               jnp.bfloat16, jax.lax.Precision.DEFAULT)
+        below = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0) \
+            > jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+        a_ref[...] = b_ref[...] * jnp.where(below, mkk, 0.0)
+        t_ref[...] = ops._inverse(a_ref[...])
+
+    return pl.pallas_call(
+        body, interpret=True,
+        out_shape=[jax.ShapeDtypeStruct((n, n), jnp.float32)] * 2)(
+            q32, k32, gam, beta)
+
+
+@pytest.mark.parametrize("n", [SUB, 2 * SUB, 4 * SUB])
+@pytest.mark.parametrize("strong, every", [(0.0, 1), (30.0, 1), (35.0, 3)])
+def test_inverse_is_the_float32_solve(monkeypatch, n, strong, every):
+    """``_inverse`` against the triangular solve of ``I + A`` in float32, on
+    A as the kernels take it from the strong-decay inputs: one sub-chunk (its
+    substitution alone), two and four (one and two factors joining the
+    blocks), in the kernel's body and in ``jax.numpy``. Both read within 1e-6
+    of the solve, and ``(I + A) T`` within 1e-6 of I; the joins on bf16
+    operands read over 1e-5, so the tolerance tells float32 products from a
+    lower precision."""
+    (q, k, _, x, a, beta), _ = _inputs(strong, every=every)
+    qn, kn = normed(q.astype(jnp.bfloat16), k.astype(jnp.bfloat16))
+    q32, k32 = (t[0, :n, 0].astype(jnp.float32) for t in (qn, kn))
+    gam = jnp.cumsum(log_decays(x, a)[0, :n, 0], axis=0)
+    b = beta[0, :n, 0, None]
+    eye = jnp.eye(n, dtype=jnp.float32)
+
+    def gaps(a, t):
+        with jax.default_matmul_precision("highest"):
+            want = jax.scipy.linalg.solve_triangular(eye + a, eye, lower=True)
+        unit = jnp.dot(eye + a, t, precision=jax.lax.Precision.HIGHEST)
+        return (float(jnp.max(jnp.abs(t - want)) / jnp.max(jnp.abs(want))),
+                float(jnp.max(jnp.abs(unit - eye))))
+
+    am, t = _a_and_inverse(q32, k32, gam, b)
+    assert float(jnp.max(jnp.abs(am))) > 0.1
+    assert max(gaps(am, t)) < 1e-6
+    assert max(gaps(am, ops._inverse(am))) < 1e-6
+    if n > SUB:
+        dot = ops._dot
+        monkeypatch.setattr(ops, "_dot", lambda a, b, dims, prec: dot(
+            a.astype(jnp.bfloat16), b.astype(jnp.bfloat16), dims, prec))
+        assert min(gaps(am, ops._inverse(am))) > 1e-5
+
+
 def test_a_row_starts_from_a_zero_state():
     """Two rows are two sequences: a row's output does not depend on the
     other row."""

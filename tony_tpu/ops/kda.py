@@ -23,7 +23,11 @@ the delta rule's WY/UT form gives, ``A = diag(β) M_kk`` strictly lower,
     O   = (Q ⊙ e^Γ) S_0 + M_qk U
     S_C = Diag(e^{Γ_C}) S_0 + (K ⊙ e^{Γ_C − Γ})ᵀ U.
 
-``(I + A)^{-1}`` is taken by forward substitution over the chunk's rows.
+``(I + A)^{-1}`` is taken by blocks of ``SUB`` tokens: forward substitution
+inside every diagonal block at once, the column substitution's float32
+arithmetic in the same order, then the blocks joined by products of float32
+operands at ``HIGHEST``: ``(I − N)(I + N²) T_d`` at a chunk of 64, ``T_d``
+the blocks' inverse and ``N = T_d L``, L being A below the blocks.
 
 Numerics: ``e^{Γ_t − Γ_j} ≤ 1`` for ``j ≤ t``, but split as ``e^{Γ_t} ·
 e^{−Γ_j}`` from the chunk's start it overflows float32 once a channel has
@@ -215,10 +219,11 @@ def _pairs(q32, k32, gam, dtype, prec, kernel: bool = True):
         return between(qa) + wqk, between(ka) + wkk, p
     # M_kk's products are written first: (I + A)^{-1} waits on M_kk alone,
     # M_qk only the output, and a kernel's products reach the MXUs in the
-    # order they are written. t XOR j is below SUB inside a sub-chunk, below
-    # 2m inside a level-m block
+    # order they are written. Its levels come before the pairs between
+    # sub-chunks, which only the joins of ``_inverse``'s blocks read. t XOR j
+    # is below SUB inside a sub-chunk, below 2m inside a level-m block
     apart = _rows(n, n) ^ jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
-    mkk = between(ka)
+    mkk = jnp.zeros((n, n), jnp.float32)
     p["levels"] = []
     m = SUB // 2
     while m:
@@ -229,6 +234,7 @@ def _pairs(q32, k32, gam, dtype, prec, kernel: bool = True):
                                             HIGHEST), mkk)
         p["levels"].append((m, f, x))
         m //= 2
+    mkk = jnp.where(apart < SUB, mkk, between(ka))
     mqk = between(qa)
     for m, _, x in p["levels"]:
         mqk = jnp.where(apart < 2 * m, _dot(x[:n], x[n:], ((1,), (1,)),
@@ -306,14 +312,45 @@ def _pairs_bwd(q32, k32, gam, dmqk, dmkk, p, prec):
 
 
 def _inverse(a):
-    """``(I + a)^{-1}`` of a strictly lower ``a [C, C]`` (float32), by
-    forward substitution a column at a time: once row ``j`` is final every
-    later row takes ``a[i, j]`` of it away."""
+    """``(I + a)^{-1}`` of a strictly lower ``a [C, C]`` (float32), C a whole
+    number of sub-chunks. First ``T_d``, the inverse of I plus a's diagonal
+    blocks of ``SUB``, by forward substitution in every block at once. The
+    blocks are held side by side, ``[SUB, C]`` with block b in lanes
+    ``SUB·b`` on, so step j takes row j of every block (a sublane broadcast)
+    times column j of each block of a across that block's lanes (a lane
+    gather made from a alone, before the steps): the column substitution's
+    float32 arithmetic in the same order, with no lane broadcast for a step
+    to wait on. Then ``(I + a)^{-1} = (I + N)^{-1} T_d`` with ``N = T_d L``,
+    L being a below the blocks: N is strictly lower by blocks, so
+    ``N^{C/SUB} = 0`` and ``(I + N)^{-1} = (I − N)(I + N²)(I + N⁴)…`` ends
+    after ``log₂(C/SUB)`` factors, each a product of float32 operands at
+    ``HIGHEST`` over the rows its power of N leaves nonzero."""
     n = a.shape[0]
-    t = jnp.where(_rows(n, n) == jax.lax.broadcasted_iota(
-        jnp.int32, (n, n), 1), 1.0, 0.0).astype(jnp.float32)
-    for j in range(n - 1):
-        t = t - a[:, j:j + 1] * t[j:j + 1, :]
+    blocks = n // SUB
+    lane = jax.lax.broadcasted_iota(jnp.int32, (SUB, n), 1)
+    group = lane // SUB
+    d = a[:SUB]                     # d[s, SUB·b + c] = a[SUB·b + s, SUB·b + c]
+    for b in range(1, blocks):
+        d = jnp.where(group == b, a[b * SUB:(b + 1) * SUB], d)
+    t = jnp.where(lane - SUB * group == _rows(SUB), 1.0, 0.0)
+    for j in range(SUB - 1):
+        col = jnp.take_along_axis(d, SUB * group + j, axis=1,
+                                  mode="promise_in_bounds")
+        t = t - col * t[j:j + 1, :]
+    td = jnp.concatenate([jnp.where(group == b, t, 0.0)
+                          for b in range(blocks)])
+
+    def times(x, y, zero):          # x y, x's first ``zero`` rows being 0
+        return jnp.concatenate([jnp.zeros((zero, n), jnp.float32),
+                                _dot(x[zero:], y, ((1,), (0,)), HIGHEST)])
+
+    apart = _rows(n, n) ^ jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    low = jnp.where(apart >= SUB, -a, 0.0)
+    t, power = td, 1
+    while power < blocks:           # m = (−N)^power
+        m = times(td, low, SUB) if power == 1 else times(m, m, power * SUB)
+        t = t + times(m, t, power * SUB)
+        power *= 2
     return t
 
 
